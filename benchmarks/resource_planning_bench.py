@@ -68,6 +68,7 @@ from repro.core.plans import OperatorCosting
 from repro.core.raqo import RAQO
 from repro.core.schema import random_query, random_schema
 from repro.core.selinger import selinger_plan
+from repro.launch.compile_cache import enable_compile_cache
 
 Row = Tuple[str, float, str]
 
@@ -546,11 +547,18 @@ def sharded_table(quick: bool = False) -> Tuple[List[Row], dict]:
     view.  Every lane's argmin is checked bit-identical against the
     numpy oracle; wall-clock SCALING additionally needs as many real
     cores as simulated devices — on fewer, the shards time-slice one CPU
-    and the ratio is recorded (and main() only notes it), not gated."""
+    and the ratio is recorded (and main() only notes it), not gated.
+
+    The lanes are CPU-only.  On a TPU host this process already holds the
+    chip, so no child is started at all: the real multi-chip check is
+    ``chip_smoke.py --chips 4``."""
     rows: List[Row] = []
     out: dict = {}
     from repro.core.planning_backend import have_backend
     if not have_backend("jax"):
+        return rows, out
+    import jax
+    if jax.default_backend() == "tpu":
         return rows, out
     src = str(Path(__file__).resolve().parent.parent / "src")
     device_counts = (1, 2) if quick else (1, 2, 4, 8)
@@ -560,6 +568,7 @@ def sharded_table(quick: bool = False) -> Tuple[List[Row], dict]:
     for d in device_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
         env.pop("REPRO_PLAN_DEVICES", None)        # the cap under test
         proc = subprocess.run(
@@ -826,6 +835,7 @@ def main() -> None:
     # wall-clock acceptance asserts — for shared/loaded runners (the
     # bench-history CI job) where a slow host must not lose the snapshot
     gate = "--no-gate" not in sys.argv[1:]
+    enable_compile_cache()
     print("name,value,derived")
     rows = run(quick)
     by_name = {name: value for name, value, _ in rows}

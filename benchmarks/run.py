@@ -265,6 +265,8 @@ def main() -> None:
     if "--report" in sys.argv[1:]:
         report()
         return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--trace" in sys.argv[1:]:
         from benchmarks import telemetry_bench
         print("name,value,derived")
@@ -279,6 +281,7 @@ def main() -> None:
     fns = list(paper_figs.ALL) + [resource_planning_bench.run,
                                   roofline_table.run, tpu_planner.run]
     all_rows = []
+    failed = []
     print("name,us_per_call,derived")
     for fn in fns:
         label = f"{fn.__module__.split('.')[-1]}.{fn.__name__}"
@@ -293,14 +296,17 @@ def main() -> None:
                 all_rows.append({"name": name, "value": value,
                                  "derived": derived})
             print(f"{label}._total,{us:.0f},bench wall time (us)")
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other benches, then fail
             traceback.print_exc()
             print(f"{label}.ERROR,-1,{type(e).__name__}: {e}")
             all_rows.append({"name": label, "value": -1,
                              "derived": f"ERROR {e}"})
+            failed.append(label)
     out = Path(__file__).resolve().parent.parent / "artifacts"
     out.mkdir(exist_ok=True)
     (out / "bench_results.json").write_text(json.dumps(all_rows, indent=1))
+    if failed:
+        sys.exit(f"{len(failed)} bench(es) raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -47,6 +47,7 @@ from repro.core.cluster import paper_cluster
 from repro.core.plan_broker import PlanBroker
 from repro.core.raqo import RAQO
 from repro.core.schema import random_query, random_schema
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import get_metrics, get_tracer, write_chrome_trace
 from repro.service import StreamingPlannerService, poisson_trace
 
@@ -284,6 +285,7 @@ def _gate_p99(by_name: dict) -> None:
 def main() -> None:
     quick = "--quick" in sys.argv[1:]
     gate = "--no-gate" not in sys.argv[1:]
+    enable_compile_cache()
     print("name,value,derived")
     rows = run(quick)
     by_name = {name: value for name, value, _ in rows}

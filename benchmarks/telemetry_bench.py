@@ -35,6 +35,7 @@ from repro.core.cluster import scaled_cluster
 from repro.core.plan_broker import PlanBroker
 from repro.core.raqo import RAQO
 from repro.core.schema import random_query, random_schema
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (get_metrics, get_tracer, wave_summary,
                        write_attribution, write_chrome_trace)
 
@@ -157,6 +158,7 @@ def _append_history(summary: dict) -> None:
 
 def main() -> None:
     quick = "--quick" in sys.argv[1:]
+    enable_compile_cache()
     print("name,value,derived")
     for name, value, derived in run(quick):
         print(f"{name},{value:.6g},{derived}")

@@ -60,6 +60,43 @@ class ClusterConditions:
         return all(d.clamp_ok(v) for d, v in zip(self.dims, cfg))
 
 
+class ConfigColumns:
+    """A batch of configurations held as one array per resource dimension.
+
+    The fused Pallas kernels decode configurations straight into
+    vector-register tiles, one tile per dimension; stacking them into an
+    ``(N, n_dims)`` array would force a relayout the TPU compiler refuses.
+    This view answers the two things batch cost fns ask of their configs,
+    ``configs[:, d]`` (the tile of dimension d) and ``shape``; cost fns
+    read it through ``as_configs``.  ``jnp.asarray`` still stacks it into
+    the ``(N, n_dims)`` array for cost fns that index rows."""
+
+    ndim = 2
+
+    def __init__(self, cols: Sequence):
+        self.cols = tuple(cols)
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return (int(self.cols[0].size), len(self.cols))
+
+    def __getitem__(self, key):
+        rows, d = key
+        if rows != slice(None) or not isinstance(d, int):
+            raise IndexError(f"ConfigColumns supports [:, d], not {key!r}")
+        return self.cols[d]
+
+    def __jax_array__(self):
+        import jax.numpy as jnp
+        return jnp.stack([c.reshape(-1) for c in self.cols], axis=1)
+
+
+def as_configs(configs, xp):
+    """``xp.asarray(configs)``, keeping a ``ConfigColumns`` view as is."""
+    return configs if isinstance(configs, ConfigColumns) \
+        else xp.asarray(configs)
+
+
 def paper_cluster(max_containers: int = 100, max_gb: int = 10,
                   step_containers: int = 1, step_gb: int = 1
                   ) -> ClusterConditions:
@@ -98,6 +135,9 @@ class PlanningStats:
     broker_requests: int = 0          # requests submitted
     broker_dedup_hits: int = 0        # resolved without their own search
     broker_batches: int = 0           # stacked array programs executed
+    # float32 winners the float64 commit found infeasible, re-searched on
+    # the numpy backend (many of them mean the device is not planning)
+    broker_researches: int = 0
     # flush-wave geometry (broker-level only: a wave spans requests from
     # many costings, so per-request stats never see these) — one entry
     # per non-empty flush, counting the requests that entered the wave
@@ -113,6 +153,7 @@ class PlanningStats:
         self.broker_requests += other.broker_requests
         self.broker_dedup_hits += other.broker_dedup_hits
         self.broker_batches += other.broker_batches
+        self.broker_researches += other.broker_researches
         self.broker_waves += other.broker_waves
         self.broker_wave_sizes.extend(other.broker_wave_sizes)
         for key, d in other.cache_detail.items():

@@ -40,6 +40,8 @@ from typing import Callable, Dict, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.cluster import as_configs
+
 FEATURES = ("ss", "ss2", "cs", "cs2", "nc", "nc2", "cs_nc")
 
 
@@ -50,7 +52,7 @@ def feature_vector(ss: float, cs: float, nc: float) -> np.ndarray:
 
 def _split_configs(configs, xp=np) -> Tuple[np.ndarray, np.ndarray]:
     """(N, 2) array of (nc, cs) resource configurations -> float columns."""
-    a = xp.asarray(configs)
+    a = as_configs(configs, xp)
     if a.ndim != 2 or a.shape[1] != 2:
         raise ValueError(f"expected (N, 2) (nc, cs) configs, got {a.shape}")
     if xp is np:

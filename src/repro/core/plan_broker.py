@@ -681,6 +681,7 @@ class PlanBroker:
                         f"{res} with finite search cost {raw} but "
                         f"infinite float64 commit")
                 elif req.fallback_fn is not None:
+                    self.stats.broker_researches += 1
                     res, cost = get_backend("numpy").argmin_grid(
                         req.fallback_fn, req.cluster, req.stats,
                         params=req.params)

@@ -76,7 +76,7 @@ matrix runs the parity suites on it).
 Precision
 ---------
 ``JaxPlanBackend(precision="x64")`` (``get_backend("jax_x64")``) scopes
-every trace and call in ``jax.experimental.enable_x64``, so the compiled
+every trace and call in ``jax.enable_x64``, so the compiled
 programs compute in float64 and argmin selection is exact — float32
 rounding can no longer flip a winner, and the planners' float64
 re-commit fallback shrinks to a parity assertion.  Backends advertise
@@ -426,7 +426,7 @@ class JaxPlanBackend:
     float64 backends up to fp tolerance, which is why the planners
     re-evaluate the winning configuration through the scalar float64 path
     before committing to it; ``precision="x64"`` scopes every trace and
-    call in ``jax.experimental.enable_x64`` so selection is exact
+    call in ``jax.enable_x64`` so selection is exact
     (``self.exact``) and that fallback shrinks to a parity assertion.
     """
 
@@ -439,10 +439,7 @@ class JaxPlanBackend:
         if precision not in ("float32", "x64"):
             raise ValueError(f"unknown jax precision {precision!r} "
                              "(expected 'float32' or 'x64')")
-        try:                               # moved out of experimental in
-            from jax import shard_map      # newer jax releases
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         self._jax = jax
         self.xp = jnp
         self._shard_map = shard_map
@@ -457,8 +454,7 @@ class JaxPlanBackend:
     def _scope(self):
         """x64-scoped tracing/execution for precision="x64"; no-op else."""
         if self.exact:
-            from jax.experimental import enable_x64
-            return enable_x64()
+            return self._jax.enable_x64()
         return contextlib.nullcontext()
 
     # -- plan mesh ----------------------------------------------------------- #
@@ -848,14 +844,14 @@ class JaxPlanBackend:
             if D == 1:
                 return jax.jit(vm)
             PS = jax.sharding.PartitionSpec
-            # check_rep=False: shard_map has no replication rule for
-            # while_loop; every output is genuinely sharded over the
-            # request axis, so the check adds nothing here
+            # check_vma=False: every output is genuinely sharded over
+            # the request axis, so the varying-manual-axes check adds
+            # nothing here
             return jax.jit(self._shard_map(
                 vm, mesh=self._plan_mesh(),
                 in_specs=(PS(), PS("plan")),
                 out_specs=(PS("plan"), PS("plan"), PS("plan")),
-                check_rep=False))
+                check_vma=False))
 
         with self._scope():
             prog = self._program("climb_many", batch_cost_fn, cluster,

@@ -38,6 +38,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.core.cluster import as_configs
 
 HW = {
     "peak_flops": 197e12,      # bf16 FLOP/s per chip
@@ -350,7 +351,7 @@ class RooflineGrid:
 
 def _res_cols(resources, xp):
     """(N, 4) array of (pods, dp, tp, microbatch) -> integer columns."""
-    a = xp.asarray(resources)
+    a = as_configs(resources, xp)
     if a.ndim != 2 or a.shape[1] != 4:
         raise ValueError(f"expected (N, 4) resource configs, got {a.shape}")
     return a[:, 0], a[:, 1], a[:, 2], a[:, 3]
@@ -584,7 +585,7 @@ def _register_lint_surfaces() -> None:
                 bad = ~g.feasible
                 bad = bad | (g.chips > params[0]) | (g.chips > params[1])
                 if kind == "train":
-                    a = xp.asarray(configs)
+                    a = as_configs(configs, xp)
                     denom = a[:, 0] * a[:, 1] * a[:, 3]
                     bad = bad | ((global_batch % denom) != 0)
                 return xp.where(bad, xp.inf, g.step_s)

@@ -42,7 +42,8 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.core.cluster import ClusterConditions, PlanningStats, ResourceDim
+from repro.core.cluster import (ClusterConditions, PlanningStats, ResourceDim,
+                                as_configs)
 from repro.core.plan_broker import PlanBroker, PlanRequest
 from repro.core.plan_cache import ResourcePlanCache
 from repro.core.planning_backend import PlanBackend, get_backend
@@ -198,7 +199,7 @@ class ShardingPlanner:
             bad = ~g.feasible
             bad = bad | (g.chips > params[0]) | (g.chips > params[1])
             if kind == "train":
-                a = xp.asarray(cfgs)
+                a = as_configs(cfgs, xp)
                 denom = a[:, 0] * a[:, 1] * a[:, 3]
                 bad = bad | ((global_batch % denom) != 0)
             return xp.where(bad, xp.inf, cost)

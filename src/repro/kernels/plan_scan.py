@@ -18,21 +18,29 @@ stages of the scan into one Pallas program per grid block:
                fn the jax backend jits; infeasible/OOM configs cost inf
                and are masked in-kernel)
     reduce     a streaming argmin: the running ``(best_cost, best_idx)``
-               pair is carried across grid blocks in the revisited output
-               block (TPU grids iterate sequentially, so the accumulator
-               stays VMEM-resident), with strict-``<`` updates in
-               ascending block order so ties break to the *first* minimum
-               in ``enumerate_configs`` order — the scalar loop's
-               tie-breaking contract, preserved bit-for-bit
+               pair is carried across grid blocks in SMEM scalars of the
+               whole-array outputs (TPU grids iterate sequentially), with
+               strict-``<`` updates in ascending block order so ties
+               break to the *first* minimum in ``enumerate_configs``
+               order — the scalar loop's tie-breaking contract, preserved
+               bit-for-bit
+
+A block is computed as 2-D ``(rows, 128)`` vreg tiles of flat row ids,
+decoded into one tile per dimension (a ``ConfigColumns`` view the cost
+fn reads as ``configs[:, d]``); per-request params arrive as SMEM
+scalars.  Nothing is stacked into an ``(N, n_dims)`` array or gathered
+dynamically, which is what lets the TPU compiler accept the kernels
+(``tests/test_tpu_compile.py`` compiles them for a v5e chip).
 
 Two scan kernels:
 
 * ``_scan_kernel`` — one request as a 1-D grid over config blocks, or Q
-  stacked requests as a 2-D grid over ``(query, block)``: params are
-  blocked per query row, the block axis is minor, and each program
-  reduces its own ``(block,)`` cost vector, so the broker's stacked
-  flush runs with ZERO materialized ``(Q, chunk)`` cost matrix (the jax
-  backend's vmap builds one per chunk).
+  stacked requests as a 2-D grid over ``(query, block)``: each program
+  reads its query's params from SMEM, the block axis is minor, and each
+  program reduces its own block of costs, so the broker's stacked flush
+  runs with ZERO materialized ``(Q, chunk)`` cost matrix (the jax
+  backend's vmap builds one per chunk).  ``build_scan_sharded`` runs the
+  same kernel on every device of the plan mesh over its own span.
 * ``_scan_many_unrolled_kernel`` — the same stacked scan with the query
   axis unrolled *inside* the block body (config decode shared across all
   Q lanes).  This is the interpret-mode variant: Pallas interpret lowers
@@ -73,18 +81,15 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
-
-try:                                       # moved out of experimental in
-    from jax import shard_map              # newer jax releases
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.registry import hot_path
-from repro.core.cluster import ClusterConditions, PlanningStats
+from repro.core.cluster import ClusterConditions, ConfigColumns, PlanningStats
 from repro.core.planning_backend import (  # noqa: F401 (re-exported types)
     DEFAULT_CHUNK, BatchCostFn, JaxPlanBackend, Result, _decode_flat,
-    _neighbor_offsets, _pad_even, grid_arrays, start_indices)
+    _neighbor_offsets, _pad_even, _pad_multiple, grid_arrays, start_indices)
 from repro.obs import get_tracer
 
 _obs = get_tracer()
@@ -94,6 +99,10 @@ _obs = get_tracer()
 MAX_FLAT = 1 << 31
 # query lanes per unrolled interpret-mode program (bounds trace size)
 UNROLL_Q = 64
+# the TPU's f32/int32 vreg tile is SUBLANES x LANES; compiled scan blocks
+# are whole tiles
+SUBLANES, LANES = 8, 128
+_INT32_MAX = np.iinfo(np.int32).max
 
 
 # ----------------------------- in-kernel decode ----------------------------- #
@@ -115,13 +124,25 @@ def _dim_sizes(cluster: ClusterConditions) -> Tuple[int, ...]:
     return tuple(len(d.grid()) for d in cluster.dims)
 
 
-def _iota1(n: int):
-    """(n,) int32 iota — TPU requires >= 2-D generation."""
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).squeeze(-1)
+def _tile(block: int) -> Tuple[int, int]:
+    """The 2-D shape one block of ``block`` flat rows is computed in:
+    whole 128-lane rows when ``block`` is a multiple of 128 (compiled
+    blocks are whole (8, 128) vreg tiles), else one row (the small blocks
+    of interpret-mode tests)."""
+    return (block // LANES, LANES) if block % LANES == 0 else (1, block)
+
+
+def _flat_ids(start, tile):
+    """``tile``-shaped int32 flat row ids ``start + r * lanes + l``.  They
+    ascend in row-major order, so the lowest id attaining a tile's minimum
+    is its first minimum in ``enumerate_configs`` order."""
+    r = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+    return start + r * tile[1] + lane
 
 
 def _value_of_index(idx, meta):
-    """One dimension's (N,) grid indices -> (N,) int32 config values."""
+    """One dimension's grid indices -> int32 config values (same shape)."""
     if meta[0] == "affine":
         _, lo, step = meta
         return (lo + step * idx).astype(jnp.int32)
@@ -132,26 +153,17 @@ def _value_of_index(idx, meta):
     return col
 
 
-def _decode_configs(flat, metas, sizes):
-    """(N,) int32 flat row ids -> (N, n_dims) int32 config values in
+def _decode_columns(flat, metas, sizes):
+    """Flat row ids -> one array of config values per dimension, in
     ``enumerate_configs`` order (row-major, first dim slowest), decoded
-    by a divmod chain from the fastest dim up."""
+    by a divmod chain by the static dim sizes from the fastest dim up."""
     cols = [None] * len(sizes)
     rem = flat
-    for d in range(len(sizes) - 1, -1, -1):
-        if d == 0:
-            idx = rem
-        else:
-            idx = rem % sizes[d]
-            rem = rem // sizes[d]
-        cols[d] = _value_of_index(idx, metas[d])
-    return jnp.stack(cols, axis=1)
-
-
-def _values_of_indices(idx2d, metas):
-    """(N, n_dims) grid indices -> (N, n_dims) int32 config values."""
-    return jnp.stack([_value_of_index(idx2d[:, d], metas[d])
-                      for d in range(len(metas))], axis=1)
+    for d in range(len(sizes) - 1, 0, -1):
+        cols[d] = _value_of_index(rem % sizes[d], metas[d])
+        rem = rem // sizes[d]
+    cols[0] = _value_of_index(rem, metas[0])
+    return cols
 
 
 # --------------------------- closure hoisting ------------------------------- #
@@ -164,31 +176,38 @@ def _values_of_indices(idx2d, metas):
 # from python/numpy scalars (every cost model in this repo) embed them as
 # jaxpr literals and hoist zero constants.
 
-def _split_cost_fn(fn: BatchCostFn, n_rows: int, n_dims: int,
+def _split_cost_fn(fn: BatchCostFn, tile: Tuple[int, int], n_dims: int,
                    p_width: int, has_params: bool):
-    """-> (call(cfgs, p, const_vals) -> (n_rows,) costs, const_ins,
-    const_shapes)."""
+    """-> (call(cols, ps, const_vals) -> ``tile``-shaped f32 costs,
+    const_ins, const_shapes).
+
+    The fn is traced on a ``ConfigColumns`` of ``tile``-shaped int32
+    columns, one per dimension, and on ``p_width`` f32 scalar params: the
+    kernels read params from SMEM one scalar at a time, and a cost fn
+    indexes ``params[k]`` either way."""
     from jax import core as jax_core
-    cfgs_ex = jax.ShapeDtypeStruct((n_rows, n_dims), jnp.int32)
-    p_ex = jax.ShapeDtypeStruct((p_width,), jnp.float32)
+    cols_ex = [jax.ShapeDtypeStruct(tile, jnp.int32)] * n_dims
+    ps_ex = [jax.ShapeDtypeStruct((), jnp.float32)] * p_width
+    size = tile[0] * tile[1]
+
+    def traced(cols, ps):
+        cfgs = ConfigColumns(cols)
+        return fn(cfgs, ps) if has_params else fn(cfgs)
+
     # the jaxpr pre-trace is the kernel-build cost worth seeing in a
     # trace: program assembly around it is cheap python
     with _obs.span("pallas.pretrace", cat="compile") as sp:
-        if has_params:
-            cj = jax.make_jaxpr(lambda c, p: fn(c, p))(cfgs_ex, p_ex)
-
-            def call(cfgs, p, const_vals):
-                out, = jax_core.eval_jaxpr(cj.jaxpr, const_vals, cfgs, p)
-                return out.astype(jnp.float32)
-        else:
-            cj = jax.make_jaxpr(lambda c: fn(c))(cfgs_ex)
-
-            def call(cfgs, p, const_vals):
-                out, = jax_core.eval_jaxpr(cj.jaxpr, const_vals, cfgs)
-                return out.astype(jnp.float32)
+        cj = jax.make_jaxpr(traced)(cols_ex, ps_ex)
         if sp:
-            sp.set(rows=n_rows, dims=n_dims,
+            sp.set(rows=size, dims=n_dims,
                    params=p_width if has_params else 0)
+
+    def call(cols, ps, const_vals):
+        out, = jax_core.eval_jaxpr(cj.jaxpr, const_vals, *cols, *ps)
+        # a cost fn that indexes rows of the stacked (N, n_dims) array
+        # returns a flat (N,) vector
+        return out.astype(jnp.float32).reshape(tile)
+
     ins, shapes = [], []
     for c in cj.consts:
         arr = jnp.asarray(c)
@@ -212,57 +231,77 @@ def _const_values(const_refs, shapes):
     return [r[...].reshape(s) for r, s in zip(const_refs, shapes)]
 
 
+def _smem():
+    """Whole-array SMEM spec: params, offsets and the carried scalar
+    accumulators (vector memory refuses scalar stores)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 # ------------------------------ scan kernels -------------------------------- #
 
-def _fold_block(costs, start, j32_of, cost_acc, idx_acc):
-    """Reduce one block's (block,) cost vector and fold it into the
-    carried accumulator refs: argmin first (first-minimum tie-breaking),
-    then a single dynamic gather of the winning cost (one reduction pass
-    instead of min+argmin), then a strict-< update — ascending block
-    order makes the carried winner the first global minimum in
-    ``enumerate_configs`` order."""
-    j = jnp.argmin(costs).astype(jnp.int32)
-    c = costs[j]
-    better = c < cost_acc[j32_of]
-    idx_acc[j32_of] = jnp.where(better, start + j, idx_acc[j32_of])
-    cost_acc[j32_of] = jnp.where(better, c, cost_acc[j32_of])
+def _fold_block(costs, flat, q, cost_acc, idx_acc):
+    """Reduce one block and fold it into slot ``q`` of the carried SMEM
+    accumulators: the block minimum, then the lowest flat id attaining
+    it (first-minimum tie-breaking within the block, with no dynamic
+    gather), then a strict-< update — ascending block order makes the
+    carried winner the first global minimum in ``enumerate_configs``
+    order."""
+    c = jnp.min(costs)
+    j = jnp.min(jnp.where(costs == c, flat, _INT32_MAX))
+    better = c < cost_acc[q]
+    idx_acc[q] = jnp.where(better, j, idx_acc[q])
+    cost_acc[q] = jnp.where(better, c, cost_acc[q])
 
 
-def _scan_kernel(params_ref, *refs, cost, shapes, metas, sizes,
-                 total, block, lo0, masked, grid_axis):
-    """One grid block: cost rows [lo0 + b*block, +block) and fold them
-    into the carried (best_cost, best_idx) accumulator living in the
-    revisited (1, 1) output blocks.  ``lo0`` is static: the interpret
-    path bakes one executable per chunk so XLA:CPU runs chunks
-    concurrently; the TPU path runs lo0=0 with the full grid."""
-    const_refs, (cost_ref, idx_ref) = refs[:-2], refs[-2:]
-    b = pl.program_id(grid_axis)
+def _block_columns(start, tile, metas, sizes, total, masked):
+    """-> (flat ids, in-grid mask or None, decoded config columns) of the
+    block starting at flat row ``start``; rows past the grid decode as
+    row 0 and are masked to inf by the caller."""
+    flat = _flat_ids(start, tile)
+    ok = flat < total if masked else None
+    cols = _decode_columns(jnp.where(ok, flat, 0) if masked else flat,
+                           metas, sizes)
+    return flat, ok, cols
+
+
+def _scan_kernel(*refs, cost, shapes, metas, sizes, total, tile, lo0,
+                 masked, many, p_width):
+    """One grid block: cost rows [start, start + block) and fold them
+    into the (best_cost, best_idx) accumulator of the block's query,
+    carried in SMEM across the sequential grid.  ``lo0`` is the static
+    first row (the interpret path bakes one executable per chunk so
+    XLA:CPU runs chunks concurrently; the compiled path runs lo0=0 with
+    the full grid), or None to read it from a leading ``(1,)`` SMEM input
+    (one executable serves every shard of the sharded scan)."""
+    if lo0 is None:
+        lo0, refs = refs[0][0], refs[1:]
+    params_ref, const_refs, (cost_ref, idx_ref) = \
+        refs[0], refs[1:-2], refs[-2:]
+    q = pl.program_id(0) if many else 0
+    b = pl.program_id(1 if many else 0)
 
     @pl.when(b == 0)
     def _init():
-        cost_ref[0, 0] = jnp.float32(jnp.inf)
-        idx_ref[0, 0] = jnp.int32(-1)
+        cost_ref[q] = jnp.float32(jnp.inf)
+        idx_ref[q] = jnp.int32(-1)
 
-    start = lo0 + b * block
-    flat = start + _iota1(block)
-    if masked:                              # tail block: rows past the grid
-        ok = flat < total
-        cfgs = _decode_configs(jnp.where(ok, flat, 0), metas, sizes)
-    else:
-        cfgs = _decode_configs(flat, metas, sizes)
-    costs = cost(cfgs, params_ref[0, :], _const_values(const_refs, shapes))
+    flat, ok, cols = _block_columns(lo0 + b * (tile[0] * tile[1]), tile,
+                                    metas, sizes, total, masked)
+    ps = [params_ref[q * p_width + k] for k in range(p_width)]
+    costs = cost(cols, ps, _const_values(const_refs, shapes))
     if masked:
         costs = jnp.where(ok, costs, jnp.inf)
-    _fold_block(costs, start, (0, 0), cost_ref, idx_ref)
+    _fold_block(costs, flat, q, cost_ref, idx_ref)
 
 
-def _scan_many_unrolled_kernel(params_ref, *refs, cost, shapes,
-                               metas, sizes, total, block, lo0, nq, masked):
+def _scan_many_unrolled_kernel(params_ref, *refs, cost, shapes, metas,
+                               sizes, total, tile, lo0, nq, masked,
+                               p_width):
     """Q stacked requests with the query axis unrolled inside the block
     body: the config block is decoded ONCE and shared by all Q cost
     evaluations (the jax backend hoists enumeration out of its vmap the
     same way).  Interpret-mode variant — every per-query cost op stays a
-    top-level (block,) op that XLA:CPU can multi-thread."""
+    top-level op that XLA:CPU can multi-thread."""
     const_refs, (cost_ref, idx_ref) = refs[:-2], refs[-2:]
     b = pl.program_id(0)
 
@@ -272,162 +311,146 @@ def _scan_many_unrolled_kernel(params_ref, *refs, cost, shapes,
             cost_ref[q] = jnp.float32(jnp.inf)
             idx_ref[q] = jnp.int32(-1)
 
-    start = lo0 + b * block
-    flat = start + _iota1(block)
-    if masked:
-        ok = flat < total
-        cfgs = _decode_configs(jnp.where(ok, flat, 0), metas, sizes)
-    else:
-        cfgs = _decode_configs(flat, metas, sizes)
+    flat, ok, cols = _block_columns(lo0 + b * (tile[0] * tile[1]), tile,
+                                    metas, sizes, total, masked)
     const_vals = _const_values(const_refs, shapes)
     for q in range(nq):
-        costs = cost(cfgs, params_ref[q, :], const_vals)
+        ps = [params_ref[q * p_width + k] for k in range(p_width)]
+        costs = cost(cols, ps, const_vals)
         if masked:
             costs = jnp.where(ok, costs, jnp.inf)
-        _fold_block(costs, start, q, cost_ref, idx_ref)
+        _fold_block(costs, flat, q, cost_ref, idx_ref)
 
 
 def _neighbor_kernel(cur_ref, params_ref, *refs, cost, shapes, metas,
-                     sizes_t, n_dims, n_starts):
+                     sizes_t, n_dims, p_width):
     """The ensemble-climb neighbor-costing step (Algorithm 1's inner
     batch): cost the S current positions and all their 2*n_dims ±1
-    neighbors (ONE fused cost evaluation over S*(2D+1) rows), mask
-    out-of-grid steps to inf, and reduce each start's best neighbor
-    (first-minimum tie-breaking over the fixed ``_neighbor_offsets``
-    order) — one program per climb step."""
+    neighbors in ONE fused cost evaluation, mask out-of-grid steps to
+    inf, and reduce each start's best neighbor (first-minimum
+    tie-breaking over the fixed ``_neighbor_offsets`` order) — one
+    program per climb step.
+
+    Starts lie on lanes (``cur_ref`` is ``(n_dims, S)``) and neighbor
+    slots on sublanes: row 0 is the center, row ``1 + 2d`` the -1 step
+    and row ``2 + 2d`` the +1 step of dim d, exactly the
+    ``_neighbor_offsets`` order; rows past ``2 * n_dims`` pad the tile
+    to whole sublane groups and never win."""
     const_refs = refs[:-3]
     center_ref, best_c_ref, best_j_ref = refs[-3:]
-    cur = cur_ref[...]                                     # (S, D) indices
-    p = params_ref[0, :]
-    # neighbors are built per (dim, ±1) slot from scalar literals (kernels
-    # cannot capture array constants), in exactly the _neighbor_offsets
-    # order the host-side move/tie-break logic assumes
-    groups = [cur]                                         # slot -1: centers
-    valids = []
+    n_slots = 2 * n_dims
+    tile = (_pad_multiple(n_slots + 1, SUBLANES), cur_ref.shape[1])
+    slot = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+    valid = None
+    cols = []
     for d in range(n_dims):
-        for delta in (-1, 1):
-            idx = cur[:, d] + delta
-            valids.append((idx >= 0) & (idx < sizes_t[d]))
-            safe = jnp.clip(idx, 0, sizes_t[d] - 1)
-            groups.append(jnp.stack(
-                [safe if dd == d else cur[:, dd]
-                 for dd in range(n_dims)], axis=1))
-    rows = jnp.concatenate(groups, axis=0)                 # ((2D+1)*S, D)
-    costs = cost(_values_of_indices(rows, metas), p,
-                 _const_values(const_refs, shapes))
-    center_ref[...] = costs[:n_starts]
-    # slot-major concat -> (S, 2D) with columns in _neighbor_offsets order
-    ncosts = costs[n_starts:].reshape(2 * n_dims, n_starts).T
-    ncosts = jnp.where(jnp.stack(valids, axis=1), ncosts, jnp.inf)
-    best_c_ref[...] = jnp.min(ncosts, axis=1)
-    best_j_ref[...] = jnp.argmin(ncosts, axis=1).astype(jnp.int32)
+        step = jnp.where(slot == 1 + 2 * d, -1,
+                         jnp.where(slot == 2 + 2 * d, 1, 0))
+        idx = cur_ref[d:d + 1, :] + step                   # (rows, S)
+        ok = (idx >= 0) & (idx < sizes_t[d])
+        valid = ok if valid is None else valid & ok
+        cols.append(_value_of_index(jnp.clip(idx, 0, sizes_t[d] - 1),
+                                    metas[d]))
+    ps = [params_ref[k] for k in range(p_width)]
+    costs = cost(cols, ps, _const_values(const_refs, shapes))
+    center_ref[...] = costs[0:1, :]
+    is_nbr = (slot >= 1) & (slot <= n_slots)
+    ncosts = jnp.where(is_nbr & valid, costs, jnp.inf)
+    best = jnp.min(ncosts, axis=0, keepdims=True)
+    best_c_ref[...] = best
+    best_j_ref[...] = jnp.min(
+        jnp.where(is_nbr & (ncosts == best), slot - 1, n_slots),
+        axis=0, keepdims=True)
 
 
 # ------------------------------ call builders ------------------------------- #
+
+def _scan_call(fn: BatchCostFn, cluster: ClusterConditions, *, block: int,
+               nb: int, nq: int, lo0: Optional[int], has_params: bool,
+               p_width: int, masked: bool, interpret: bool):
+    """The fused scan ``pallas_call`` over ``nb`` blocks starting at flat
+    row ``lo0`` (static), or at a traced offset passed as a leading
+    ``(1,)`` input when ``lo0`` is None, plus its hoisted const inputs.
+    Params arrive flat, ``(max(1, nq) * p_width,)``; both outputs are
+    ``(max(1, nq),)``.
+
+    ``nq == 0``: one request, 1-D grid of ``nb`` blocks.
+    ``nq > 0``: Q stacked requests as a 2-D grid over (query, block) —
+    block axis minor so each row's carried accumulator completes before
+    the next row starts.  No (Q, chunk) cost matrix exists anywhere:
+    every program reduces its own block of costs in VMEM."""
+    tile = _tile(block)
+    cost, const_ins, shapes = _split_cost_fn(
+        fn, tile, cluster.n_dims, p_width, has_params or nq > 0)
+    many = nq > 0
+    kernel = functools.partial(
+        _scan_kernel, cost=cost, shapes=shapes, metas=_dim_meta(cluster),
+        sizes=_dim_sizes(cluster), total=cluster.grid_size(), tile=tile,
+        lo0=lo0, masked=masked, many=many, p_width=p_width)
+    rows = max(1, nq)
+    call = pl.pallas_call(
+        kernel,
+        grid=(nq, nb) if many else (nb,),
+        in_specs=[_smem()] * (2 if lo0 is None else 1)
+        + _const_specs(const_ins),
+        out_specs=[_smem(), _smem()],
+        out_shape=[jax.ShapeDtypeStruct((rows,), jnp.float32),
+                   jax.ShapeDtypeStruct((rows,), jnp.int32)],
+        interpret=interpret,
+    )
+    return call, const_ins
+
 
 @hot_path("builds the fused scan program the per-chunk dispatch loop runs")
 def build_scan(fn: BatchCostFn, cluster: ClusterConditions, *, block: int,
                nb: int, nq: int, lo0: int, has_params: bool, p_width: int,
                masked: bool, interpret: bool):
     """Jitted fused scan ``scan(params) -> (costs, idx)`` over ``nb``
-    blocks starting at static flat row ``lo0``.
-
-    ``nq == 0``: one request, 1-D grid of ``nb`` blocks, (1, 1) outputs.
-    ``nq > 0``: Q stacked requests as a 2-D grid over (query, block) —
-    params blocked per query row, block axis minor so each row's carried
-    accumulator completes before the next row starts; (Q, 1) outputs.
-    No (Q, chunk) cost matrix exists anywhere: every program reduces its
-    own (block,) cost vector in VMEM."""
-    cost, const_ins, shapes = _split_cost_fn(
-        fn, block, cluster.n_dims, p_width, has_params or nq > 0)
-    many = nq > 0
-    kernel = functools.partial(
-        _scan_kernel, cost=cost, shapes=shapes, metas=_dim_meta(cluster),
-        sizes=_dim_sizes(cluster), total=cluster.grid_size(), block=block,
-        lo0=lo0, masked=masked, grid_axis=1 if many else 0)
-    if many:
-        p_spec = pl.BlockSpec((1, p_width), lambda q, b: (q, 0))
-        out_spec = pl.BlockSpec((1, 1), lambda q, b: (q, 0))
-    else:
-        p_spec = pl.BlockSpec((1, p_width), lambda b: (0, 0))
-        out_spec = pl.BlockSpec((1, 1), lambda b: (0, 0))
-    rows = max(1, nq)
-    call = pl.pallas_call(
-        kernel,
-        grid=(nq, nb) if many else (nb,),
-        in_specs=[p_spec] + _const_specs(const_ins),
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.int32)],
-        interpret=interpret,
-    )
+    blocks starting at static flat row ``lo0`` (see ``_scan_call``)."""
+    call, const_ins = _scan_call(
+        fn, cluster, block=block, nb=nb, nq=nq, lo0=lo0,
+        has_params=has_params, p_width=p_width, masked=masked,
+        interpret=interpret)
     return jax.jit(lambda p: call(p, *const_ins))
-
-
-def _scan_kernel_dyn(off_ref, params_ref, *refs, cost, shapes, metas,
-                     sizes, total, block, masked, grid_axis):
-    """``_scan_kernel`` with the chunk offset as a traced ``(1,)`` input
-    instead of a static ``lo0``: the sharded dispatch path feeds every
-    device its own offset through ``shard_map``, so ONE executable serves
-    every shard of the mesh."""
-    _scan_kernel(params_ref, *refs, cost=cost, shapes=shapes, metas=metas,
-                 sizes=sizes, total=total, block=block, lo0=off_ref[0],
-                 masked=masked, grid_axis=grid_axis)
 
 
 @hot_path("builds the sharded scan program one dispatch spreads over the mesh")
 def build_scan_sharded(fn: BatchCostFn, cluster: ClusterConditions, *,
-                       block: int, nb_shard: int, n_dev: int,
+                       block: int, nb_shard: int, n_dev: int, nq: int,
                        has_params: bool, p_width: int, mesh,
                        interpret: bool):
-    """Jitted fused scan ``scan(params) -> (cost, flat)`` over the whole
-    grid, partitioned across ``n_dev`` devices: each device runs the SAME
-    single executable over its own ``nb_shard * block``-row span (its
-    start offset arriving as a traced scalar through ``shard_map``),
-    carrying its per-shard (best_cost, best_idx) accumulator exactly like
-    the unsharded kernel.  The cross-shard fold — ``jnp.argmin`` over the
-    ``(n_dev,)`` per-shard bests, first minimum = lowest device = lowest
-    flat rows (spans are contiguous and ascending) — happens inside the
-    program, so the result is bit-identical to the single-device scan and
-    ONE host sync reads it back.  Every block is masked (``flat < total``)
-    because one uniform executable must also cover the ragged last
-    shard."""
-    cost, const_ins, shapes = _split_cost_fn(
-        fn, block, cluster.n_dims, p_width, has_params)
-    kernel = functools.partial(
-        _scan_kernel_dyn, cost=cost, shapes=shapes, metas=_dim_meta(cluster),
-        sizes=_dim_sizes(cluster), total=cluster.grid_size(), block=block,
-        masked=True, grid_axis=0)
-    call = pl.pallas_call(
-        kernel,
-        grid=(nb_shard,),
-        in_specs=[pl.BlockSpec((1,), lambda b: (0,)),
-                  pl.BlockSpec((1, p_width), lambda b: (0, 0))]
-        + _const_specs(const_ins),
-        out_specs=[pl.BlockSpec((1, 1), lambda b: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda b: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
-        interpret=interpret,
-    )
+    """Jitted fused scan ``scan(params) -> (costs, idx)`` over the whole
+    grid for ``max(1, nq)`` requests, partitioned across ``n_dev``
+    devices: each device runs the SAME single executable over its own
+    ``nb_shard * block``-row span (its start offset arriving as a traced
+    scalar through ``shard_map``), carrying its per-shard (best_cost,
+    best_idx) accumulators exactly like the unsharded kernel.  The
+    cross-shard fold — ``jnp.argmin`` over the per-shard bests of each
+    request, first minimum = lowest device = lowest flat rows (spans are
+    contiguous and ascending) — happens inside the program, so the result
+    is bit-identical to the single-device scan and ONE host sync reads it
+    back.  Every block is masked (``flat < total``) because one uniform
+    executable must also cover the ragged last shard."""
+    call, const_ins = _scan_call(
+        fn, cluster, block=block, nb=nb_shard, nq=nq, lo0=None,
+        has_params=has_params, p_width=p_width, masked=True,
+        interpret=interpret)
+    rows = max(1, nq)
     PS = jax.sharding.PartitionSpec
-
-    def shard_body(off, p):
-        c, f = call(off, p, *const_ins)
-        return c[0], f[0]                      # one (1,) row per shard
-
-    # check_rep=False: there is no replication rule for pallas_call, and
+    # check_vma=False: pallas_call has no varying-manual-axes rule, and
     # both outputs are genuinely sharded over "plan" anyway
-    shard = shard_map(shard_body, mesh=mesh,
+    shard = shard_map(lambda off, p: call(off, p, *const_ins), mesh=mesh,
                       in_specs=(PS("plan"), PS()),
                       out_specs=(PS("plan"), PS("plan")),
-                      check_rep=False)
+                      check_vma=False)
     offs = jnp.arange(n_dev, dtype=jnp.int32) * (nb_shard * block)
 
     def run(p):
         cs, fs = shard(offs, p)
-        k = jnp.argmin(cs)                     # first min: lowest device
-        return cs[k], fs[k]
+        cs, fs = cs.reshape(n_dev, rows), fs.reshape(n_dev, rows)
+        k = jnp.argmin(cs, axis=0)[None]       # first min: lowest device
+        return (jnp.take_along_axis(cs, k, 0)[0],
+                jnp.take_along_axis(fs, k, 0)[0])
 
     return jax.jit(run)
 
@@ -438,20 +461,19 @@ def build_scan_many_unrolled(fn: BatchCostFn, cluster: ClusterConditions, *,
                              p_width: int, masked: bool, interpret: bool):
     """Jitted stacked scan with the query axis unrolled in the body:
     ``scan(params) -> ((Q,) costs, (Q,) idx)``."""
+    tile = _tile(block)
     cost, const_ins, shapes = _split_cost_fn(
-        fn, block, cluster.n_dims, p_width, True)
+        fn, tile, cluster.n_dims, p_width, True)
     kernel = functools.partial(
         _scan_many_unrolled_kernel, cost=cost, shapes=shapes,
         metas=_dim_meta(cluster), sizes=_dim_sizes(cluster),
-        total=cluster.grid_size(), block=block, lo0=lo0, nq=nq,
-        masked=masked)
+        total=cluster.grid_size(), tile=tile, lo0=lo0, nq=nq,
+        masked=masked, p_width=p_width)
     call = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((nq, p_width), lambda b: (0, 0))]
-        + _const_specs(const_ins),
-        out_specs=[pl.BlockSpec((nq,), lambda b: (0,)),
-                   pl.BlockSpec((nq,), lambda b: (0,))],
+        in_specs=[_smem()] + _const_specs(const_ins),
+        out_specs=[_smem(), _smem()],
         out_shape=[jax.ShapeDtypeStruct((nq,), jnp.float32),
                    jax.ShapeDtypeStruct((nq,), jnp.int32)],
         interpret=interpret,
@@ -463,28 +485,28 @@ def build_scan_many_unrolled(fn: BatchCostFn, cluster: ClusterConditions, *,
 def build_neighbor_step(fn: BatchCostFn, cluster: ClusterConditions, *,
                         n_starts: int, has_params: bool, p_width: int,
                         interpret: bool):
-    """Jitted ``step(cur_idx, params) -> (center, best_cost, best_j)``."""
+    """Jitted ``step(cur_idx_t, params) -> (center, best_cost, best_j)``
+    over the ``(n_dims, S)`` transposed start indices; every output is
+    ``(1, S)``."""
     n_dims = cluster.n_dims
-    n_rows = n_starts * (2 * n_dims + 1)
+    tile = (_pad_multiple(2 * n_dims + 1, SUBLANES), n_starts)
     cost, const_ins, shapes = _split_cost_fn(
-        fn, n_rows, n_dims, p_width, has_params)
+        fn, tile, n_dims, p_width, has_params)
     kernel = functools.partial(
         _neighbor_kernel, cost=cost, shapes=shapes, metas=_dim_meta(cluster),
-        sizes_t=_dim_sizes(cluster), n_dims=n_dims, n_starts=n_starts)
+        sizes_t=_dim_sizes(cluster), n_dims=n_dims, p_width=p_width)
+    row = pl.BlockSpec((1, n_starts), lambda: (0, 0))
     call = pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec((n_starts, n_dims), lambda: (0, 0)),
-                  pl.BlockSpec((1, p_width), lambda: (0, 0))]
-        + _const_specs(const_ins),
-        out_specs=[pl.BlockSpec((n_starts,), lambda: (0,)),
-                   pl.BlockSpec((n_starts,), lambda: (0,)),
-                   pl.BlockSpec((n_starts,), lambda: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((n_starts,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_starts,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_starts,), jnp.int32)],
+        in_specs=[pl.BlockSpec((n_dims, n_starts), lambda: (0, 0)),
+                  _smem()] + _const_specs(const_ins),
+        out_specs=[row, row, row],
+        out_shape=[jax.ShapeDtypeStruct((1, n_starts), jnp.float32),
+                   jax.ShapeDtypeStruct((1, n_starts), jnp.float32),
+                   jax.ShapeDtypeStruct((1, n_starts), jnp.int32)],
         interpret=interpret,
     )
-    return jax.jit(lambda cur, p: call(cur, p, *const_ins))
+    return jax.jit(lambda cur_t, p: call(cur_t, p, *const_ins))
 
 
 # ------------------------------ the backend --------------------------------- #
@@ -498,10 +520,11 @@ class PallasPlanBackend(JaxPlanBackend):
     through scalar float64, exactly as for ``get_backend("jax")``).
 
     Geometry: on TPU one ``pallas_call`` covers the whole grid —
-    ``block`` rows per program (default 32K ≈ 1.5 MB of f32 temporaries,
-    comfortably inside the ~16 MB VMEM even for cost surfaces with a
-    dozen live intermediates), grid steps iterating sequentially with
-    the argmin accumulator carried in the revisited output block.  In
+    ``block`` rows per program (default 32K, a (256, 128) tile of 128 KB
+    per f32 temporary, comfortably inside the ~16 MB VMEM even for cost
+    surfaces with a dozen live intermediates; smaller grids round up to
+    whole (8, 128) tiles), grid steps iterating sequentially with the
+    argmin accumulator carried in SMEM.  In
     interpret mode (any non-TPU host) multi-step grids would lower to a
     single-threaded XLA loop, so the wrapper instead dispatches one
     single-block program per ``block``-row chunk (default 2M rows),
@@ -516,9 +539,10 @@ class PallasPlanBackend(JaxPlanBackend):
     over the plan mesh — params are pre-placed on every device so chunk i
     dispatches on device ``i % D``, and the per-chunk winners hop back to
     device 0 (async copies) before the single stacked fold, which stays
-    the one host sync.  The compiled single-request path instead builds
-    ONE sharded executable (``build_scan_sharded``): per-device offsets
-    travel through ``shard_map`` and the cross-shard fold runs in-program.
+    the one host sync.  The compiled paths instead build ONE sharded
+    executable (``build_scan_sharded``), single-request and stacked
+    alike: per-device offsets travel through ``shard_map`` and the
+    cross-shard fold runs in-program.
     ``shard_variant`` forces a strategy ("roundrobin"/"shardmap"/"off");
     "auto" picks round-robin under interpret, shard_map when compiled.
     Neither changes results: spans stay contiguous/ascending so the fold
@@ -571,11 +595,23 @@ class PallasPlanBackend(JaxPlanBackend):
         return jax.local_devices()[:self.device_count()]
 
     def _params32(self, params, p_width: int) -> jnp.ndarray:
-        p = np.zeros((1, p_width), dtype=np.float32)
+        p = np.zeros(p_width, dtype=np.float32)
         if params is not None:
             arr = np.asarray(params, dtype=np.float32).ravel()
-            p[0, :arr.size] = arr
+            p[:arr.size] = arr
         return jnp.asarray(p)
+
+    def _block_rows(self, total: int) -> int:
+        """Rows per kernel block for a ``total``-row grid: compiled blocks
+        are whole (8, 128) tiles, rows past the grid masked to inf."""
+        block = int(min(self.block, total))
+        return block if self.interpret else \
+            _pad_multiple(block, SUBLANES * LANES)
+
+    def _fits_int32(self, total: int, block: int) -> bool:
+        """int32 row ids: the padded tail blocks of every shard reach up
+        to ``total + D * block - 1``, which must not wrap negative."""
+        return total <= MAX_FLAT - block * self.device_count()
 
     @staticmethod
     def _result(cluster: ClusterConditions, flat: int, cost: float) -> Result:
@@ -600,12 +636,10 @@ class PallasPlanBackend(JaxPlanBackend):
         total = cluster.grid_size()
         if total == 0:
             return None, math.inf
-        if total > MAX_FLAT - self.block:
-            # int32 row ids: the padded tail block reaches up to
-            # total + block - 1, which must not wrap negative
+        block = self._block_rows(total)
+        if not self._fits_int32(total, block):
             return super().argmin_grid(batch_cost_fn, cluster, stats,
                                        params=params, chunk_size=chunk_size)
-        block = int(min(self.block, total))
         has_params = params is not None
         p_width = max(1, 0 if params is None else np.size(params))
         p = self._params32(params, p_width)
@@ -638,37 +672,43 @@ class PallasPlanBackend(JaxPlanBackend):
                 d0 = devs[0]
                 outs = [(jax.device_put(c, d0), jax.device_put(f, d0))
                         for c, f in outs]
-            costs = np.asarray(jnp.stack([c for c, _ in outs]))[:, 0, 0]
-            flats = np.asarray(jnp.stack([f for _, f in outs]))[:, 0, 0]
+            costs = np.asarray(jnp.stack([c for c, _ in outs]))[:, 0]
+            flats = np.asarray(jnp.stack([f for _, f in outs]))[:, 0]
             k = int(np.argmin(costs))         # first min: lowest-lo chunk
             return self._result(cluster, int(flats[k]), float(costs[k]))
 
+        prog = self._compiled_scan(batch_cost_fn, cluster, block, 0,
+                                   has_params, p_width, mode)
+        c, f = prog(p)                        # one sync: float()/int()
+        return self._result(cluster, int(f[0]), float(c[0]))
+
+    def _compiled_scan(self, batch_cost_fn: BatchCostFn,
+                       cluster: ClusterConditions, block: int, nq: int,
+                       has_params: bool, p_width: int, mode: str):
+        """The one-dispatch scan program over the whole grid for
+        ``max(1, nq)`` requests: a sequential grid on one device, or with
+        ``mode == "shardmap"`` one sharded executable whose per-device
+        offsets travel through ``shard_map`` and whose cross-shard fold
+        runs in-program."""
+        total = cluster.grid_size()
         if mode == "shardmap":
-            # one sharded executable covering the whole grid: per-device
-            # offsets travel through shard_map, the cross-shard fold runs
-            # in-program, and this float()/int() pair is the single sync
             D = self.device_count()
             nbs = -(-total // (block * D))    # blocks per shard
-            prog = self._program(
+            return self._program(
                 "pscan_sh", batch_cost_fn, cluster,
-                (block, nbs, D, has_params, p_width, self.interpret),
+                (block, nbs, D, nq, has_params, p_width, self.interpret),
                 lambda: build_scan_sharded(
                     batch_cost_fn, cluster, block=block, nb_shard=nbs,
-                    n_dev=D, has_params=has_params, p_width=p_width,
+                    n_dev=D, nq=nq, has_params=has_params, p_width=p_width,
                     mesh=self._plan_mesh(), interpret=self.interpret))
-            c, f = prog(p)
-            return self._result(cluster, int(f), float(c))
-
         nb = -(-total // block)
-        prog = self._program(
-            "pscan", batch_cost_fn, cluster,
-            (block, nb, 0, 0, has_params, p_width, True, False),
+        return self._program(
+            "pscan_many" if nq else "pscan", batch_cost_fn, cluster,
+            (block, nb, nq, 0, has_params, p_width, True, self.interpret),
             lambda: build_scan(batch_cost_fn, cluster, block=block, nb=nb,
-                               nq=0, lo0=0, has_params=has_params,
+                               nq=nq, lo0=0, has_params=has_params,
                                p_width=p_width, masked=True,
-                               interpret=False))
-        c, f = prog(p)
-        return self._result(cluster, int(f[0, 0]), float(c[0, 0]))
+                               interpret=self.interpret))
 
     @hot_path("dispatches the stacked fused-kernel scan per flush",
               folds=5)  # params asarray + 2-site fold per many variant
@@ -692,7 +732,7 @@ class PallasPlanBackend(JaxPlanBackend):
         device dispatch applies to the per-chunk unrolled path exactly as
         in ``argmin_grid``; the compiled 2-D grid path stays one program
         (its per-query carried accumulators are already a single
-        dispatch)."""
+        dispatch), sharded over the plan mesh like ``argmin_grid``'s."""
         stats = stats if stats is not None else PlanningStats()
         pm = np.asarray(params_many, dtype=np.float64)
         Q, P = pm.shape
@@ -702,7 +742,8 @@ class PallasPlanBackend(JaxPlanBackend):
         if total == 0:
             res = [(None, math.inf)] * Q
             return lambda: res
-        if total > MAX_FLAT - self.block:     # tail padding must not wrap
+        block = self._block_rows(total)
+        if not self._fits_int32(total, block):
             return super().argmin_grid_many_async(batch_cost_fn, cluster,
                                                   pm, stats=stats,
                                                   chunk_size=chunk_size)
@@ -711,12 +752,11 @@ class PallasPlanBackend(JaxPlanBackend):
                 batch_cost_fn, cluster, pm[lo:lo + UNROLL_Q], stats=stats,
                 chunk_size=chunk_size) for lo in range(0, Q, UNROLL_Q)]
             return lambda: [r for fin in fins for r in fin()]
-        block = int(min(self.block, total))
         p_width = max(1, P)
         Qpad = _pad_even(Q)
         pmp = np.pad(pm, ((0, Qpad - Q), (0, 0)), mode="edge")
-        p = jnp.asarray(pmp.astype(np.float32)) if P else \
-            jnp.zeros((Qpad, 1), dtype=jnp.float32)
+        p = jnp.asarray(pmp.astype(np.float32).reshape(-1)) if P else \
+            jnp.zeros(Qpad, dtype=jnp.float32)
         stats.configs_explored += Q * total
 
         if self._use_unrolled():
@@ -748,22 +788,15 @@ class PallasPlanBackend(JaxPlanBackend):
                         for q in range(Q)]
             return finalize
 
-        nb = -(-total // block)
-        prog = self._program(
-            "pscan_many", batch_cost_fn, cluster,
-            (block, nb, Qpad, 0, p_width, True, self.interpret),
-            lambda: build_scan(
-                batch_cost_fn, cluster, block=block, nb=nb, nq=Qpad,
-                lo0=0, has_params=True, p_width=p_width, masked=True,
-                interpret=self.interpret))
+        prog = self._compiled_scan(batch_cost_fn, cluster, block, Qpad,
+                                   True, p_width, self._shard_mode())
         c, f = prog(p)
 
         def finalize() -> List[Result]:
-            costs = np.asarray(c).reshape(1, Qpad)[:, :Q]
-            flats = np.asarray(f).reshape(1, Qpad)[:, :Q]
-            k = np.argmin(costs, axis=0)
-            return [self._result(cluster, int(flats[k[q], q]),
-                                 float(costs[k[q], q])) for q in range(Q)]
+            costs = np.asarray(c)[:Q]
+            flats = np.asarray(f)[:Q]
+            return [self._result(cluster, int(flats[q]), float(costs[q]))
+                    for q in range(Q)]
         return finalize
 
     # -- ensemble climb on the fused neighbor step ---------------------------- #
@@ -798,12 +831,12 @@ class PallasPlanBackend(JaxPlanBackend):
 
         cur_cost = np.full(S, np.inf)
         for it in range(max_iters):
-            center, best_c, best_j = prog(jnp.asarray(cur, dtype=jnp.int32),
-                                          p)
+            center, best_c, best_j = prog(
+                jnp.asarray(cur.T, dtype=jnp.int32), p)
             # plan-lint: allow(host-sync): the climb is host-driven — each fused neighbor step must land before the move/stop decision; in-kernel while_loop fusion is the ROADMAP follow-up
-            center = np.asarray(center, dtype=np.float64)
-            best_c = np.asarray(best_c, dtype=np.float64)  # plan-lint: allow(host-sync): same per-iteration fold as the line above
-            best_j = np.asarray(best_j)
+            center = np.asarray(center, dtype=np.float64)[0]
+            best_c = np.asarray(best_c, dtype=np.float64)[0]  # plan-lint: allow(host-sync): same per-iteration fold as the line above
+            best_j = np.asarray(best_j)[0]
             nbr = cur[:, None, :] + offs[None, :, :]
             valid = ((nbr >= 0) & (nbr < sizes)).all(-1)
             stats.configs_explored += S + int(valid.sum())

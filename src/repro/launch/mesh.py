@@ -32,8 +32,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """make_mesh that tolerates len(jax.devices()) > prod(shape) and stays on
-    the pre-0.9 Auto axis-type behavior."""
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices with Auto
+    axis types; tolerates ``len(jax.devices()) > prod(shape)`` and raises
+    when there are too few devices."""
     n = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) < n:
@@ -41,13 +42,9 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py "
             "sets this automatically)")
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except Exception:
-        return jax.sharding.Mesh(
-            np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
 def plan_device_count() -> int:

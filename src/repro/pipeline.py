@@ -22,24 +22,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                   # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:                    # jax 0.4.x keeps it in experimental
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                # no shard_map at all: gate gpipe_apply
-        shard_map = None
-
-
-def _pcast(x, axes, to="varying"):
-    """jax.lax.pcast fallback: older jax (< 0.6) has no varying-over-axis
-    type tracking inside shard_map, so the cast is an identity there."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to=to)
-    return x
 
 
 def gpipe_apply(params_stacked: Any, x, body_fn: Callable, *, mesh,
@@ -53,10 +37,6 @@ def gpipe_apply(params_stacked: Any, x, body_fn: Callable, *, mesh,
                                      itself lax.scan over the local layers)
     Returns (B, S, d) with identical semantics to sequentially applying all
     L layers."""
-    if shard_map is None:
-        raise NotImplementedError(
-            "gpipe_apply needs shard_map (jax.shard_map or "
-            "jax.experimental.shard_map); this jax has neither")
     n_stages = mesh.shape[stage_axis]
     B = x.shape[0]
     assert B % n_micro == 0, (B, n_micro)
@@ -109,9 +89,9 @@ def gpipe_apply(params_stacked: Any, x, body_fn: Callable, *, mesh,
 
         # initial carries must carry the 'varying over stage_axis' type the
         # loop body produces (shard_map VMA tracking)
-        init_state = _pcast(zero, (stage_axis,), to="varying")
-        init_acc = _pcast(jnp.zeros_like(xs_local), (stage_axis,),
-                          to="varying")
+        init_state = jax.lax.pcast(zero, (stage_axis,), to="varying")
+        init_acc = jax.lax.pcast(jnp.zeros_like(xs_local), (stage_axis,),
+                                 to="varying")
         (state, out_acc), _ = jax.lax.scan(
             tick, (init_state, init_acc), jnp.arange(T))
         # every stage except the last holds zeros; psum broadcasts the result

@@ -67,10 +67,6 @@ print("PIPELINE_OK")
 
 @pytest.mark.slow
 def test_gpipe_matches_sequential():
-    from repro import pipeline
-    if pipeline.shard_map is None:
-        pytest.skip("this jax has neither jax.shard_map nor "
-                    "jax.experimental.shard_map")
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=1200, env={**os.environ},
                        cwd=ROOT)
