@@ -1,0 +1,5 @@
+"""``backend.programs_built`` (search programs built inside the window) in
+the cells whose throughput is ``plans_per_s.grid10m``."""
+from bench.spec import reader
+
+read = reader("backend.programs_built")

@@ -1,0 +1,9 @@
+"""Time (ms) the host blocked on device results (``broker.group.sync``
+spans) per service wave."""
+from bench.spans import total_ms
+
+
+def read(ctx):
+    if ctx.obs_spans is None or not ctx.window.waves:
+        return None
+    return total_ms(ctx.obs_spans, ("broker.group.sync",)) / ctx.window.waves
