@@ -99,8 +99,10 @@ def _telemetry_summary(sources: list) -> dict:
                     "requests": req.get("count", 0),
                     "request_p50_s": req.get("p50_s"),
                     "request_p99_s": req.get("p99_s"),
-                    "wave_assembly_mean_s":
-                        data.get("wave_assembly", {}).get("mean_s"),
+                    "wave_stage1_mean_s":
+                        data.get("wave_stage1", {}).get("mean_s"),
+                    "wave_dispatch_mean_s":
+                        data.get("wave_dispatch", {}).get("mean_s"),
                     "wave_execute_mean_s":
                         data.get("wave_execute", {}).get("mean_s"),
                     "wave_commit_mean_s":
@@ -108,7 +110,7 @@ def _telemetry_summary(sources: list) -> dict:
                     "waves": data.get("waves"),
                     "max_wave": data.get("max_wave"),
                     "programs_built": data.get("programs_built"),
-                    "programs_reused": data.get("programs_reused")}
+                    "compiles": data.get("compiles")}
         except (json.JSONDecodeError, TypeError):
             pass
     tracked = ROOT / "BENCH_telemetry.json"
@@ -117,9 +119,9 @@ def _telemetry_summary(sources: list) -> dict:
             data = json.loads(tracked.read_text())
             snap = (data.get("history") or [{}])[-1]
             keep = ("requests", "request_p50_s", "request_p99_s",
-                    "wave_assembly_mean_s", "wave_execute_mean_s",
-                    "wave_commit_mean_s", "waves", "max_wave",
-                    "programs_built", "programs_reused")
+                    "wave_stage1_mean_s", "wave_dispatch_mean_s",
+                    "wave_execute_mean_s", "wave_commit_mean_s", "waves",
+                    "max_wave", "programs_built", "compiles")
             out = {k: snap.get(k) for k in keep}
             out["source"] = "BENCH_telemetry.json (last snapshot)"
             return out

@@ -93,8 +93,9 @@ def run(quick: bool = False) -> List[Row]:
         if not quick:
             _append_history(summary)
 
-        req, asm = ws["request"], ws["wave_assembly"]
-        exe, com = ws["wave_execute"], ws["wave_commit"]
+        req, st1 = ws["request"], ws["wave_stage1"]
+        dis, exe, com = ws["wave_dispatch"], ws["wave_execute"], \
+            ws["wave_commit"]
         rows: List[Row] = [
             ("telemetry.wall_s", wall_s,
              f"traced {n_q}-query lockstep batch ({be})"),
@@ -102,8 +103,10 @@ def run(quick: bool = False) -> List[Row]:
              f"submit->resolve latency p50 over {req['count']} requests"),
             ("telemetry.request_p99_s", req.get("p99_s", 0.0),
              "submit->resolve latency p99"),
-            ("telemetry.wave_assembly_mean_s", asm.get("mean_s", 0.0),
-             "dedup+cache fronting+dispatch per wave"),
+            ("telemetry.wave_stage1_mean_s", st1.get("mean_s", 0.0),
+             "dedup+cache fronting per wave"),
+            ("telemetry.wave_dispatch_mean_s", dis.get("mean_s", 0.0),
+             "program launches per dispatched wave"),
             ("telemetry.wave_execute_mean_s", exe.get("mean_s", 0.0),
              "device execute (host sync) per dispatched wave"),
             ("telemetry.wave_commit_mean_s", com.get("mean_s", 0.0),
@@ -112,8 +115,8 @@ def run(quick: bool = False) -> List[Row]:
              f"flush waves (sizes {ws['wave_sizes']})"),
             ("telemetry.programs_built", float(ws["programs_built"]),
              "backend programs compiled during the run"),
-            ("telemetry.programs_reused", float(ws["programs_reused"]),
-             "program-memo hits during the run"),
+            ("telemetry.compiles", float(ws["compiles"]),
+             "XLA compiles (and compile-cache loads) during the run"),
             ("telemetry.trace_events", float(len(tr.events())),
              "events in artifacts/trace_lockstep.json"),
         ]
@@ -141,14 +144,15 @@ def _append_history(summary: dict) -> None:
         "requests": summary["requests"],
         "request_p50_s": req.get("p50_s"),
         "request_p99_s": req.get("p99_s"),
-        "wave_assembly_mean_s": summary["wave_assembly"].get("mean_s"),
+        "wave_stage1_mean_s": summary["wave_stage1"].get("mean_s"),
+        "wave_dispatch_mean_s": summary["wave_dispatch"].get("mean_s"),
         "wave_execute_mean_s": summary["wave_execute"].get("mean_s"),
         "wave_commit_mean_s": summary["wave_commit"].get("mean_s"),
         "waves": summary["waves"],
         "max_wave": summary["max_wave"],
         "mean_wave": summary["mean_wave"],
         "programs_built": summary["programs_built"],
-        "programs_reused": summary["programs_reused"],
+        "compiles": summary["compiles"],
     }
     history.append(snapshot)
     out.write_text(json.dumps(

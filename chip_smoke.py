@@ -120,7 +120,7 @@ def _serve(raqo, work, concurrency: int, warm: bool) -> tuple:
     tr, mx = get_tracer(), get_metrics()
     tr.reset()
     mx.reset()
-    tr.enable()                   # record_program events: builds in window
+    tr.enable()                   # the report's request split and spans
     try:
         svc = StreamingPlannerService(raqo)
         t0 = time.perf_counter()

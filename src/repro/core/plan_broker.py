@@ -93,6 +93,22 @@ a backend without the async split) degrades ``flush_async`` to
 every (fn, grid) group's program is dispatched before any group's
 results are read back, so e.g. a flush mixing SMJ and BHJ operators
 overlaps the two scans.
+
+Instrumentation (``repro.obs``): a wave is the spans ``broker.stage1``
+(dedup, memo and cache fronting), ``broker.dispatch`` (around each
+``broker.dispatch.group``), ``broker.wave.execute`` (the sync, around
+each ``broker.group.sync``) and ``broker.wave.commit``, all opened on
+the thread's span stack and all carrying the broker ``wave`` number, so
+a double-buffered wave's dispatch, sync and commit share an identifier.
+A synchronous flush opens ``broker.flush.sync`` with its ``cause``:
+``result`` (a ``PlanFuture.result()`` on a pending request), ``retry``
+(the ensemble -> grid ``scan_fallback`` rerun) or ``explicit`` (any
+other ``flush()`` caller).  Always on, one increment per flush or
+stacked group: ``broker.sync_flushes.<cause>`` and the grid rows each
+stacked scan group sweeps, once per group, by the kind of flush that
+dispatched it (``broker.sweep_rows.sync`` / ``broker.sweep_rows.async``).
+Traced, a request keeps its submit stamp, its verdict and its wave;
+the wave's stamps serve ``PlanFuture.critical_path()``.
 """
 from __future__ import annotations
 
@@ -120,60 +136,37 @@ _obs = get_tracer()
 _metrics = get_metrics()
 
 
-def _request_done(fut: "PlanFuture") -> None:
-    """Tracing-enabled path: stamp resolution and feed the per-request
-    latency histogram (submit -> resolve, the broker's tail metric)."""
-    now = time.perf_counter_ns()
-    fut.obs["resolve"] = now
-    _metrics.histogram("broker.request_s").observe(
-        (now - fut.obs["submit"]) / 1e9)
+class _Stamps:
+    """Tracing-on record of one broker wave, shared by every request it
+    carries: its number and the ``perf_counter_ns`` at which stage 1
+    ended, its programs were dispatched, its sync returned and its
+    commit ended (0 where the wave never got there)."""
+
+    __slots__ = ("wave", "stage1", "dispatch", "execute", "commit")
+
+    def __init__(self, wave: int):
+        self.wave = wave
+        self.stage1 = self.dispatch = self.execute = self.commit = 0
 
 
-def _wave_assembled(t0_ns: int, wave_no: int, size: int, leaders: int,
-                    order, pipelined: bool, dispatched: bool) -> None:
-    """Tracing-enabled path: close the wave-assembly span (stage 1 dedup
-    + stage 2 dispatch), stamp every future the wave carries, and open
-    the wave's async interval (closed at commit, so double-buffered
-    waves render as overlapping tracks in Perfetto)."""
-    _obs.complete("broker.wave", t0_ns, cat="broker", wave=wave_no,
-                  size=size, leaders=leaders, pipelined=pipelined)
-    now = time.perf_counter_ns()
-    _metrics.histogram("broker.wave_assembly_s").observe(
-        (now - t0_ns) / 1e9)
+def _wave_futures(order):
+    """Every future a wave's stage 3 resolves (leaders, their followers,
+    replayed followers)."""
     for role, entry in order:
-        futs = [entry[1]] if role == "dfollower" else \
-            [entry.fut] + [f for _, f in entry.followers]
-        for f in futs:
-            if f.obs is not None:
-                f.obs["wave"] = wave_no
-                f.obs["dispatch"] = now
-    if dispatched:
-        _obs.async_begin("wave", wave_no, size=size, pipelined=pipelined)
+        if role == "dfollower":
+            yield entry[1]
+        else:
+            yield entry.fut
+            for _, f in entry.followers:
+                yield f
 
 
-def _wave_executed(t0_ns: int, wave_no: int, order) -> None:
-    """Tracing-enabled path: record the finalize (host-sync) duration and
-    stamp per-request execute completion."""
-    now = time.perf_counter_ns()
-    _obs.complete("broker.wave.execute", t0_ns, cat="broker", wave=wave_no)
-    _metrics.histogram("broker.wave_execute_s").observe(
-        (now - t0_ns) / 1e9)
-    for role, entry in order:
-        futs = [entry[1]] if role == "dfollower" else \
-            [entry.fut] + [f for _, f in entry.followers]
-        for f in futs:
-            if f.obs is not None:
-                f.obs["execute_done"] = now
-
-
-def _wave_committed(t0_ns: int, wave_no: int, n: int) -> None:
-    """Tracing-enabled path: record the stage-3 commit duration and close
-    the wave's async interval."""
-    _obs.complete("broker.wave.commit", t0_ns, cat="broker",
-                  wave=wave_no, entries=n)
-    _metrics.histogram("broker.wave_commit_s").observe(
-        (time.perf_counter_ns() - t0_ns) / 1e9)
-    _obs.async_end("wave", wave_no)
+def _observe_latencies(futs, t_ns: int) -> None:
+    """Tracing-on: feed the broker's tail histogram (submit -> resolve)
+    with one lock for the requests resolved at ``t_ns``."""
+    _metrics.histogram("broker.request_s").observe_many(
+        [(t_ns - f.submit_ns) / 1e9 for f in futs
+         if f.submit_ns is not None])
 
 
 @dataclasses.dataclass
@@ -209,18 +202,21 @@ class PlanFuture:
     """Handle to a deferred plan; ``result()`` flushes the broker if the
     request is still pending and returns ``(resources, cost)``.
 
-    When tracing is enabled at submit time, ``obs`` holds the request's
-    lifecycle stamps (``perf_counter_ns``) and ``critical_path()``
-    reports the latency breakdown; with tracing off, ``obs`` stays None
-    and the future costs exactly what it did pre-instrumentation."""
+    When tracing is enabled at submit time, ``submit_ns`` holds the
+    submit stamp (``perf_counter_ns``), ``verdict`` the broker's
+    decision and ``wave`` the stamps of the wave that resolved it, and
+    ``critical_path()`` reports the latency breakdown; with tracing off
+    all three stay None."""
 
-    __slots__ = ("_broker", "done", "value", "obs")
+    __slots__ = ("_broker", "done", "value", "submit_ns", "verdict", "wave")
 
     def __init__(self, broker: "PlanBroker"):
         self._broker = broker
         self.done = False
         self.value: Result = (None, math.inf)
-        self.obs: Optional[dict] = None
+        self.submit_ns: Optional[int] = None
+        self.verdict: Optional[str] = None
+        self.wave: Optional[_Stamps] = None
 
     def result(self) -> Result:
         if not self.done:
@@ -232,26 +228,35 @@ class PlanFuture:
     def critical_path(self) -> Optional[dict]:
         """Latency breakdown of this request (None when tracing was off
         at submit): ``verdict`` (memo / cache-hit / leader / follower /
-        replay / dleader), ``wave`` number, and the seconds split —
-        ``queue_s`` (submit -> wave dispatch), ``execute_s`` (dispatch ->
-        wave sync), ``commit_s`` (sync -> resolve), ``total_s``.  Memo /
-        cache hits resolve before any wave, so they only carry
-        ``total_s``."""
-        o = self.obs
-        if o is None:
+        replay / dleader), ``wave`` number, and the seconds split, read
+        from the wave's stamps — ``queue_s`` (submit -> wave dispatch),
+        ``execute_s`` (dispatch -> wave sync), ``commit_s`` (sync ->
+        the wave's commit end), ``total_s``.  A memo hit at submit
+        resolves inside ``submit()`` (``total_s`` 0, no wave); memo and
+        cache hits of stage 1 resolve when stage 1 ends, so they carry
+        ``total_s`` alone."""
+        sub = self.submit_ns
+        if sub is None:
             return None
-        out: dict = {"verdict": o.get("verdict", "pending"),
-                     "wave": o.get("wave")}
-        sub, res = o.get("submit"), o.get("resolve")
-        disp, xd = o.get("dispatch"), o.get("execute_done")
-        if sub is not None and res is not None:
-            out["total_s"] = (res - sub) / 1e9
-        if sub is not None and disp is not None:
-            out["queue_s"] = (disp - sub) / 1e9
-        if disp is not None and xd is not None:
-            out["execute_s"] = (xd - disp) / 1e9
-        if xd is not None and res is not None:
-            out["commit_s"] = (res - xd) / 1e9
+        w = self.wave
+        out: dict = {"verdict": self.verdict or "pending",
+                     "wave": None if w is None else w.wave}
+        if w is None:
+            if self.done:
+                out["total_s"] = 0.0
+            return out
+        if self.verdict in ("memo", "cache-hit"):
+            if w.stage1:
+                out["total_s"] = (w.stage1 - sub) / 1e9
+            return out
+        if w.commit:
+            out["total_s"] = (w.commit - sub) / 1e9
+        if w.dispatch:
+            out["queue_s"] = (w.dispatch - sub) / 1e9
+        if w.dispatch and w.execute:
+            out["execute_s"] = (w.execute - w.dispatch) / 1e9
+        if w.execute and w.commit:
+            out["commit_s"] = (w.commit - w.execute) / 1e9
         return out
 
 
@@ -278,6 +283,7 @@ class _Wave:
     finalize: Callable[[], None]
     futs: frozenset
     wave_no: int = 0
+    stamps: Optional[_Stamps] = None
 
 
 class PlanBroker:
@@ -300,6 +306,7 @@ class PlanBroker:
         # with a ResourcePlanCache keep the cache as their single source
         # of cross-flush reuse (so mutable-cache semantics stay per-op)
         self._memo: Dict[Tuple, Tuple[BatchCostFn, Result]] = {}
+        self._instant = 0              # traced memo hits at submit, unfed
         self.stats = PlanningStats()   # broker-level aggregate
 
     # ------------------------------------------------------------------ #
@@ -317,17 +324,18 @@ class PlanBroker:
         (or immediately, on a session-memo hit)."""
         fut = PlanFuture(self)
         if _obs.enabled:
-            fut.obs = {"submit": time.perf_counter_ns(),
-                       "verdict": "pending"}
+            fut.submit_ns = time.perf_counter_ns()
         self._bump(req, "broker_requests")
         if req.cache is None:
             hit = self._memo.get(self._key(req))
             if hit is not None and hit[0] is req.fn:
                 self._bump(req, "broker_dedup_hits")
                 fut.value, fut.done = hit[1], True
-                if fut.obs is not None:
-                    fut.obs["verdict"] = "memo"
-                    _request_done(fut)
+                if fut.submit_ns is not None:
+                    # resolved inside submit(): no wave, latency 0, fed
+                    # to the histogram in bulk (_observe_instant)
+                    fut.verdict = "memo"
+                    self._instant += 1
                 return fut
         self._pending.append((req, fut))
         return fut
@@ -342,11 +350,22 @@ class PlanBroker:
         self.stats.broker_waves += 1
         self.stats.broker_wave_sizes.append(len(pending))
 
+    def _observe_instant(self) -> None:
+        """Feed ``broker.request_s`` the traced memo hits at submit since
+        the last call, at latency 0 (they resolve inside ``submit()``):
+        once per flush and per snapshot, so no request pays a histogram
+        update of its own."""
+        if self._instant:
+            _metrics.histogram("broker.request_s").observe_many(
+                [0.0] * self._instant)
+            self._instant = 0
+
     def counters_snapshot(self) -> dict:
         """JSON-friendly broker counters including flush-wave geometry —
         the lockstep multi-query win is wave *shape* (few waves, ΣQ_L
         requests each), not just wall-clock, so benches trend these next
-        to the timings."""
+        to the timings.  Also brings ``broker.request_s`` up to date."""
+        self._observe_instant()
         ws = list(self.stats.broker_wave_sizes)
         return {
             "requests": self.stats.broker_requests,
@@ -375,27 +394,36 @@ class PlanBroker:
         # cluster, budget): caller falls through to search
         return None
 
-    @hot_path("resolves every pending request of the session per flush")
     def flush(self) -> None:
         """Resolve every pending request: dedup -> stacked search ->
         float64 commit -> fan-out (stages 1-3 of the module docstring).
         Any in-flight double-buffered wave commits first, so sequential
         ordering is preserved."""
+        self._flush_sync("explicit")
+
+    @hot_path("resolves every pending request of the session per flush")
+    def _flush_sync(self, cause: str) -> None:
+        """``flush()`` on behalf of ``cause`` (``explicit`` or
+        ``result``): the synchronous wave runs under a
+        ``broker.flush.sync`` span and counts in
+        ``broker.sync_flushes.<cause>``."""
         self._commit_inflight()
         pending, self._pending = self._pending, []
         if not pending:
             return
         self._record_wave(pending)
         wave_no = self.stats.broker_waves
-        t0 = time.perf_counter_ns() if _obs.enabled else 0
-        order, execs = self._stage1(pending)
-        fin = self._dispatch(execs) if execs else None
-        if _obs.enabled:
-            _wave_assembled(t0, wave_no, len(pending), len(execs), order,
-                            False, fin is not None)
-        if fin is None:
-            return
-        self._finish(order, execs, fin, wave_no)
+        _metrics.counter("broker.sync_flushes." + cause).inc()
+        with _obs.span("broker.flush.sync", cat="broker") as fsp:
+            order, execs, stamps = self._stage1_span(pending, wave_no)
+            groups = 0
+            if execs:
+                fin, groups = self._dispatch_span(execs, wave_no, stamps,
+                                                  sync=True)
+                self._finish(order, execs, fin, wave_no, stamps)
+            if fsp:
+                fsp.set(cause=cause, requests=len(pending), groups=groups,
+                        wave=wave_no)
 
     def flush_async(self) -> None:
         """Double-buffered flush: commit the previous in-flight wave
@@ -415,50 +443,71 @@ class PlanBroker:
             return
         self._record_wave(pending)
         wave_no = self.stats.broker_waves
-        t0 = time.perf_counter_ns() if _obs.enabled else 0
-        order, execs = self._stage1(pending)
+        order, execs, stamps = self._stage1_span(pending, wave_no)
         if not execs:
-            if _obs.enabled:
-                _wave_assembled(t0, wave_no, len(pending), 0, order,
-                                True, False)
             return
-        futs = set()
-        for role, entry in order:
-            if role == "dfollower":
-                futs.add(id(entry[1]))
-            else:
-                futs.add(id(entry.fut))
-                futs.update(id(ffut) for _, ffut in entry.followers)
-        fin = self._dispatch(execs)
-        if _obs.enabled:
-            _wave_assembled(t0, wave_no, len(pending), len(execs), order,
-                            True, True)
+        futs = frozenset(id(f) for f in _wave_futures(order))
+        fin, _ = self._dispatch_span(execs, wave_no, stamps, sync=False)
         self._inflight = _Wave(order=order, execs=execs, finalize=fin,
-                               futs=frozenset(futs), wave_no=wave_no)
+                               futs=futs, wave_no=wave_no, stamps=stamps)
 
     def inflight_count(self) -> int:
         """Futures the in-flight wave will resolve (0 when none)."""
         return 0 if self._inflight is None else len(self._inflight.futs)
 
     def _commit_inflight(self) -> None:
-        """Finalize + commit the in-flight wave, if any."""
+        """Finalize + commit the in-flight wave, if any (the first step
+        of every flush)."""
+        self._observe_instant()
         wave, self._inflight = self._inflight, None
         if wave is not None:
             self._finish(wave.order, wave.execs, wave.finalize,
-                         wave.wave_no)
+                         wave.wave_no, wave.stamps)
 
     def _ensure(self, fut: PlanFuture) -> None:
         """Resolve ``fut``: a member of the in-flight wave commits just
         that wave (newer pending requests stay pending, still
         accumulating into the next one); anything else takes the full
-        flush."""
+        synchronous flush, counted under cause ``result``."""
         if self._inflight is not None and id(fut) in self._inflight.futs:
             self._commit_inflight()
         else:
-            self.flush()
+            self._flush_sync("result")
+
+    def _stage1_span(self, pending, wave_no: int):
+        """Stage 1 under the ``broker.stage1`` span; traced, also the
+        wave's stamps (else None) and the latencies of the requests it
+        resolved."""
+        stamps = _Stamps(wave_no) if _obs.enabled else None
+        with _obs.span("broker.stage1", cat="broker") as sp:
+            order, execs = self._stage1(pending, stamps)
+            if sp:
+                sp.set(wave=wave_no, size=len(pending), leaders=len(execs))
+        if stamps is not None:
+            stamps.stage1 = time.perf_counter_ns()
+            _observe_latencies(
+                [f for _, f in pending if f.done and f.wave is stamps],
+                stamps.stage1)
+        return order, execs, stamps
+
+    def _dispatch_span(self, execs, wave_no: int, stamps, sync: bool):
+        """Stage 2's dispatch under the ``broker.dispatch`` span; traced,
+        stamps the wave and opens its async interval (closed at commit,
+        so double-buffered waves render as overlapping tracks)."""
+        with _obs.span("broker.dispatch", cat="broker") as sp:
+            fin, groups = self._dispatch(execs, wave_no=wave_no, sync=sync)
+            if sp:
+                sp.set(wave=wave_no, groups=groups, leaders=len(execs),
+                       pipelined=not sync)
+        if stamps is not None:
+            stamps.dispatch = time.perf_counter_ns()
+            _obs.async_begin("wave", wave_no, leaders=len(execs),
+                             pipelined=not sync)
+        return fin, groups
 
     # ------------------------------------------------------------------ #
-    def _stage1(self, pending: List[Tuple[PlanRequest, PlanFuture]]
+    def _stage1(self, pending: List[Tuple[PlanRequest, PlanFuture]],
+                stamps: Optional[_Stamps] = None
                 ) -> Tuple[List[Tuple[str, object]], List[_Exec]]:
         """Stage 1: cache fronting + within-flush dedup.
 
@@ -474,18 +523,22 @@ class PlanBroker:
         multi-query lockstep shape where every query's copy of a
         recurring operator lands in one wave.  Cache-less duplicates
         stay plain followers (memo semantics are insertion-order
-        identical either way).  Returns (stage-3 submission order,
-        leader execs)."""
+        identical either way).  Traced (``stamps``), every request gets
+        its verdict and the wave's stamps.  Returns (stage-3 submission
+        order, leader execs)."""
         leaders: Dict[Tuple, _Exec] = {}
         order: List[Tuple[str, object]] = []   # stage-3 submission order
+        traced = stamps is not None
         for req, fut in pending:
+            if traced:
+                fut.wave = stamps
             cached = req.cache is not None and req.cache_key is not None
             if req.cache is None:
                 memo = self._memo.get(self._key(req))
                 if memo is not None and memo[0] is req.fn:
                     self._bump(req, "broker_dedup_hits")
-                    if fut.obs is not None:
-                        fut.obs["verdict"] = "memo"
+                    if traced:
+                        fut.verdict = "memo"
                     self._resolve(fut, memo[1])
                     continue
             deferred = cached and \
@@ -497,8 +550,8 @@ class PlanBroker:
                 dkey = ("exact",) + self._key(req)
             led = leaders.get(dkey)
             if led is not None:
-                if fut.obs is not None:
-                    fut.obs["verdict"] = "replay" if cached else "follower"
+                if traced:
+                    fut.verdict = "replay" if cached else "follower"
                 if cached:
                     # same cache key as an earlier same-flush request:
                     # the sequential loop would give it a fresh lookup
@@ -516,34 +569,46 @@ class PlanBroker:
             if cached and not deferred:
                 got = self._lookup(req)
                 if got is not None:
-                    if fut.obs is not None:
-                        fut.obs["verdict"] = "cache-hit"
+                    if traced:
+                        fut.verdict = "cache-hit"
                     self._resolve(fut, got)
                     continue
             ex = _Exec(req=req, fut=fut)
             leaders[dkey] = ex
-            if fut.obs is not None:
-                fut.obs["verdict"] = "dleader" if deferred else "leader"
+            if traced:
+                fut.verdict = "dleader" if deferred else "leader"
             order.append(("dleader" if deferred else "leader", ex))
         return order, list(leaders.values())
 
     def _finish(self, order: List[Tuple[str, object]], execs: List[_Exec],
-                finalize: Callable[[], None], wave_no: int = 0) -> None:
+                finalize: Callable[[], None], wave_no: int = 0,
+                stamps: Optional[_Stamps] = None) -> None:
         """Finalize a dispatched wave (the single host sync), then run
         stage 3: float64 commit + fan-out, in submission order."""
-        t0 = time.perf_counter_ns() if _obs.enabled else 0
-        finalize()
-        if _obs.enabled:
-            _wave_executed(t0, wave_no, order)
+        with _obs.span("broker.wave.execute", cat="broker") as sp:
+            finalize()
+            if sp:
+                sp.set(wave=wave_no)
+        if stamps is not None:
+            stamps.execute = time.perf_counter_ns()
         retry = [ex for ex in execs
                  if ex.req.scan_fallback and ex.req.mode == "ensemble"
                  and not math.isfinite(ex.cost)]
         if retry:
             # all starts stranded on an infeasible plateau: exhaustive
             # scan, still stacked per (fn, grid) group
-            self._run(retry, force_mode="grid")
+            self._run(retry, force_mode="grid", wave_no=wave_no)
+        with _obs.span("broker.wave.commit", cat="broker") as sp:
+            self._commit_wave(order)
+            if sp:
+                sp.set(wave=wave_no, entries=len(order))
+        if stamps is not None:
+            stamps.commit = time.perf_counter_ns()
+            _observe_latencies(_wave_futures(order), stamps.commit)
+            _obs.async_end("wave", wave_no)
 
-        tc = time.perf_counter_ns() if _obs.enabled else 0
+    def _commit_wave(self, order: List[Tuple[str, object]]) -> None:
+        """Stage 3: float64 commit + fan-out, in submission order."""
         for role, entry in order:
             if role == "dfollower":
                 # sequential per-request replay: its lookup sees every
@@ -586,20 +651,22 @@ class PlanBroker:
                 # as it goes.  Rare corner: replay it sequentially.
                 for freq, ffut in ex.followers:
                     self._resolve(ffut, self._solve_one(freq))
-        if _obs.enabled:
-            _wave_committed(tc, wave_no, len(order))
 
     # ------------------------------------------------------------------ #
     @hot_path("dispatches one stacked search program per (fn, grid) group")
     def _dispatch(self, execs: List[_Exec],
-                  force_mode: Optional[str] = None) -> Callable[[], None]:
+                  force_mode: Optional[str] = None, *, wave_no: int = 0,
+                  sync: bool = True) -> Tuple[Callable[[], None], int]:
         """Stage 2, dispatch half: group leaders per (cost-fn, grid,
         mode), stack their params, and launch every group's array
         program via the backend's async split — ALL groups dispatch
         before any result is read back, so a flush mixing cost surfaces
         (SMJ and BHJ operators, say) overlaps their scans on device.
-        Returns the zero-arg finalize performing the host syncs and
-        writing raw (res, cost) back onto each _Exec."""
+        Each grid group counts the rows its program sweeps, once, in
+        ``broker.sweep_rows.sync`` or ``.async`` (``sync``: dispatched by
+        a synchronous flush).  Returns the zero-arg finalize performing
+        the host syncs and writing raw (res, cost) back onto each _Exec,
+        and the number of groups."""
         groups: Dict[Tuple, List[_Exec]] = {}
         for ex in execs:
             req = ex.req
@@ -608,12 +675,15 @@ class PlanBroker:
                     req.seed, len(req.params))
             groups.setdefault(gkey, []).append(ex)
         be = self.backend
+        rows = "broker.sweep_rows.sync" if sync else "broker.sweep_rows.async"
         waves = []
         for gkey, entries in groups.items():
             req0 = entries[0].req
             mode = force_mode or req0.mode
             pm = np.stack([ex.req.params for ex in entries])
             gstats = PlanningStats()
+            if mode == "grid":
+                _metrics.counter(rows).inc(req0.cluster.grid_size())
             with _obs.span("broker.dispatch.group", cat="broker") as sp:
                 if mode == "grid":
                     if hasattr(be, "argmin_grid_many_async"):
@@ -634,7 +704,7 @@ class PlanBroker:
                             n_random=req0.n_random, seed=req0.seed)
                         fin = (lambda r=results: r)
                 if sp:
-                    sp.set(mode=mode, q=len(entries),
+                    sp.set(mode=mode, q=len(entries), wave=wave_no,
                            backend=getattr(be, "name", "?"))
             for ex in entries:
                 self._bump(ex.req, "broker_batches")
@@ -646,7 +716,7 @@ class PlanBroker:
                 with _obs.span("broker.group.sync", cat="broker") as sp:
                     results = fin()
                     if sp:
-                        sp.set(q=len(entries))
+                        sp.set(q=len(entries), wave=wave_no)
                 # attribute the group's exploration evenly (grid groups
                 # are exactly grid_size per request; climb convergence
                 # varies per request, so the split is approximate there)
@@ -657,13 +727,21 @@ class PlanBroker:
                         n = share + (rem if i == 0 else 0)
                         ex.req.stats.configs_explored += n
                         ex.req.stats.cost_calls += n
-        return finalize
+        return finalize, len(groups)
 
-    def _run(self, execs: List[_Exec], force_mode: Optional[str] = None
-             ) -> None:
+    def _run(self, execs: List[_Exec], force_mode: Optional[str] = None,
+             wave_no: int = 0) -> None:
         """Synchronous stage 2: dispatch + immediate finalize (the
-        scan_fallback retry path)."""
-        self._dispatch(execs, force_mode)()
+        scan_fallback retry path, a synchronous flush of cause
+        ``retry``)."""
+        _metrics.counter("broker.sync_flushes.retry").inc()
+        with _obs.span("broker.flush.sync", cat="broker") as sp:
+            fin, groups = self._dispatch(execs, force_mode, wave_no=wave_no,
+                                         sync=True)
+            fin()
+            if sp:
+                sp.set(cause="retry", requests=len(execs), groups=groups,
+                       wave=wave_no)
 
     def _commit(self, req: PlanRequest, res, cost: float) -> Result:
         """Float64 commit of one raw search result: re-cost through the
@@ -725,5 +803,3 @@ class PlanBroker:
         fut.value = (None if value[0] is None
                      else tuple(int(v) for v in value[0]), float(value[1]))
         fut.done = True
-        if fut.obs is not None:
-            _request_done(fut)

@@ -113,6 +113,22 @@ programs execute on device while the Selinger / FastRandomized drivers
 enumerate and submit wave N+1 (see ``repro.core.plan_broker``).  The
 numpy backend computes eagerly and defers only the return, keeping the
 wave machinery backend-uniform.
+
+Program names and counters
+--------------------------
+Every program the jax backend launches carries its stage (and, for the
+stacked scan, its padded width) in its name, so that profiles, HLO
+dumps and compile logs attribute device time without guessing from
+shapes: ``plan_scan_many_w{Qpad}``, ``plan_scan``, ``plan_climb_many``,
+``plan_climb`` and ``plan_fold`` (the stack-and-fold of a scan's span
+results); params reach the device as a plain transfer, no program.
+Three counters are always on (``repro.obs.ALWAYS_ON``), one increment
+per scan call or program build: ``backend.launches`` (scan programs
+enqueued), ``backend.programs_built`` (memo misses) and
+``backend.compiles`` (XLA compiles and compile-cache loads, from a
+``jax.monitoring`` listener registered when the first jax backend is
+built).  Traced, the scan's launch loop is the ``backend.launch`` span
+and each compile a ``backend.compile`` span.
 """
 from __future__ import annotations
 
@@ -126,9 +142,10 @@ import numpy as np
 from repro.analysis.registry import hot_path
 from repro.core.cluster import ClusterConditions, PlanningStats
 from repro.core.plan_cache import snap_to_grid
-from repro.obs import get_tracer, record_program
+from repro.obs import get_metrics, get_tracer
 
 _obs = get_tracer()
+_metrics = get_metrics()
 
 BatchCostFn = Callable[..., "np.ndarray"]
 Result = Tuple[Optional[Tuple[int, ...]], float]
@@ -140,6 +157,53 @@ DEFAULT_CHUNK = 1 << 20
 # elements per device — never exceeds MAX_LIVE_ELEMENTS.
 MIN_SHARD_ROWS = 512
 MAX_LIVE_ELEMENTS = 1 << 22
+
+
+# --------------------------- names and compiles ---------------------------- #
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` renamed to ``name``: ``jax.jit`` names its program after
+    the function (``jit_<name>`` in HLO, profiles and compile logs)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _fold_fn(jnp) -> Callable:
+    """The ``plan_fold`` program's body: stack a scan's per-span
+    ``(best_cost, best_flat)`` results and keep, per request, the first
+    span holding the minimum (the strict-< order of a sequential fold)."""
+    def plan_fold(costs, flats):
+        c, f = jnp.stack(costs), jnp.stack(flats)
+        k = jnp.argmin(c, axis=0)[None]
+        return (jnp.take_along_axis(c, k, 0)[0],
+                jnp.take_along_axis(f, k, 0)[0])
+    return plan_fold
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener = []             # the registered listener, once
+
+
+def _on_compile(event: str, start_s: float, end_s: float, **kw) -> None:
+    """``jax.monitoring`` time-span listener: one XLA compile, or one
+    load from the persistent compilation cache, has ended.  Counted
+    always; traced, it becomes a ``backend.compile`` span, its
+    ``time.time()`` stamps moved onto the tracer's clock."""
+    if event != _COMPILE_EVENT:
+        return
+    _metrics.counter("backend.compiles").inc()
+    if _obs.enabled:
+        shift = time.perf_counter_ns() - time.time_ns()
+        _obs.complete("backend.compile", int(start_s * 1e9) + shift,
+                      cat="compile", end_ns=int(end_s * 1e9) + shift,
+                      fun_name=kw.get("fun_name", "?"))
+
+
+def _listen_for_compiles(jax) -> None:
+    """Register ``_on_compile`` with jax once per process."""
+    if not _compile_listener:
+        jax.monitoring.register_event_time_span_listener(_on_compile)
+        _compile_listener.append(_on_compile)
 
 
 # ----------------------------- grid helpers -------------------------------- #
@@ -440,8 +504,10 @@ class JaxPlanBackend:
             raise ValueError(f"unknown jax precision {precision!r} "
                              "(expected 'float32' or 'x64')")
         from jax import shard_map
+        _listen_for_compiles(jax)
         self._jax = jax
         self.xp = jnp
+        self._fold = jax.jit(_named(_fold_fn(jnp), "plan_fold"))
         self._shard_map = shard_map
         self.precision = precision
         self.exact = precision == "x64"
@@ -483,18 +549,16 @@ class JaxPlanBackend:
         key = (kind, id(fn), cluster.dims, extra)
         hit = self._programs.get(key)
         if hit is not None and hit[0] is fn:
-            if _obs.enabled:
-                record_program(self.name, kind, reused=True)
             return hit[1]
-        t0 = time.perf_counter_ns() if _obs.enabled else 0
-        prog = build()
-        if _obs.enabled:
-            # compile-event capture: which program was built, how long
-            # the build (tracing + jit wrapping; XLA compiles lazily at
-            # first dispatch) took, on how many plan-mesh devices —
-            # cross-checkable against the plan-lint recompile audit
-            record_program(self.name, kind, reused=False, start_ns=t0,
-                           devices=self.device_count())
+        # a memo miss builds the jit wrapper only: XLA compiles at the
+        # program's first launch, which ``backend.compiles`` counts (and,
+        # traced, times as a ``backend.compile`` span under the launch)
+        with _obs.span("backend.program_build", cat="compile") as sp:
+            prog = build()
+            if sp:
+                sp.set(backend=self.name, kind=kind,
+                       devices=self.device_count())
+        _metrics.counter("backend.programs_built").inc()
         # bounded cache on the process-wide singleton: evict oldest first
         # so callers that churn fresh fn closures cannot grow it without
         # limit (reusing one fn object per cost surface stays the fast
@@ -509,8 +573,11 @@ class JaxPlanBackend:
         return fn(cfgs) if params is None else fn(cfgs, params)
 
     def _params(self, params):
-        dtype = self.xp.float64 if self.exact else self.xp.float32
-        return self.xp.asarray([] if params is None else params, dtype=dtype)
+        """Params on the device: cast on the host (as ``jnp.asarray``
+        would) and transferred, so the upload is no program."""
+        dtype = np.float64 if self.exact else np.float32
+        return self._jax.device_put(
+            np.asarray([] if params is None else params, dtype=dtype))
 
     # -- chunked grid scan --------------------------------------------------- #
     @hot_path("dispatches one compiled program per grid span per request",
@@ -556,10 +623,9 @@ class JaxPlanBackend:
                 return costs[j], flat[j]
 
             if D == 1:
-                @jax.jit
                 def scan_chunk(lo, p):
                     return shard_body(lo + jnp.arange(chunk), p)
-                return scan_chunk
+                return jax.jit(_named(scan_chunk, "plan_scan"))
 
             PS = jax.sharding.PartitionSpec
             shard = self._shard_map(
@@ -568,7 +634,6 @@ class JaxPlanBackend:
                 in_specs=(PS("plan"), PS()),
                 out_specs=(PS("plan"), PS("plan")))
 
-            @jax.jit
             def scan_span(lo, p):
                 # shards hold ascending contiguous flat ranges, so
                 # jnp.argmin over the (D,) per-shard bests (first minimum
@@ -577,27 +642,32 @@ class JaxPlanBackend:
                 cs, fs = shard(lo + jnp.arange(span), p)
                 k = jnp.argmin(cs)
                 return cs[k], fs[k]
-            return scan_span
+            return jax.jit(_named(scan_span, "plan_scan"))
 
         with self._scope():
             prog = self._program("scan", batch_cost_fn, cluster,
                                  (chunk, has_params, D), build)
             p = self._params(params)
             span_costs, span_flats = [], []
-            for lo in range(0, total, span):
-                c, f = prog(lo, p)          # async dispatch: no host sync
-                span_costs.append(c)
-                span_flats.append(f)
-                stats.configs_explored += min(span, total - lo)
-            costs = np.asarray(jnp.stack(span_costs))       # one sync
-            flats = np.asarray(jnp.stack(span_flats))
-        # np.argmin keeps the first (lowest-lo) span on ties — the same
-        # strict-< update order as the old sequential per-chunk fold
-        k = int(np.argmin(costs))
-        best_cost = float(costs[k])
+            with _obs.span("backend.launch", cat="dispatch") as sp:
+                for lo in range(0, total, span):
+                    c, f = prog(lo, p)      # async dispatch: no host sync
+                    span_costs.append(c)
+                    span_flats.append(f)
+                    stats.configs_explored += min(span, total - lo)
+                if sp:
+                    sp.set(kind="scan", Qpad=1, chunk=chunk,
+                           launches=len(span_costs))
+            _metrics.counter("backend.launches").inc(len(span_costs))
+            # the first (lowest-lo) span wins ties — the same strict-<
+            # update order as the old sequential per-chunk fold
+            c, f = self._fold(span_costs, span_flats)
+            cost = np.asarray(c)                            # one sync
+            flat = np.asarray(f)
+        best_cost = float(cost)
         if math.isinf(best_cost):
             return None, math.inf
-        idx = np.unravel_index(int(flats[k]), shape)
+        idx = np.unravel_index(int(flat), shape)
         return tuple(int(g[i]) for g, i in zip(grids_np, idx)), best_cost
 
     @hot_path("dispatches one compiled program per grid span per flush",
@@ -652,11 +722,11 @@ class JaxPlanBackend:
                 return jnp.take_along_axis(costs, j[:, None], 1)[:, 0], \
                     flat[j]
 
+            name = f"plan_scan_many_w{Qpad}"
             if D == 1:
-                @jax.jit
                 def scan_chunk(lo, p):
                     return shard_body(lo + jnp.arange(chunk), p)
-                return scan_chunk
+                return jax.jit(_named(scan_chunk, name))
 
             PS = jax.sharding.PartitionSpec
             shard = self._shard_map(
@@ -665,7 +735,6 @@ class JaxPlanBackend:
                 in_specs=(PS("plan"), PS()),
                 out_specs=(PS("plan"), PS("plan")))
 
-            @jax.jit
             def scan_span(lo, p):
                 cs, fs = shard(lo + jnp.arange(span), p)    # (D, Qpad)
                 # first minimum over the device axis = lowest device =
@@ -673,7 +742,7 @@ class JaxPlanBackend:
                 k = jnp.argmin(cs, axis=0)
                 return (jnp.take_along_axis(cs, k[None, :], 0)[0],
                         jnp.take_along_axis(fs, k[None, :], 0)[0])
-            return scan_span
+            return jax.jit(_named(scan_span, name))
 
         with self._scope():
             prog = self._program("scan_many", batch_cost_fn, cluster,
@@ -681,27 +750,32 @@ class JaxPlanBackend:
             p = self._params(np.pad(pm, ((0, Qpad - Q), (0, 0)),
                                     mode="edge"))
             span_costs, span_flats = [], []
-            for lo in range(0, total, span):
-                c, f = prog(lo, p)          # async dispatch: no host sync
-                span_costs.append(c)
-                span_flats.append(f)
-                stats.configs_explored += Q * min(span, total - lo)
+            with _obs.span("backend.launch", cat="dispatch") as sp:
+                for lo in range(0, total, span):
+                    c, f = prog(lo, p)      # async dispatch: no host sync
+                    span_costs.append(c)
+                    span_flats.append(f)
+                    stats.configs_explored += Q * min(span, total - lo)
+                if sp:
+                    sp.set(kind="scan_many", Qpad=Qpad, chunk=chunk,
+                           launches=len(span_costs))
+            _metrics.counter("backend.launches").inc(len(span_costs))
 
         def finalize() -> List[Result]:
             with self._scope():
-                costs = np.asarray(jnp.stack(span_costs))[:, :Q]  # one sync
-                flats = np.asarray(jnp.stack(span_flats))[:, :Q]  # (C, Q)
-            # np.argmin keeps the first (lowest-lo) span on ties — the
-            # same strict-< update order as the sequential per-chunk loop
-            k = np.argmin(costs, axis=0)
+                # the first (lowest-lo) span wins ties — the same strict-<
+                # update order as the sequential per-chunk loop
+                c, f = self._fold(span_costs, span_flats)
+                costs = np.asarray(c)[:Q]                   # one sync
+                flats = np.asarray(f)[:Q]
             out: List[Result] = []
             for q in range(Q):
-                c = float(costs[k[q], q])
-                if math.isinf(c):
+                cq = float(costs[q])
+                if math.isinf(cq):
                     out.append((None, math.inf))
                 else:
-                    out.append((_decode_flat(grids_np, shape,
-                                             flats[k[q], q]), c))
+                    out.append((_decode_flat(grids_np, shape, flats[q]),
+                                cq))
             return out
 
         return finalize
@@ -794,8 +868,9 @@ class JaxPlanBackend:
         with self._scope():
             prog = self._program(
                 "climb", batch_cost_fn, cluster, (S, max_iters, has_params),
-                lambda: jax.jit(self._climb_fn(batch_cost_fn, grids_np,
-                                               max_iters, has_params)))
+                lambda: jax.jit(_named(
+                    self._climb_fn(batch_cost_fn, grids_np, max_iters,
+                                   has_params), "plan_climb")))
             idx, cost, n_eval = prog(jnp.asarray(cur0), self._params(params))
             idx = np.asarray(idx)
             n_eval = int(n_eval)
@@ -842,16 +917,16 @@ class JaxPlanBackend:
             climb = self._climb_fn(batch_cost_fn, grids_np, max_iters, True)
             vm = jax.vmap(climb, in_axes=(None, 0))
             if D == 1:
-                return jax.jit(vm)
+                return jax.jit(_named(vm, "plan_climb_many"))
             PS = jax.sharding.PartitionSpec
             # check_vma=False: every output is genuinely sharded over
             # the request axis, so the varying-manual-axes check adds
             # nothing here
-            return jax.jit(self._shard_map(
+            return jax.jit(_named(self._shard_map(
                 vm, mesh=self._plan_mesh(),
                 in_specs=(PS(), PS("plan")),
                 out_specs=(PS("plan"), PS("plan"), PS("plan")),
-                check_vma=False))
+                check_vma=False), "plan_climb_many"))
 
         with self._scope():
             prog = self._program("climb_many", batch_cost_fn, cluster,

@@ -208,14 +208,7 @@ class RAQO:
                         for tables, costing in zip(queries, costings)]
             drive_fast_randomized(sessions, broker)
             plans = [s.result()[0] for s in sessions]
-        out = [self._wrap(p, t0, c) for p, c in zip(plans, costings)]
-        if _obs.enabled:
-            for i, jp in enumerate(out):
-                _obs.instant("raqo.query", cat="driver", query=i,
-                             requests=jp.stats.broker_requests,
-                             dedup=jp.stats.broker_dedup_hits,
-                             explored=jp.stats.configs_explored)
-        return out
+        return [self._wrap(p, t0, c) for p, c in zip(plans, costings)]
 
     def _prefetch_base(self, queries: Sequence[Sequence[str]],
                        costings: Sequence[OperatorCosting]) -> None:

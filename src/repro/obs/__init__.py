@@ -6,8 +6,6 @@ stdlib-only so the hot core modules (``plan_broker``,
 time with zero added dependencies.  See README.md in this directory for
 the span model and the overhead contract.
 """
-import time
-
 from repro.obs.exporters import (attribution_md, wave_summary,
                                  write_attribution, write_chrome_trace)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -19,33 +17,20 @@ __all__ = [
     "NULL_SPAN", "Span", "Tracer", "get_tracer", "trace_enabled",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_metrics",
     "attribution_md", "wave_summary", "write_attribution",
-    "write_chrome_trace", "record_program",
+    "write_chrome_trace", "ALWAYS_ON",
 ]
 
-
-def record_program(backend_name: str, kind: str, reused: bool,
-                   start_ns=None, devices=None) -> None:
-    """Compile-event capture for the backend program memos: called on
-    every ``_program`` lookup when tracing is enabled.  Emits an instant
-    event (built events carry the build duration) and bumps the
-    built/reused counters the recompile audit cross-checks."""
-    tracer = get_tracer()
-    metrics = get_metrics()
-    if reused:
-        metrics.counter("backend.programs_reused").inc()
-        metrics.counter(f"backend.reused.{backend_name}.{kind}").inc()
-        tracer.instant("backend.program", cat="compile",
-                       backend=backend_name, kind=kind, event="reused")
-        return
-    metrics.counter("backend.programs_built").inc()
-    metrics.counter(f"backend.built.{backend_name}.{kind}").inc()
-    args = {"backend": backend_name, "kind": kind, "event": "built"}
-    if devices is not None:
-        args["devices"] = devices
-    if start_ns is not None:
-        tracer.complete("backend.program_build", start_ns, cat="compile",
-                        **args)
-        metrics.histogram("backend.build_s").observe(
-            (time.perf_counter_ns() - start_ns) / 1e9)
-    else:
-        tracer.instant("backend.program", cat="compile", **args)
+# Counters the planner increments whether tracing is on or off, each at
+# most once per scan call, stacked group or synchronous flush (README,
+# "Overhead contract").  A counter is created at its first increment, so
+# one of these missing from a snapshot has counted 0.
+ALWAYS_ON = (
+    "backend.compiles",            # XLA compiles and compile-cache loads
+    "backend.launches",            # scan program launches
+    "backend.programs_built",      # program-memo misses
+    "broker.sweep_rows.sync",      # grid rows swept by synchronous flushes
+    "broker.sweep_rows.async",     # grid rows swept by flush_async waves
+    "broker.sync_flushes.result",  # flush() forced by PlanFuture.result()
+    "broker.sync_flushes.retry",   # ensemble -> grid scan_fallback retries
+    "broker.sync_flushes.explicit",  # any other caller of flush()
+)

@@ -11,13 +11,14 @@ Two output formats, both fed from the tracer/metrics singletons:
 * ``attribution_md(joint_plans)`` renders the human-readable per-query
   attribution table: for each planned query, where its planning effort
   went (requests, dedup/cache hits, configs explored) next to the
-  broker-level latency percentiles and the wave assembly/execute/commit
-  split from the histogram registry.
+  broker-level latency percentiles and the wave stage-1 / dispatch /
+  execute / commit split read from the broker's spans.
 
 ``wave_summary()`` is the JSON-friendly digest both the telemetry bench
 and the reconciliation tests consume: wave count/sizes recovered from
-the ``broker.wave`` spans (cross-checkable against
-``PlanBroker.counters_snapshot``) plus p50/p99 from the registry.
+the ``broker.stage1`` spans (cross-checkable against
+``PlanBroker.counters_snapshot``), each stage's spans, the request
+latency histogram and the always-on program counters.
 """
 from __future__ import annotations
 
@@ -47,28 +48,48 @@ def _hist_stats(metrics: MetricsRegistry, name: str) -> dict:
             "p50_s": h.percentile(50), "p99_s": h.percentile(99)}
 
 
+def _pct(xs: List[float], p: float) -> float:
+    k = (len(xs) - 1) * p / 100.0
+    lo = math.floor(k)
+    hi = min(lo + 1, len(xs) - 1)
+    return xs[lo] + (k - lo) * (xs[hi] - xs[lo])
+
+
+def _span_stats(tracer: Tracer, name: str) -> dict:
+    """Count, mean and exact p50/p99 (seconds) of the ``name`` spans."""
+    xs = sorted(e["dur"] / 1e6 for e in tracer.spans(name))
+    if not xs:
+        return {"count": 0}
+    return {"count": len(xs), "mean_s": sum(xs) / len(xs),
+            "p50_s": _pct(xs, 50), "p99_s": _pct(xs, 99)}
+
+
 def wave_summary(tracer: Optional[Tracer] = None,
                  metrics: Optional[MetricsRegistry] = None) -> dict:
-    """Digest of wave geometry (from spans) + latency percentiles (from
-    histograms).  ``wave_sizes`` comes from the ``broker.wave`` span
+    """Digest of wave geometry and stage times (from spans), request
+    latency (from the ``broker.request_s`` histogram) and the program
+    counters.  ``wave_sizes`` comes from the ``broker.stage1`` span
     args, so tests can reconcile it exactly against
     ``counters_snapshot()['wave_sizes']``."""
     tracer = tracer or get_tracer()
     metrics = metrics or get_metrics()
-    waves = sorted(tracer.spans("broker.wave"),
+    waves = sorted(tracer.spans("broker.stage1"),
                    key=lambda e: e["args"].get("wave", 0))
     sizes = [e["args"].get("size", 0) for e in waves]
+    counts = metrics.snapshot()
     out = {
         "waves": len(waves),
         "wave_sizes": sizes,
         "max_wave": max(sizes) if sizes else 0,
         "mean_wave": round(sum(sizes) / len(sizes), 3) if sizes else 0.0,
         "request": _hist_stats(metrics, "broker.request_s"),
-        "wave_assembly": _hist_stats(metrics, "broker.wave_assembly_s"),
-        "wave_execute": _hist_stats(metrics, "broker.wave_execute_s"),
-        "wave_commit": _hist_stats(metrics, "broker.wave_commit_s"),
-        "programs_built": metrics.counter("backend.programs_built").value,
-        "programs_reused": metrics.counter("backend.programs_reused").value,
+        "wave_stage1": _span_stats(tracer, "broker.stage1"),
+        "wave_dispatch": _span_stats(tracer, "broker.dispatch"),
+        "wave_execute": _span_stats(tracer, "broker.wave.execute"),
+        "wave_commit": _span_stats(tracer, "broker.wave.commit"),
+        "programs_built": counts.get("backend.programs_built", 0),
+        "compiles": counts.get("backend.compiles", 0),
+        "launches": counts.get("backend.launches", 0),
     }
     return out
 
@@ -112,7 +133,8 @@ def attribution_md(joint_plans: Sequence,
         "| stage | count | mean | p50 | p99 |", "|---|---|---|---|---|",
     ]
     for label, key in (("request (submit->resolve)", "request"),
-                       ("wave assembly (dedup+dispatch)", "wave_assembly"),
+                       ("wave stage 1 (dedup+cache)", "wave_stage1"),
+                       ("wave dispatch (launches)", "wave_dispatch"),
                        ("wave execute (host sync)", "wave_execute"),
                        ("wave commit (float64+fan-out)", "wave_commit")):
         s = summary[key]
@@ -125,7 +147,8 @@ def attribution_md(joint_plans: Sequence,
         f"(sizes {summary['wave_sizes']}, mean {summary['mean_wave']}, "
         f"max {summary['max_wave']}); "
         f"programs built {summary['programs_built']}, "
-        f"reused {summary['programs_reused']}; "
+        f"XLA compiles {summary['compiles']}, "
+        f"scan launches {summary['launches']}; "
         f"request p50 {_fmt_s(req.get('p50_s'))} / "
         f"p99 {_fmt_s(req.get('p99_s'))}.", "",
     ]

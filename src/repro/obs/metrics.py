@@ -17,11 +17,15 @@ along and clamp the interpolation at the tails.
 
 Thread-safe: each metric guards its state with one lock; the registry
 guards its name table.  Like the tracer there is a process-wide
-singleton (``get_metrics()``); hot call sites stay behind the tracer's
-enabled flag so a disabled run never touches it.
+singleton (``get_metrics()``).  Histograms and most counters stay
+behind the tracer's enabled flag; the few counters that are always on
+(``repro.obs.ALWAYS_ON``) cost one increment per scan, group or flush.
+``reset()`` drops the name table, so call sites look a metric up by name
+at each use rather than binding the object once.
 """
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -79,25 +83,33 @@ class Histogram:
         self._lock = threading.Lock()
 
     def _bucket(self, v: float) -> int:
-        lo, hi = 0, len(self.edges)
-        while lo < hi:                      # first edge >= v
-            mid = (lo + hi) // 2
-            if self.edges[mid] < v:
-                lo = mid + 1
-            else:
-                hi = mid
-        return lo
+        return bisect.bisect_left(self.edges, v)    # first edge >= v
 
     def observe(self, v: float) -> None:
         v = float(v)
+        b = self._bucket(v)
         with self._lock:
-            self.counts[self._bucket(v)] += 1
+            self.counts[b] += 1
             self.count += 1
             self.sum += v
             if v < self.min:
                 self.min = v
             if v > self.max:
                 self.max = v
+
+    def observe_many(self, values) -> None:
+        """Observe every value of ``values`` under one lock acquisition
+        (a broker wave feeds its requests' latencies this way)."""
+        vs = [float(v) for v in values]
+        if not vs:
+            return
+        with self._lock:
+            for v in vs:
+                self.counts[bisect.bisect_left(self.edges, v)] += 1
+            self.count += len(vs)
+            self.sum += sum(vs)
+            self.min = min(self.min, min(vs))
+            self.max = max(self.max, max(vs))
 
     def percentile(self, p: float) -> float:
         """Interpolated p-th percentile (p in [0, 100]); NaN when empty."""
@@ -155,13 +167,15 @@ class MetricsRegistry:
         self._metrics: Dict[str, object] = {}
 
     def _get(self, name: str, cls, *args):
-        with self._lock:
-            m = self._metrics.get(name)
-            if m is None:
-                m = self._metrics[name] = cls(*args)
-            assert isinstance(m, cls), \
-                f"metric {name!r} already registered as {type(m).__name__}"
-            return m
+        m = self._metrics.get(name)        # a hit reads the table lock-free
+        if m is None:
+            with self._lock:
+                m = self._metrics.get(name)
+                if m is None:
+                    m = self._metrics[name] = cls(*args)
+        assert isinstance(m, cls), \
+            f"metric {name!r} already registered as {type(m).__name__}"
+        return m
 
     def counter(self, name: str) -> Counter:
         return self._get(name, Counter)

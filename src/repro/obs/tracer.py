@@ -23,8 +23,10 @@ ever perturbing what gets planned:
   lock-guarded buffer; the *open*-span stack is ``threading.local``, so
   spans opened on different threads (or interleaved across
   ``flush_async`` double-buffered waves) nest independently and cannot
-  corrupt each other.  Each event records its thread id and nesting
-  depth.
+  corrupt each other.  Each complete event records its thread id, its
+  nesting ``depth``, its own ``id`` and ``parent``: the id of the span
+  open on its thread when it opened (0 at the top), so a reader can
+  tell which span caused it.
 
 Enablement: ``REPRO_TRACE=1`` in the environment at import, or
 ``get_tracer().enable()`` programmatically (the benches and tests use the
@@ -45,6 +47,7 @@ async b/e  ``b``/``e``  ``async_begin(name, id)`` / ``async_end`` —
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -79,7 +82,8 @@ class Span:
     ``if sp:`` at hot call sites.  The event is emitted at ``__exit__``.
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_depth", "_id",
+                 "_parent")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -88,6 +92,8 @@ class Span:
         self.args = args
         self._t0 = 0
         self._depth = 0
+        self._id = 0
+        self._parent = 0
 
     def __bool__(self) -> bool:
         return True
@@ -99,6 +105,8 @@ class Span:
     def __enter__(self) -> "Span":
         stack = self._tracer._stack()
         self._depth = len(stack)
+        self._parent = stack[-1]._id if stack else 0
+        self._id = next(self._tracer._ids)
         stack.append(self)
         self._t0 = time.perf_counter_ns()
         return self
@@ -111,7 +119,8 @@ class Span:
         if stack and stack[-1] is self:
             stack.pop()
         self._tracer._emit_complete(self.name, self.cat, self._t0, t1,
-                                    self._depth, self.args)
+                                    self._depth, self._id, self._parent,
+                                    self.args)
         return False
 
 
@@ -127,6 +136,7 @@ class Tracer:
         self._local = threading.local()
         self._epoch_ns = time.perf_counter_ns()
         self._pid = os.getpid()
+        self._ids = itertools.count(1)     # span ids; 0 means "no parent"
 
     # -- enablement ---------------------------------------------------- #
     def enable(self) -> None:
@@ -150,14 +160,18 @@ class Tracer:
         return Span(self, name, cat, args)
 
     def complete(self, name: str, start_ns: int, cat: str = "plan",
-                 **args) -> None:
-        """Emit a complete ("X") event whose start was stamped manually
-        with ``time.perf_counter_ns()`` — for regions where a ``with``
-        block would force awkward re-indentation."""
+                 end_ns: Optional[int] = None, **args) -> None:
+        """Emit a complete ("X") event for a region timed outside the
+        span stack: ``start_ns`` (and ``end_ns``, default now) on the
+        ``time.perf_counter_ns`` clock — e.g. a compile that jax reports
+        after the fact.  Its parent is the span open on this thread."""
         if not self.enabled:
             return
-        self._emit_complete(name, cat, start_ns, time.perf_counter_ns(),
-                            len(self._stack()), args)
+        stack = self._stack()
+        self._emit_complete(name, cat, start_ns,
+                            time.perf_counter_ns() if end_ns is None
+                            else end_ns, len(stack), next(self._ids),
+                            stack[-1]._id if stack else 0, args)
 
     def instant(self, name: str, cat: str = "plan", **args) -> None:
         if not self.enabled:
@@ -213,11 +227,12 @@ class Tracer:
         return stack
 
     def _emit_complete(self, name: str, cat: str, t0: int, t1: int,
-                       depth: int, args: dict) -> None:
+                       depth: int, sid: int, parent: int,
+                       args: dict) -> None:
         ev = {"name": name, "cat": cat, "ph": "X",
               "ts": self._us(t0), "dur": (t1 - t0) / 1000.0,
               "pid": self._pid, "tid": threading.get_ident(),
-              "args": dict(args, depth=depth)}
+              "args": dict(args, depth=depth, id=sid, parent=parent)}
         self._emit(ev)
 
     def _emit(self, ev: dict) -> None:

@@ -26,13 +26,13 @@ session's level-L requests are pure functions of its own table sets,
 queued in its solo order within the wave, and the broker's dedup /
 replay semantics are defined to equal "search once, then hit".
 
-Measurement rides PR 9's observability spine instead of new timers:
+Measurement rides the observability spine instead of new timers:
 per-request latency lands in the ``broker.request_s`` histogram, wave
-stage splits in ``broker.wave_*_s``, and the service samples
-``PlanFuture.critical_path()`` for the queue/execute/commit breakdown —
-all gated on ``get_tracer().enabled`` so an untraced service adds two
-clock reads per query (the submit/resolve ticket stamps) and nothing
-else.  ``report()`` summarizes plans/sec and exact p50/p99
+stages in the broker's ``broker.*`` spans, and the service samples
+``PlanFuture.critical_path()`` (read from the wave's stamps) for the
+queue/execute/commit breakdown — all gated on ``get_tracer().enabled``
+so an untraced service adds two clock reads per query (the
+submit/resolve ticket stamps) and nothing else.  ``report()`` summarizes plans/sec and exact p50/p99
 submit->resolve latency from the tickets themselves, so the headline
 numbers exist even with tracing off.
 """
@@ -139,9 +139,6 @@ class StreamingPlannerService:
         else:
             self.driver.admit(session)
             self._active.append((ticket, session, costing, t0))
-        if _obs.enabled:
-            _obs.instant("service.submit", cat="service", tenant=tenant,
-                         tables=len(ticket.tables), wave=self.waves)
         return ticket
 
     @hot_path("one shared flush wave advancing every live tenant's DP "
@@ -190,11 +187,8 @@ class StreamingPlannerService:
         ticket.resolve_ns = time.perf_counter_ns()
         ticket.final_wave = self.waves
         if _obs.enabled:
-            lat = (ticket.resolve_ns - ticket.submit_ns) / 1e9
-            _metrics.histogram("service.query_s").observe(lat)
-            _obs.instant("service.resolve", cat="service",
-                         tenant=ticket.tenant, wave=self.waves,
-                         latency_us=int(lat * 1e6))
+            _metrics.histogram("service.query_s").observe(
+                (ticket.resolve_ns - ticket.submit_ns) / 1e9)
 
     # ------------------------------------------------------------------ #
     def run_closed_loop(self, queries: Sequence[Tuple[int, Sequence[str]]],

@@ -83,6 +83,10 @@ def test_disabled_path_is_allocation_free():
     allocate: net allocated-block delta over 20k iterations stays at
     noise level (a per-iteration allocation would show up as thousands)."""
     tr = Tracer(enabled=False)
+    reg = MetricsRegistry()
+    stages = ("broker.stage1", "broker.dispatch", "broker.flush.sync",
+              "broker.wave.execute", "broker.wave.commit", "backend.launch",
+              "backend.program_build")
 
     def loop(n):
         for i in range(n):
@@ -91,6 +95,16 @@ def test_disabled_path_is_allocation_free():
                 sp.set(mode="grid", q=i)
             with sp:
                 pass
+            # the wave-stage, sync-flush, launch and build sites, the
+            # compile span stamped after the fact, and the always-on
+            # counters (looked up by name at each increment)
+            for name in stages:
+                with tr.span(name, cat="broker") as sp:
+                    if sp:
+                        sp.set(wave=i)
+            tr.complete("backend.compile", 0, cat="compile", end_ns=1)
+            reg.counter("backend.launches").inc(3)
+            reg.counter("broker.sweep_rows.async").inc(1000)
 
     loop(1000)                        # warm caches / lazy init
     gc.collect()
@@ -294,7 +308,7 @@ def test_critical_path_none_when_disabled():
     broker = PlanBroker("numpy")
     fut = broker.submit(_req(3.0))
     fut.result()
-    assert fut.obs is None and fut.critical_path() is None
+    assert fut.submit_ns is None and fut.critical_path() is None
 
 
 def test_critical_path_breakdown(traced):
@@ -336,6 +350,203 @@ def test_flush_async_wave_interval_encloses_interleaved_host_work(traced):
     assert begins["1"]["ts"] <= marker["ts"]
     assert marker["ts"] + marker["dur"] <= ends["1"]["ts"]
     assert f1.critical_path()["verdict"] == "leader"
+
+
+def test_parent_links_each_group_to_its_dispatch(traced):
+    """Every complete event names the span open when it opened: each
+    ``broker.dispatch.group`` is a child of its wave's ``broker.dispatch``,
+    and every broker span carries the wave number."""
+    tr, _ = traced
+    broker = PlanBroker("numpy", double_buffer=True)
+    broker.submit(_req(2.0))
+    broker.submit(_req(5.0))
+    broker.flush_async()
+    broker.submit(_req(6.0))
+    broker.flush()
+    dispatch = {e["args"]["id"]: e for e in tr.spans("broker.dispatch")}
+    groups = tr.spans("broker.dispatch.group")
+    assert len(dispatch) == len(groups) == 2
+    for g in groups:
+        d = dispatch[g["args"]["parent"]]
+        assert d["args"]["wave"] == g["args"]["wave"]
+        assert d["args"]["depth"] + 1 == g["args"]["depth"]
+    assert sorted(d["args"]["wave"] for d in dispatch.values()) == [1, 2]
+    assert all("wave" in e["args"] for e in tr.spans()
+               if e["name"].startswith("broker."))
+    assert not tr.spans("broker.wave")
+
+
+# -------------- synchronous flushes, swept rows, launches, compiles ---------- #
+
+def test_result_on_a_pending_future_is_one_sync_flush_of_cause_result(
+        traced):
+    tr, mx = traced
+    broker = PlanBroker("numpy")
+    fut = broker.submit(_req(3.0))
+    fut.result()
+    syncs = tr.spans("broker.flush.sync")
+    assert len(syncs) == 1
+    a = syncs[0]["args"]
+    assert (a["cause"], a["requests"], a["groups"], a["wave"]) == \
+        ("result", 1, 1, 1)
+    snap = mx.snapshot()
+    assert snap["broker.sync_flushes.result"] == 1
+    assert "broker.sync_flushes.explicit" not in snap
+    broker.submit(_req(4.0))
+    broker.flush()
+    assert mx.counter("broker.sync_flushes.explicit").value == 1
+    assert [e["args"]["cause"] for e in tr.spans("broker.flush.sync")] == \
+        ["result", "explicit"]
+
+
+def _only_at(target):
+    """A cost surface infeasible everywhere but ``target``: a climb from
+    the corners strands, so ``scan_fallback`` reruns it as a grid scan."""
+    def fn(cfgs, params):
+        c = np.asarray(cfgs, dtype=np.float64)
+        hit = (c[:, 0] == target[0]) & (c[:, 1] == target[1])
+        return np.where(hit, 1.0, np.inf) + 0.0 * params[0]
+    return fn
+
+
+def test_scan_fallback_retry_is_a_sync_flush_of_cause_retry(traced):
+    tr, mx = traced
+    cluster = ClusterConditions(dims=(ResourceDim("a", 1, 8),
+                                      ResourceDim("b", 1, 4)))
+    req = PlanRequest(fn=_only_at((4, 2)), cluster=cluster,
+                      params=np.asarray([0.0]),
+                      commit_fn=lambda cfg: 1.0 if cfg == (4, 2)
+                      else math.inf,
+                      mode="ensemble", scan_fallback=True)
+    broker = PlanBroker("numpy")
+    fut = broker.submit(req)
+    broker.flush_async()
+    assert fut.result() == ((4, 2), 1.0)
+    retry = [e for e in tr.spans("broker.flush.sync")
+             if e["args"]["cause"] == "retry"]
+    assert len(retry) == 1 and retry[0]["args"]["wave"] == 1
+    snap = mx.snapshot()
+    assert snap["broker.sync_flushes.retry"] == 1
+    # the climb sweeps no grid rows; the retry's grid scan sweeps them all
+    assert snap["broker.sweep_rows.sync"] == cluster.grid_size()
+    assert "broker.sweep_rows.async" not in snap
+
+
+def test_sweep_rows_count_each_group_once_by_flush_kind(monkeypatch):
+    """With tracing off, ``broker.sweep_rows.sync + .async`` equals the
+    stacked grid groups dispatched times the rows each sweeps."""
+    from repro.core.planning_backend import get_backend
+    be = get_backend("numpy")
+    groups = []
+    orig = be.argmin_grid_many_async
+
+    def spy(fn, cluster, params_many, **kw):
+        groups.append(cluster.grid_size())
+        return orig(fn, cluster, params_many, **kw)
+    monkeypatch.setattr(be, "argmin_grid_many_async", spy)
+    mx = get_metrics()
+    mx.reset()
+    try:
+        _, broker = _run_lockstep(n_queries=6)
+        snap = mx.snapshot()
+    finally:
+        mx.reset()
+    rows = snap.get("broker.sweep_rows.sync", 0) + \
+        snap.get("broker.sweep_rows.async", 0)
+    assert groups and rows == sum(groups) == len(groups) * 192
+    assert snap.get("broker.sweep_rows.async", 0) > 0
+
+
+def _xp_fn(cfgs, p):
+    """A cost surface written for either array namespace."""
+    return (cfgs[:, 0] - p[0]) ** 2 + 0.1 * cfgs[:, 1]
+
+
+@needs_jax
+def test_launches_count_every_span_of_every_scan(monkeypatch):
+    """``backend.launches`` over a small lockstep run equals the sum,
+    over the stacked scans it dispatched, of ceil(grid / span), the span
+    computed by hand from ``_many_chunk`` (the live-element cap lowered
+    so that each scan takes several launches)."""
+    from repro.core import planning_backend as pb
+    monkeypatch.setattr(pb, "MAX_LIVE_ELEMENTS", 256)
+    be = pb.JaxPlanBackend()
+    scans = []
+    orig = be.argmin_grid_many_async
+
+    def spy(fn, cluster, params_many, **kw):
+        scans.append((len(params_many), cluster.grid_size()))
+        return orig(fn, cluster, params_many, **kw)
+    monkeypatch.setattr(be, "argmin_grid_many_async", spy)
+    mx = get_metrics()
+    mx.reset()
+    try:
+        _run_lockstep(n_queries=4, backend=be)
+        launches = mx.counter("backend.launches").value
+    finally:
+        mx.reset()
+    D = be.device_count()
+    want = sum(-(-total // (D * pb._many_chunk(total, pb._pad_even(q), D,
+                                                pb.DEFAULT_CHUNK)))
+               for q, total in scans)
+    assert scans and want > len(scans)
+    assert launches == want
+
+
+@needs_jax
+def test_fresh_width_compiles_once_and_a_repeat_does_not():
+    """With tracing off, the first stacked scan of a width builds one
+    program and compiles it and its fold; the same width again neither
+    builds nor compiles."""
+    from repro.core.planning_backend import JaxPlanBackend
+    assert not get_tracer().enabled
+    be = JaxPlanBackend()
+    cluster = paper_cluster(24, 8)
+    mx = get_metrics()
+
+    def counts():
+        snap = mx.snapshot()
+        return (snap.get("backend.programs_built", 0),
+                snap.get("backend.compiles", 0))
+    pm = np.arange(3.0)[:, None]
+    b0, c0 = counts()
+    first = be.argmin_grid_many(_xp_fn, cluster, pm)
+    b1, c1 = counts()
+    assert (b1 - b0, c1 - c0) == (1, 2)         # plan_scan_many_w4, fold
+    assert be.argmin_grid_many(_xp_fn, cluster, pm) == first
+    assert counts() == (b1, c1)
+
+
+@needs_jax
+def test_stacked_scan_program_is_named_by_its_width():
+    import jax.numpy as jnp
+    from repro.core.planning_backend import JaxPlanBackend
+    be = JaxPlanBackend()
+    be.argmin_grid_many(_xp_fn, paper_cluster(24, 8), np.arange(3.0)[:, None])
+    (prog,) = [v[1] for k, v in be._programs.items() if k[0] == "scan_many"]
+    text = prog.lower(0, jnp.zeros((4, 1), jnp.float32)).as_text()
+    assert text.startswith("module @jit_plan_scan_many_w4 ")
+
+
+@needs_jax
+def test_traced_compile_and_launch_spans_nest_under_the_launch(traced):
+    """Traced, a first launch's XLA compile is a ``backend.compile`` span
+    (its jax stamps moved onto the tracer's clock) inside the
+    ``backend.launch`` span that triggered it."""
+    from repro.core.planning_backend import JaxPlanBackend
+    tr, _ = traced
+    be = JaxPlanBackend()
+    be.argmin_grid_many(_xp_fn, paper_cluster(24, 8), np.arange(5.0)[:, None])
+    (launch,) = tr.spans("backend.launch")
+    assert launch["args"]["kind"] == "scan_many"
+    assert launch["args"]["Qpad"] == 6 and launch["args"]["launches"] == 1
+    inner = [e for e in tr.spans("backend.compile")
+             if e["args"]["parent"] == launch["args"]["id"]]
+    assert [e["args"]["fun_name"] for e in inner] == \
+        ["jit(plan_scan_many_w6)"]
+    c = inner[0]
+    assert launch["ts"] <= c["ts"]
+    assert c["ts"] + c["dur"] <= launch["ts"] + launch["dur"] + 1.0
 
 
 # ----------------------- invariance & reconciliation ------------------------ #
@@ -399,12 +610,12 @@ def test_wave_spans_reconcile_with_counters(traced, tmp_path):
     assert ws["max_wave"] == cs["max_wave"]
     assert ws["mean_wave"] == pytest.approx(cs["mean_wave"], abs=1e-3)
     assert ws["request"]["count"] == cs["requests"]
-    assert ws["wave_assembly"]["count"] == cs["waves"]
+    assert ws["wave_stage1"]["count"] == cs["waves"]
     # execute/commit fire once per *dispatched* wave (an all-cache-hit
     # wave assembles but never reaches the device)
     assert ws["wave_execute"]["count"] == ws["wave_commit"]["count"]
     assert 0 < ws["wave_execute"]["count"] <= cs["waves"]
-    for stage in ("request", "wave_assembly", "wave_execute",
+    for stage in ("request", "wave_stage1", "wave_execute",
                   "wave_commit"):
         s = ws[stage]
         assert s["p50_s"] <= s["p99_s"]
@@ -412,7 +623,7 @@ def test_wave_spans_reconcile_with_counters(traced, tmp_path):
     # every future reports a critical path, and per-wave request counts
     # recovered from the stamps match the wave sizes
     per_wave = {}
-    for sp in tr.spans("broker.wave"):
+    for sp in tr.spans("broker.stage1"):
         per_wave[sp["args"]["wave"]] = sp["args"]["size"]
     assert sorted(per_wave) == list(range(1, cs["waves"] + 1))
 
