@@ -63,9 +63,10 @@ class ClusterConditions:
 class ConfigColumns:
     """A batch of configurations held as one array per resource dimension.
 
-    The fused Pallas kernels decode configurations straight into
-    vector-register tiles, one tile per dimension; stacking them into an
-    ``(N, n_dims)`` array would force a relayout the TPU compiler refuses.
+    The grid scans (the jax programs and the fused Pallas kernels) decode
+    configurations straight into one array per dimension; stacking them
+    into an ``(N, n_dims)`` array would force a relayout the TPU compiler
+    handles badly, or in a Pallas kernel refuses.
     This view answers the two things batch cost fns ask of their configs,
     ``configs[:, d]`` (the tile of dimension d) and ``shape``; cost fns
     read it through ``as_configs``.  ``jnp.asarray`` still stacks it into

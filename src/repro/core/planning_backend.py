@@ -43,7 +43,11 @@ units, e.g. ``(nc, cs)`` or ``(pods, dp, tp, microbatch)``) and ``params``
 is a small float vector of per-request scalars.  Infeasible
 configurations must cost ``inf``.  For the jax backend the fn must be
 traceable (build it from ``backend.xp`` ops; every cost model in this
-repo takes an ``xp`` argument for exactly this).
+repo takes an ``xp`` argument for exactly this).  The jax-family grid
+scans decode their configurations by arithmetic into a ``ConfigColumns``
+view, one column per dimension, rather than an array: a fn reads it
+through ``as_configs`` (``configs[:, d]``, ``shape``), and
+``jnp.asarray`` still stacks it for a fn that indexes rows.
 
 Many-request primitives
 -----------------------
@@ -122,9 +126,11 @@ dumps and compile logs attribute device time without guessing from
 shapes: ``plan_scan_many_w{Qpad}``, ``plan_scan``, ``plan_climb_many``,
 ``plan_climb`` and ``plan_fold`` (the stack-and-fold of a scan's span
 results); params reach the device as a plain transfer, no program.
-Three counters are always on (``repro.obs.ALWAYS_ON``), one increment
+Four counters are always on (``repro.obs.ALWAYS_ON``), one increment
 per scan call or program build: ``backend.launches`` (scan programs
-enqueued), ``backend.programs_built`` (memo misses) and
+enqueued), ``backend.programs_built`` (memo misses),
+``backend.decode_table_dims`` (the dimensions a built scan program
+decodes from a value table rather than by arithmetic) and
 ``backend.compiles`` (XLA compiles and compile-cache loads, from a
 ``jax.monitoring`` listener registered when the first jax backend is
 built).  Traced, the scan's launch loop is the ``backend.launch`` span
@@ -140,7 +146,8 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.analysis.registry import hot_path
-from repro.core.cluster import ClusterConditions, PlanningStats
+from repro.core.cluster import (ClusterConditions, ConfigColumns,
+                                PlanningStats)
 from repro.core.plan_cache import snap_to_grid
 from repro.obs import get_metrics, get_tracer
 
@@ -168,15 +175,33 @@ def _named(fn: Callable, name: str) -> Callable:
     return fn
 
 
+def _first_min(costs, flats, axis: int):
+    """``(best_cost, best_flat)`` along ``axis``: the least cost and the
+    lowest flat row id attaining it, in ONE variadic reduction under
+    ``jnp.argmin``'s comparator (a NaN wins, ties go to the lower id).
+    ``flats`` (broadcast against ``costs``) ascend along ``axis``, so this
+    is the strict-< first minimum, and no gather reads the winner back."""
+    import jax.numpy as jnp
+    from jax import lax
+    flats = jnp.broadcast_to(flats, costs.shape)
+
+    def pick(a, b):
+        (ac, af), (bc, bf) = a, b
+        take = (ac < bc) | (ac != ac)
+        take_f = take | ((ac == bc) & (af < bf))
+        return lax.select(take, ac, bc), lax.select(take_f, af, bf)
+
+    init = (np.array(np.inf, costs.dtype),
+            np.array(np.iinfo(flats.dtype).max, flats.dtype))
+    return lax.reduce((costs, flats), init, pick, (axis,))
+
+
 def _fold_fn(jnp) -> Callable:
     """The ``plan_fold`` program's body: stack a scan's per-span
     ``(best_cost, best_flat)`` results and keep, per request, the first
     span holding the minimum (the strict-< order of a sequential fold)."""
     def plan_fold(costs, flats):
-        c, f = jnp.stack(costs), jnp.stack(flats)
-        k = jnp.argmin(c, axis=0)[None]
-        return (jnp.take_along_axis(c, k, 0)[0],
-                jnp.take_along_axis(f, k, 0)[0])
+        return _first_min(jnp.stack(costs), jnp.stack(flats), 0)
     return plan_fold
 
 
@@ -225,6 +250,57 @@ def enumerate_configs(cluster: ClusterConditions, lo: int = 0,
     flat = np.arange(lo, hi, dtype=np.int64)
     idx = np.unravel_index(flat, shape)
     return np.stack([g[i] for g, i in zip(grids, idx)], axis=1)
+
+
+# ------------------------------- grid decode -------------------------------- #
+# One decode serves every scan program, jax and Pallas alike: flat row ids
+# become configuration values by arithmetic, so no program gathers from a
+# value table or stacks an (N, n_dims) array (a TPU has no vector gather,
+# and a minor dimension of n_dims is a relayout its compiler handles badly).
+
+def _dim_meta(cluster: ClusterConditions) -> Tuple[Tuple, ...]:
+    """Static per-dimension decode recipe: ("affine", lo, step) for range
+    dims (value = lo + step * idx, pure arithmetic) or ("values", vals)
+    for explicit grids (compare-select over the small value table).
+    Called once per program build, which ``backend.decode_table_dims``
+    counts by its value-table dims."""
+    metas = tuple(("values", tuple(int(v) for v in d.values)) if d.values
+                  else ("affine", int(d.lo), int(d.step))
+                  for d in cluster.dims)
+    _metrics.counter("backend.decode_table_dims").inc(
+        sum(m[0] == "values" for m in metas))
+    return metas
+
+
+def _dim_sizes(cluster: ClusterConditions) -> Tuple[int, ...]:
+    return tuple(len(d.grid()) for d in cluster.dims)
+
+
+def _value_of_index(idx, meta):
+    """One dimension's grid indices -> config values (same shape and
+    integer dtype)."""
+    import jax.numpy as jnp
+    if meta[0] == "affine":
+        _, lo, step = meta
+        return lo + step * idx
+    vals = meta[1]
+    col = jnp.full_like(idx, vals[0])
+    for k in range(1, len(vals)):
+        col = jnp.where(idx == k, vals[k], col)
+    return col
+
+
+def _decode_columns(flat, metas, sizes):
+    """Flat row ids -> one array of config values per dimension, in
+    ``enumerate_configs`` order (row-major, first dim slowest), decoded
+    by a divmod chain by the static dim sizes from the fastest dim up."""
+    cols = [None] * len(sizes)
+    rem = flat
+    for d in range(len(sizes) - 1, 0, -1):
+        cols[d] = _value_of_index(rem % sizes[d], metas[d])
+        rem = rem // sizes[d]
+    cols[0] = _value_of_index(rem, metas[0])
+    return cols
 
 
 def start_indices(cluster: ClusterConditions,
@@ -580,6 +656,22 @@ class JaxPlanBackend:
             np.asarray([] if params is None else params, dtype=dtype))
 
     # -- chunked grid scan --------------------------------------------------- #
+    def _decoder(self, cluster: ClusterConditions):
+        """The scan programs' decode, made once per program build:
+        ``rows(flat) -> (ok, configs)``, the in-grid mask of a span's flat
+        row ids and their configurations as a ``ConfigColumns`` view,
+        decoded by ``_decode_columns`` (rows past the grid decode as row
+        0; the caller masks their costs to inf)."""
+        jnp = self.xp
+        total = cluster.grid_size()
+        metas, sizes = _dim_meta(cluster), _dim_sizes(cluster)
+
+        def rows(flat):
+            ok = flat < total
+            return ok, ConfigColumns(
+                _decode_columns(jnp.where(ok, flat, 0), metas, sizes))
+        return rows
+
     @hot_path("dispatches one compiled program per grid span per request",
               folds=2)
     def argmin_grid(self, batch_cost_fn: BatchCostFn,
@@ -609,18 +701,13 @@ class JaxPlanBackend:
         has_params = params is not None
 
         def build():
-            grids = [jnp.asarray(g) for g in grids_np]
+            rows = self._decoder(cluster)
 
             def shard_body(flat, p):
-                ok = flat < total
-                safe = jnp.where(ok, flat, 0)
-                idx = jnp.unravel_index(safe, shape)
-                cfgs = jnp.stack([g[i] for g, i in zip(grids, idx)], axis=1)
+                ok, cfgs = rows(flat)
                 costs = self._call(batch_cost_fn, cfgs,
                                    p if has_params else None)
-                costs = jnp.where(ok, costs, jnp.inf)
-                j = jnp.argmin(costs)
-                return costs[j], flat[j]
+                return _first_min(jnp.where(ok, costs, jnp.inf), flat, 0)
 
             if D == 1:
                 def scan_chunk(lo, p):
@@ -635,13 +722,12 @@ class JaxPlanBackend:
                 out_specs=(PS("plan"), PS("plan")))
 
             def scan_span(lo, p):
-                # shards hold ascending contiguous flat ranges, so
-                # jnp.argmin over the (D,) per-shard bests (first minimum
-                # = lowest device = lowest rows) is the globally first
-                # strict minimum of the span
+                # shards hold ascending contiguous flat ranges, so the
+                # first minimum over the (D,) per-shard bests (lowest
+                # device = lowest rows) is the globally first strict
+                # minimum of the span
                 cs, fs = shard(lo + jnp.arange(span), p)
-                k = jnp.argmin(cs)
-                return cs[k], fs[k]
+                return _first_min(cs, fs, 0)
             return jax.jit(_named(scan_span, "plan_scan"))
 
         with self._scope():
@@ -709,18 +795,13 @@ class JaxPlanBackend:
         shape = tuple(len(g) for g in grids_np)
 
         def build():
-            grids = [jnp.asarray(g) for g in grids_np]
+            rows = self._decoder(cluster)
 
             def shard_body(flat, p):
-                ok = flat < total
-                safe = jnp.where(ok, flat, 0)
-                idx = jnp.unravel_index(safe, shape)
-                cfgs = jnp.stack([g[i] for g, i in zip(grids, idx)], axis=1)
+                ok, cfgs = rows(flat)
                 costs = jax.vmap(lambda q: batch_cost_fn(cfgs, q))(p)
                 costs = jnp.where(ok[None, :], costs, jnp.inf)  # (Q, rows)
-                j = jnp.argmin(costs, axis=1)
-                return jnp.take_along_axis(costs, j[:, None], 1)[:, 0], \
-                    flat[j]
+                return _first_min(costs, flat[None, :], 1)
 
             name = f"plan_scan_many_w{Qpad}"
             if D == 1:
@@ -739,9 +820,7 @@ class JaxPlanBackend:
                 cs, fs = shard(lo + jnp.arange(span), p)    # (D, Qpad)
                 # first minimum over the device axis = lowest device =
                 # lowest flat rows: the strict-< tie-break per request
-                k = jnp.argmin(cs, axis=0)
-                return (jnp.take_along_axis(cs, k[None, :], 0)[0],
-                        jnp.take_along_axis(fs, k[None, :], 0)[0])
+                return _first_min(cs, fs, 0)
             return jax.jit(_named(scan_span, name))
 
         with self._scope():

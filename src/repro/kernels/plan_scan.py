@@ -88,8 +88,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.analysis.registry import hot_path
 from repro.core.cluster import ClusterConditions, ConfigColumns, PlanningStats
 from repro.core.planning_backend import (  # noqa: F401 (re-exported types)
-    DEFAULT_CHUNK, BatchCostFn, JaxPlanBackend, Result, _decode_flat,
-    _neighbor_offsets, _pad_even, _pad_multiple, grid_arrays, start_indices)
+    DEFAULT_CHUNK, BatchCostFn, JaxPlanBackend, Result, _decode_columns,
+    _decode_flat, _dim_meta, _dim_sizes, _neighbor_offsets, _pad_even,
+    _pad_multiple, _value_of_index, grid_arrays, start_indices)
 from repro.obs import get_tracer
 
 _obs = get_tracer()
@@ -106,23 +107,9 @@ _INT32_MAX = np.iinfo(np.int32).max
 
 
 # ----------------------------- in-kernel decode ----------------------------- #
-
-def _dim_meta(cluster: ClusterConditions) -> Tuple[Tuple, ...]:
-    """Static per-dimension decode recipe: ("affine", lo, step) for range
-    dims (value = lo + step * idx, pure arithmetic) or ("values", vals)
-    for explicit grids (compare-select over the small value table)."""
-    metas = []
-    for d in cluster.dims:
-        if d.values:
-            metas.append(("values", tuple(int(v) for v in d.values)))
-        else:
-            metas.append(("affine", int(d.lo), int(d.step)))
-    return tuple(metas)
-
-
-def _dim_sizes(cluster: ClusterConditions) -> Tuple[int, ...]:
-    return tuple(len(d.grid()) for d in cluster.dims)
-
+# The decode recipe (``_dim_meta``, ``_decode_columns``) is the one the jax
+# backend's scan programs use, shared from ``planning_backend``; here it
+# runs on one block's 2-D tile of flat row ids.
 
 def _tile(block: int) -> Tuple[int, int]:
     """The 2-D shape one block of ``block`` flat rows is computed in:
@@ -139,31 +126,6 @@ def _flat_ids(start, tile):
     r = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
     return start + r * tile[1] + lane
-
-
-def _value_of_index(idx, meta):
-    """One dimension's grid indices -> int32 config values (same shape)."""
-    if meta[0] == "affine":
-        _, lo, step = meta
-        return (lo + step * idx).astype(jnp.int32)
-    vals = meta[1]
-    col = jnp.full_like(idx, vals[0])
-    for k in range(1, len(vals)):
-        col = jnp.where(idx == k, vals[k], col)
-    return col
-
-
-def _decode_columns(flat, metas, sizes):
-    """Flat row ids -> one array of config values per dimension, in
-    ``enumerate_configs`` order (row-major, first dim slowest), decoded
-    by a divmod chain by the static dim sizes from the fastest dim up."""
-    cols = [None] * len(sizes)
-    rem = flat
-    for d in range(len(sizes) - 1, 0, -1):
-        cols[d] = _value_of_index(rem % sizes[d], metas[d])
-        rem = rem // sizes[d]
-    cols[0] = _value_of_index(rem, metas[0])
-    return cols
 
 
 # --------------------------- closure hoisting ------------------------------- #

@@ -28,6 +28,7 @@ ALWAYS_ON = (
     "backend.compiles",            # XLA compiles and compile-cache loads
     "backend.launches",            # scan program launches
     "backend.programs_built",      # program-memo misses
+    "backend.decode_table_dims",   # value-table dims decoded, per build
     "broker.sweep_rows.sync",      # grid rows swept by synchronous flushes
     "broker.sweep_rows.async",     # grid rows swept by flush_async waves
     "broker.sync_flushes.result",  # flush() forced by PlanFuture.result()

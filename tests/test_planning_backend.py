@@ -10,7 +10,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config, get_shape
-from repro.core.cluster import ClusterConditions, ResourceDim, paper_cluster
+from repro.core.cluster import (ClusterConditions, ResourceDim, as_configs,
+                                paper_cluster, scaled_cluster)
 from repro.core.cost_model import simulator_cost_models
 from repro.core.hillclimb import brute_force, hill_climb_multi
 from repro.core.planning_backend import (enumerate_configs, get_backend,
@@ -90,6 +91,126 @@ def test_hypothesis_jax_numpy_argmin_identical(seed, na, nb, ragged):
         _table_fn(cluster, table, jnp), cluster)
     assert r_jx == r_np
     assert (c_jx == c_np) or (math.isinf(c_jx) and math.isinf(c_np))
+
+
+# A range grid with lo != 1 and step > 1, and a grid with one explicit-value
+# dim; neither size (1240, 1050) is a multiple of the 512-row chunk, so the
+# last span of each scan is masked.
+DECODE_GRIDS = {
+    "range": ClusterConditions(dims=(ResourceDim("a", 3, 159, step=4),
+                                     ResourceDim("b", 2, 92, step=3))),
+    "mixed": ClusterConditions(dims=(
+        ResourceDim("a", 5, 303, step=2),
+        ResourceDim("b", 1, 64, values=(1, 2, 4, 8, 16, 32, 64)))),
+}
+
+
+def _table_param_fn(cluster, table, xp):
+    """``table[config] + params[0]``: integer-valued (exact in float32,
+    many ties), read through ``as_configs`` as the cost surfaces do.  A
+    config off the grid costs -inf, so a wrong decode wins the scan."""
+    ga, gb = (xp.asarray(np.asarray(d.grid(), dtype=np.int64))
+              for d in cluster.dims)
+    t = xp.asarray(table)
+
+    def fn(cfgs, params=None):
+        a = as_configs(cfgs, xp)
+        i = xp.minimum(xp.searchsorted(ga, a[:, 0]), len(ga) - 1)
+        j = xp.minimum(xp.searchsorted(gb, a[:, 1]), len(gb) - 1)
+        on_grid = (ga[i] == a[:, 0]) & (gb[j] == a[:, 1])
+        c = xp.where(on_grid, t[i, j], -xp.inf)
+        return c if params is None else c + params[0]
+    return fn
+
+
+def _target_fn(xp):
+    """Squared distance to the config ``params``: 0 at that grid point
+    alone, so a request finds its target only where it decodes right."""
+    def fn(cfgs, params):
+        a = as_configs(cfgs, xp)
+        return (a[:, 0] - params[0]) ** 2 + (a[:, 1] - params[1]) ** 2
+    return fn
+
+
+@needs_jax
+@pytest.mark.parametrize("method", ["argmin_grid", "argmin_grid_many"])
+@pytest.mark.parametrize("grid", sorted(DECODE_GRIDS))
+@pytest.mark.parametrize("backend", ["jax", "jax_x64"])
+def test_jax_decode_matches_numpy_oracle(backend, grid, method):
+    """The jax scans' arithmetic decode (affine dims by ``lo + step *
+    idx``, value-table dims by compare-select) returns the numpy oracle's
+    configs and costs, ties and the masked last span included."""
+    import jax.numpy as jnp
+    cluster = DECODE_GRIDS[grid]
+    shape = tuple(len(d.grid()) for d in cluster.dims)
+    rng = np.random.default_rng(14)
+    table = rng.integers(0, 64, size=shape).astype(np.float64)
+    table[rng.random(shape) < 0.15] = np.inf
+    table[-1, -1] = -1.0              # the unique minimum in the last span
+    fn_np, fn_jx = (_table_param_fn(cluster, table, xp) for xp in (np, jnp))
+    np_be, be = get_backend("numpy"), get_backend(backend)
+    if method == "argmin_grid":
+        tied = table.copy()
+        tied[-1, -1] = 7.0            # ties decide the winner instead
+        got = [be.argmin_grid(fn_jx, cluster, chunk_size=512),
+               be.argmin_grid(_table_param_fn(cluster, tied, jnp), cluster,
+                              chunk_size=512)]
+        ref = [np_be.argmin_grid(fn_np, cluster),
+               np_be.argmin_grid(_table_param_fn(cluster, tied, np),
+                                 cluster)]
+    else:
+        pm = np.array([[0.0], [3.0], [-2.0]])
+        got = be.argmin_grid_many(fn_jx, cluster, pm, chunk_size=512)
+        ref = np_be.argmin_grid_many(fn_np, cluster, pm)
+        # one request per grid value of each dim, each its own target
+        ga, gb = (d.grid() for d in cluster.dims)
+        n = max(len(ga), len(gb))
+        targets = [(ga[k % len(ga)], gb[k % len(gb)]) for k in range(n)]
+        got_t = be.argmin_grid_many(_target_fn(jnp), cluster,
+                                    np.asarray(targets, float),
+                                    chunk_size=512)
+        assert got_t == [(t, 0.0) for t in targets]
+    assert got[0][0] == cluster.max_config()
+    assert got == ref
+
+
+@needs_jax
+@pytest.mark.parametrize("method", ["argmin_grid", "argmin_grid_many"])
+def test_jax_scan_decodes_without_gather(method):
+    """On a range grid the compiled jax scan programs hold no gather and
+    no concatenate (no value table, no (rows, n_dims) stack), and their
+    build counts no value-table dim; one explicit-value dim counts one."""
+    import re
+
+    import jax.numpy as jnp
+    from repro.core.planning_backend import JaxPlanBackend
+    from repro.obs import get_metrics
+    smj = simulator_cost_models()["SMJ"]
+
+    def fn(cfgs, params):
+        return smj.cost_grid(params[0], params[1], cfgs, xp=jnp)
+
+    def table_dims():
+        return get_metrics().snapshot().get("backend.decode_table_dims", 0)
+
+    be = JaxPlanBackend(devices=1)
+    pm = np.array([[1.0, 5.0], [2.0, 3.0], [0.5, 9.0]])
+    mixed = ClusterConditions(dims=(
+        ResourceDim("nc", 1, 40), ResourceDim("cs", 1, 16,
+                                              values=(1, 2, 4, 8, 16))))
+    for cluster, n_table in ((scaled_cluster(40, 16), 0), (mixed, 1)):
+        before = table_dims()
+        if method == "argmin_grid":
+            be.argmin_grid(fn, cluster, params=pm[0])
+        else:
+            be.argmin_grid_many(fn, cluster, pm)
+        assert table_dims() - before == n_table
+    (prog,) = [prog for key, (_, prog) in be._programs.items()
+               if key[2] == scaled_cluster(40, 16).dims]
+    p = be._params(pm[0] if method == "argmin_grid"
+                   else np.pad(pm, ((0, 1), (0, 0))))     # Qpad = 4
+    text = prog.lower(0, p).compile().as_text()
+    assert not re.search(r"\b(gather|concatenate)\(", text)
 
 
 @needs_jax
