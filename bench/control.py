@@ -2,14 +2,17 @@
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3
 
-Puts the reference (``bench/reference.py``) in the planner's place with
-its grid search in bfloat16 and its committed costs in float32, the step
-below the float32 search and float64 commit that the configuration
-states, plans the queries a run of the cell would compare (the seed's
-window stream, sampled as ``run_cell.check`` samples, the longest query
-among them), and judges the plans as a run judges the planner's.  Each
-compared number is printed beside its limit: the control has to fail at
-least one.  The benchmark's own runs never run this; it needs no chip.
+The reference is a file that the configuration names
+(``check.reference``: ``bench/references/<name>.py``; where it names none,
+``bench/reference.py``; ``spec.reference``).  This puts that reference in
+the planner's place with its grid search in bfloat16 and its committed
+costs in float32, the step below the float32 search and float64 commit
+that the configuration states, plans the queries a run of the cell would
+compare (the seed's window stream, sampled as ``run_cell.check`` samples,
+the longest query among them), and judges the plans as a run judges the
+planner's, by every key of ``check.limits``.  Each compared number is
+printed beside its limit: the control has to fail at least one.  The
+benchmark's own runs never run this; it needs no chip.
 """
 from __future__ import annotations
 
@@ -24,8 +27,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from bench import spec  # noqa: E402
-from bench.reference import Planner  # noqa: E402
-from bench.run_cell import sample, window_queries  # noqa: E402
+from bench.run_cell import judge, sample, window_queries  # noqa: E402
 from bench.traffic import generator as gen  # noqa: E402
 from bench.window import Offer  # noqa: E402
 
@@ -33,20 +35,19 @@ POOL_PER_SAMPLE = 4        # stream prefix drawn from, per compared query
 
 
 def readings(config: dict, traffic: dict, seed: int) -> dict:
-    """The control's worst ``plan_gap`` and ``cost_gap`` on ``seed``."""
+    """The control's worst value of each key of ``check.limits`` on
+    ``seed``."""
     schema = gen.build_schema(traffic["schema"])
     k = int(config["check"]["sample_queries"])
     pool = window_queries(schema, POOL_PER_SAMPLE * k, seed, traffic)
     picked = sample([Offer(q.tables, 0) for q in pool], k, seed)
-    ref = Planner(config, schema)
-    ctl = Planner(config, schema, precision="control")
+    reference = spec.reference(config)
+    ref = reference(config, schema)
+    ctl = reference(config, schema, precision="control")
     for planner in (ref, ctl):
         planner.prefetch(o.tables for o in picked)
-    worst = {"plan_gap": 0.0, "cost_gap": 0.0}
-    for o in picked:
-        got = ref.compare(ctl.plan(o.tables), o.tables)
-        for key in worst:
-            worst[key] = max(worst[key], got[key])
+    worst, _ = judge(ref, ((ctl.plan(o.tables), o.tables) for o in picked),
+                     config["check"]["limits"])
     return dict(worst, compared=len(picked))
 
 

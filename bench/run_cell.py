@@ -20,7 +20,9 @@ One run is one process that holds the chip:
    end-to-end metrics are reported;
 4. once the window has closed and the device's memory peak is read, a
    sample of the plans resolved in the window is compared with the
-   float64 reference (``bench/reference.py``).
+   float64 reference that the configuration names (``bench/spec.py``),
+   each number the reference returns against its limit in
+   ``check.limits``.
 
 The last line of stdout is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``; its last
@@ -39,7 +41,8 @@ import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Iterator, List, Optional  # noqa: E402
+from typing import (Dict, Iterable, Iterator, List, Optional,  # noqa: E402
+                    Tuple)
 
 ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
@@ -48,7 +51,6 @@ if str(ROOT) not in sys.path:
 import numpy as np  # noqa: E402
 
 from bench import spec  # noqa: E402
-from bench.reference import Planner  # noqa: E402
 from bench.traffic import generator as gen  # noqa: E402
 from bench.window import Offer, Window, resolved_within  # noqa: E402
 
@@ -285,9 +287,11 @@ class Traced:
 
 # ----------------------------- correctness --------------------------------- #
 
-def check(dep: Deployment, window: Window, seed: int) -> dict:
+def check(dep: Deployment, window: Window, seed: int,
+          reference: type) -> dict:
     """Compare a seeded sample of the window's resolved plans, with the
-    longest queries in it, with the float64 reference."""
+    longest queries in it, with the float64 ``reference`` (a ``Planner``
+    class, ``spec.reference``)."""
     chk = dep.config["check"]
     limits = chk["limits"]
     resolved = window.resolved
@@ -295,17 +299,11 @@ def check(dep: Deployment, window: Window, seed: int) -> dict:
     no_plan = sum(1 for o in resolved if o.ticket.joint is None or
                   o.ticket.joint.plan is None)
     pick = sample(resolved, int(chk["sample_queries"]), seed)
-    ref = Planner(dep.config, dep.schema)
+    ref = reference(dep.config, dep.schema)
     ref.prefetch(o.tables for o in pick)
-    worst = {"plan_gap": 0.0, "cost_gap": 0.0}
-    bad = 0
-    for o in pick:
-        joint = o.ticket.joint
-        got = ref.compare(None if joint is None else joint.plan, o.tables)
-        if any(got[k] > limits[k] for k in worst):
-            bad += 1
-        for k in worst:
-            worst[k] = max(worst[k], got[k])
+    worst, bad = judge(ref, ((None if o.ticket.joint is None else
+                              o.ticket.joint.plan, o.tables)
+                             for o in pick), limits)
     checks = {k: {"value": worst[k], "limit": limits[k]} for k in worst}
     checks["compared"] = {"value": len(pick), "limit": 1}
     checks["unresolved"] = {"value": missing, "limit": 0}
@@ -313,6 +311,27 @@ def check(dep: Deployment, window: Window, seed: int) -> dict:
           all(worst[k] <= limits[k] for k in worst))
     return {"correct": ok, "failed": missing + no_plan + bad,
             "checks": checks, "searches": ref.searches}
+
+
+def judge(ref, plans: Iterable[Tuple[object, Tuple[str, ...]]],
+          limits: Dict[str, float]) -> Tuple[Dict[str, float], int]:
+    """The worst value of each key of ``limits`` over ``ref.compare`` of
+    each (plan, tables) of ``plans``, and how many plans read over a
+    limit.  A key that the reference does not return is an error."""
+    worst = dict.fromkeys(limits, 0.0)
+    bad = 0
+    for plan, tables in plans:
+        got = ref.compare(plan, tables)
+        absent = [k for k in limits if k not in got]
+        if absent:
+            raise KeyError(f"check.limits names {absent}, which the "
+                           f"reference {type(ref).__module__} does not "
+                           f"return (it returns {sorted(got)})")
+        if any(got[k] > limits[k] for k in limits):
+            bad += 1
+        for k in limits:
+            worst[k] = max(worst[k], got[k])
+    return worst, bad
 
 
 def sample(offers: List[Offer], k: int, seed: int) -> List[Offer]:
@@ -442,7 +461,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, jax,
     del svc, loop
     dep.service = None
     t_c = time.perf_counter()
-    verdict = check(dep, window, seed)
+    verdict = check(dep, window, seed, cell.reference)
     say("check", seconds=time.perf_counter() - t_c,
         reference_searches=verdict["searches"])
     out = {"correct": verdict["correct"], "attempted": len(counted),

@@ -17,12 +17,13 @@ QUEUED = {"grid1k.wide100.poisson": ("raqo-sec7-grid1k", "wide100.poisson",
 
 def _queued(name: str, bench: Path) -> spec.Cell:
     config, mix, metrics = QUEUED[name]
+    config = spec.load_json(bench / "configs" / f"{config}.json")
     return spec.Cell(
-        name=name, chips=1,
-        config=spec.load_json(bench / "configs" / f"{config}.json"),
+        name=name, chips=1, config=config,
         traffic=spec.load_json(bench / "traffic" / f"{mix}.json"),
         metrics=[spec.Metric(m, "", "end_to_end", spec.reader(m, bench))
-                 for m in metrics])
+                 for m in metrics],
+        reference=spec.reference(config, bench))
 
 
 def tiny(name: str, trace: bool = False, root: Path = ROOT,
@@ -49,3 +50,15 @@ def tiny(name: str, trace: bool = False, root: Path = ROOT,
 def device(jax) -> dict:
     from bench.run_cell import device_info
     return device_info(jax)
+
+
+def fast_randomized(config: dict) -> dict:
+    """``config`` planned by FastRandomized and judged by its reference
+    (``bench/references/fast_randomized.py``), with ``op_gap`` held to
+    the limit of ``plan_gap``."""
+    config = copy.deepcopy(config)
+    config["planner"] = "fastrandomized"
+    chk = config["check"]
+    chk["reference"] = "fast_randomized"
+    chk["limits"] = dict(chk["limits"], op_gap=chk["limits"]["plan_gap"])
+    return config

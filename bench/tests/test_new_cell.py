@@ -17,11 +17,17 @@ def _digest(tree):
         and "__pycache__" not in p.parts}
 
 
-def test_new_files_make_a_new_cell(tmp_path):
+def _copy(tmp_path):
+    """A copy of the benchmark under ``tmp_path``: its ``bench`` dir."""
     bench = tmp_path / "bench"
     shutil.copytree(cells.ROOT / "bench", bench,
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     shutil.copy(cells.ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    return bench
+
+
+def test_new_files_make_a_new_cell(tmp_path):
+    bench = _copy(tmp_path)
     before = _digest(bench)
 
     cfg = json.loads((bench / "configs" / "raqo-sec7-grid1k.json")
@@ -76,3 +82,47 @@ def test_every_metric_has_a_reader_in_cells_that_report_what_it_moves():
         assert callable(spec.reader(m["name"]))
     for m in bj["per_layer"]:
         assert set(m["workloads"]) <= cells_of[m["moves"]], m["name"]
+
+
+NEW_REFERENCE = """
+from bench.reference import Planner as Selinger
+
+
+class Planner(Selinger):
+    def compare(self, plan, tables):
+        return dict(super().compare(plan, tables), tables_judged=0.0)
+"""
+
+
+def test_a_configuration_names_its_reference_by_files_alone(tmp_path):
+    bench = _copy(tmp_path)
+    before = _digest(bench)
+
+    (bench / "references" / "selinger_plus.py").write_text(NEW_REFERENCE)
+    cfg = json.loads((bench / "configs" / "raqo-sec7-grid1k.json")
+                     .read_text())
+    cfg["name"] = "tiny-named"
+    cfg["check"]["reference"] = "selinger_plus"
+    cfg["check"]["limits"]["tables_judged"] = 0.0
+    (bench / "configs" / "tiny-named.json").write_text(json.dumps(cfg))
+    spec_json = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    spec_json["configs"].append(
+        {"name": "tiny-named", "source": "test", "reduced": [],
+         "file": "bench/configs/tiny-named.json", "why": "test"})
+    spec_json["workloads"].append(
+        {"name": "tiny.named", "config": "tiny-named",
+         "traffic": "recur16.closed256", "chips": 1, "why": "test"})
+    spec_json["end_to_end"][0]["workloads"].append("tiny.named")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec_json))
+
+    after = _digest(bench)
+    assert all(after[k] == v for k, v in before.items())
+    assert after.keys() - before.keys() == {"references/selinger_plus.py",
+                                            "configs/tiny-named.json"}
+
+    cell = cells.tiny("tiny.named", root=tmp_path, bench=bench)
+    assert cell.reference.__module__ == "bench_reference_selinger_plus"
+    out = run_cell.run(cell, 5, 1.5, False, jax, cells.device(jax))
+    assert out["correct"], out["checks"]
+    assert list(out["checks"])[:3] == ["plan_gap", "cost_gap",
+                                       "tables_judged"]
