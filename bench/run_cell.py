@@ -63,8 +63,17 @@ def say(tag: str, **fields) -> None:
 
 # ------------------------------ deployment --------------------------------- #
 
+# what a planner service that declares no ``planners`` serves: its
+# ``submit`` builds a Selinger session whatever the RAQO names
+SERVED = ("selinger",)
+
+
 class Deployment:
-    """The system under test, built from a configuration and a mix."""
+    """The system under test, built from a configuration and a mix.
+
+    A configuration whose ``planner`` the service does not serve, or
+    whose ``planner_params`` (each a keyword of ``RAQO``) the program
+    does not take, fails here, before anything compiles or runs."""
 
     def __init__(self, config: dict, traffic: dict):
         from repro.core import schema as rschema
@@ -76,6 +85,12 @@ class Deployment:
         from repro.core.raqo import RAQO
         from repro.service import StreamingPlannerService
 
+        served = getattr(StreamingPlannerService, "planners", SERVED)
+        if config["planner"] not in served:
+            raise ValueError(
+                f"the configuration names the planner "
+                f"{config['planner']!r}, which the planner service does not "
+                f"serve (it serves {list(served)})")
         self.config, self.traffic = config, traffic
         self.schema = gen.build_schema(traffic["schema"])
         program_schema = rschema.Schema(
@@ -95,7 +110,8 @@ class Deployment:
                          cluster=self.cluster, planner=config["planner"],
                          resource_planning=config["resource_planning"],
                          backend=self.backend,
-                         broker=PlanBroker(backend=self.backend))
+                         broker=PlanBroker(backend=self.backend),
+                         **config.get("planner_params", {}))
         self.service = StreamingPlannerService(
             self.raqo, objective=cm["objective"])
 

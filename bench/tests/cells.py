@@ -9,8 +9,8 @@ TINY_DIMS = [{"name": "num_containers", "lo": 1, "hi": 100, "step": 1},
              {"name": "container_gb", "lo": 1, "hi": 10, "step": 1}]
 
 
-# a cell queued for a later benchmark (its rate awaits a chip sweep): its
-# configuration, mix and end-to-end readers are in ``bench/`` already
+# a cell queued for a later benchmark (its rate awaits steady sets on the
+# chip): its configuration, mix and readers are in ``bench/`` already
 QUEUED = {"grid1k.wide100.poisson": ("raqo-sec7-grid1k", "wide100.poisson",
                                      ["plan_p50_s", "plan_p95_s", "setup_s"])}
 
