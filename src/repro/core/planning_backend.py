@@ -102,16 +102,19 @@ lowest rows) reproduces the strict-< first-minimum tie-break exactly, so
 sharded results are bit-identical with the single-device and numpy
 paths.  The stacked ensemble climb shards the *request* axis instead
 (vmap lanes are independent, so trajectories are unchanged).  The host
-still performs one ``np.asarray`` sync per call — the documented fold,
-now over per-span instead of per-chunk partials.  ``REPRO_PLAN_DEVICES``
+still performs one ``np.asarray`` sync per call.  ``REPRO_PLAN_DEVICES``
 caps the device count (``1`` disables sharding); the ``devices`` ctor
 arg caps it per backend instance.
 
 Async dispatch (broker double-buffering)
 ----------------------------------------
 ``argmin_grid_many_async`` / ``hill_climb_ensemble_many_async`` enqueue
-every span program on device and return a zero-arg ``finalize`` closure
-that performs the single host sync and decodes results.  The broker's
+one program on device and return a zero-arg ``finalize`` closure that
+performs the single host sync and decodes results.  A scan whose grid
+takes more than one span (``_many_chunk`` and the device count set a
+span's rows) loops over its spans inside that program, a ``fori_loop``
+carrying each request's first minimum (``_over_spans``), so the host
+launches one program per scan however large the grid.  The broker's
 double-buffered flush waves are built on exactly this split: wave N's
 programs execute on device while the Selinger / FastRandomized drivers
 enumerate and submit wave N+1 (see ``repro.core.plan_broker``).  The
@@ -123,18 +126,20 @@ Program names and counters
 Every program the jax backend launches carries its stage (and, for the
 stacked scan, its padded width) in its name, so that profiles, HLO
 dumps and compile logs attribute device time without guessing from
-shapes: ``plan_scan_many_w{Qpad}``, ``plan_scan``, ``plan_climb_many``,
-``plan_climb`` and ``plan_fold`` (the stack-and-fold of a scan's span
-results); params reach the device as a plain transfer, no program.
-Four counters are always on (``repro.obs.ALWAYS_ON``), one increment
-per scan call or program build: ``backend.launches`` (scan programs
-enqueued), ``backend.programs_built`` (memo misses),
-``backend.decode_table_dims`` (the dimensions a built scan program
-decodes from a value table rather than by arithmetic) and
+shapes: ``plan_scan_many_w{Qpad}``, ``plan_scan``, ``plan_climb_many``
+and ``plan_climb``; params reach the device as a plain transfer, no
+program.  Five counters are always on (``repro.obs.ALWAYS_ON``), one
+increment per scan call or program build: ``backend.launches`` (scan
+programs enqueued, one per scan call), ``backend.scan_spans`` (the
+spans those programs sweep, so ``scan_spans / launches`` is the mean
+trip count of the span loop), ``backend.programs_built`` (memo
+misses), ``backend.decode_table_dims`` (the dimensions a built scan
+program decodes from a value table rather than by arithmetic) and
 ``backend.compiles`` (XLA compiles and compile-cache loads, from a
 ``jax.monitoring`` listener registered when the first jax backend is
-built).  Traced, the scan's launch loop is the ``backend.launch`` span
-and each compile a ``backend.compile`` span.
+built).  Traced, a scan's launch is the ``backend.launch`` span
+(``launches=1``, ``spans``) and each compile a ``backend.compile``
+span.
 """
 from __future__ import annotations
 
@@ -175,34 +180,54 @@ def _named(fn: Callable, name: str) -> Callable:
     return fn
 
 
+def _pick(a, b):
+    """Of two ``(cost, flat)`` pairs, elementwise, the one
+    ``jnp.argmin``'s comparator keeps: the lesser cost (a NaN wins, ``a``
+    first), on equal costs the lower flat row id."""
+    from jax import lax
+    (ac, af), (bc, bf) = a, b
+    take = (ac < bc) | (ac != ac)
+    take_f = take | ((ac == bc) & (af < bf))
+    return lax.select(take, ac, bc), lax.select(take_f, af, bf)
+
+
 def _first_min(costs, flats, axis: int):
     """``(best_cost, best_flat)`` along ``axis``: the least cost and the
     lowest flat row id attaining it, in ONE variadic reduction under
-    ``jnp.argmin``'s comparator (a NaN wins, ties go to the lower id).
-    ``flats`` (broadcast against ``costs``) ascend along ``axis``, so this
-    is the strict-< first minimum, and no gather reads the winner back."""
+    ``_pick``.  ``flats`` (broadcast against ``costs``) ascend along
+    ``axis``, so this is the strict-< first minimum, and no gather reads
+    the winner back."""
     import jax.numpy as jnp
     from jax import lax
     flats = jnp.broadcast_to(flats, costs.shape)
-
-    def pick(a, b):
-        (ac, af), (bc, bf) = a, b
-        take = (ac < bc) | (ac != ac)
-        take_f = take | ((ac == bc) & (af < bf))
-        return lax.select(take, ac, bc), lax.select(take_f, af, bf)
-
     init = (np.array(np.inf, costs.dtype),
             np.array(np.iinfo(flats.dtype).max, flats.dtype))
-    return lax.reduce((costs, flats), init, pick, (axis,))
+    return lax.reduce((costs, flats), init, _pick, (axis,))
 
 
-def _fold_fn(jnp) -> Callable:
-    """The ``plan_fold`` program's body: stack a scan's per-span
-    ``(best_cost, best_flat)`` results and keep, per request, the first
-    span holding the minimum (the strict-< order of a sequential fold)."""
-    def plan_fold(costs, flats):
-        return _first_min(jnp.stack(costs), jnp.stack(flats), 0)
-    return plan_fold
+def _over_spans(body: Callable, n_spans: int, span: int) -> Callable:
+    """``body(lo, p) -> (best_cost, best_flat)``, the scan of the ``span``
+    rows from ``lo``, made into the scan of ``n_spans`` consecutive spans
+    from ``lo``: ``body`` itself for one span, else a ``fori_loop`` on
+    the device that carries ``(best_cost, best_flat)`` from ``(inf, max
+    id)`` and merges each span's result into it, carry first, under
+    ``_first_min``'s comparator (``_pick``).  The carry holds the lower
+    rows, so the first span holding the minimum wins, as in a sequential
+    fold."""
+    if n_spans == 1:
+        return body
+    import jax
+    import jax.numpy as jnp
+
+    def sweep(lo, p):
+        cost, flat = jax.eval_shape(body, lo, p)
+        init = (jnp.full(cost.shape, np.inf, cost.dtype),
+                jnp.full(flat.shape, np.iinfo(flat.dtype).max, flat.dtype))
+
+        def step(i, best):
+            return _pick(best, body(lo + i.astype(flat.dtype) * span, p))
+        return jax.lax.fori_loop(0, n_spans, step, init)
+    return sweep
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -583,7 +608,6 @@ class JaxPlanBackend:
         _listen_for_compiles(jax)
         self._jax = jax
         self.xp = jnp
-        self._fold = jax.jit(_named(_fold_fn(jnp), "plan_fold"))
         self._shard_map = shard_map
         self.precision = precision
         self.exact = precision == "x64"
@@ -672,30 +696,31 @@ class JaxPlanBackend:
                 _decode_columns(jnp.where(ok, flat, 0), metas, sizes))
         return rows
 
-    @hot_path("dispatches one compiled program per grid span per request",
+    @hot_path("launches one compiled program per grid scan per request",
               folds=2)
     def argmin_grid(self, batch_cost_fn: BatchCostFn,
                     cluster: ClusterConditions,
                     stats: Optional[PlanningStats] = None, *,
                     params=None, chunk_size: int = DEFAULT_CHUNK) -> Result:
-        """Span-scan the grid with one jitted program per span shape.
+        """Scan the whole grid in one jitted program, span by span.
 
         With D local devices a span is ``D * chunk`` contiguous flat rows,
         ``shard_map``-partitioned so every device reduces its own
         ``chunk``-row shard to a ``(best_cost, best_flat)`` pair and the
-        cross-shard fold runs inside the program; with D == 1 this is the
-        legacy single-device chunk scan unchanged.  First-strict-minimum
-        tie-breaking matches the numpy backend everywhere: jnp.argmin
-        picks the first min within a shard, the lowest (= lowest-rows)
-        device across shards, and np.argmin the first span across spans.
-        Span results stay on device until a single cross-span fold — one
-        host sync per call, not one per span."""
+        cross-shard fold runs inside the program; with D == 1 a span is
+        one chunk.  A grid of more than one span loops over its spans on
+        the device (``_over_spans``), so the host launches once and syncs
+        once per call.  First-strict-minimum tie-breaking matches the
+        numpy backend everywhere: the first min within a shard, the
+        lowest (= lowest-rows) device across shards, and the carried
+        (lower-rows) best across spans."""
         jax, jnp = self._jax, self.xp
         stats = stats if stats is not None else PlanningStats()
         total = cluster.grid_size()
         D = self.device_count()
         chunk = int(min(chunk_size, _pad_multiple(total, D) // D))
         span = chunk * D
+        n_spans = -(-total // span)
         grids_np = grid_arrays(cluster)
         shape = tuple(len(g) for g in grids_np)
         has_params = params is not None
@@ -710,44 +735,39 @@ class JaxPlanBackend:
                 return _first_min(jnp.where(ok, costs, jnp.inf), flat, 0)
 
             if D == 1:
-                def scan_chunk(lo, p):
+                def scan_span(lo, p):
                     return shard_body(lo + jnp.arange(chunk), p)
-                return jax.jit(_named(scan_chunk, "plan_scan"))
+            else:
+                PS = jax.sharding.PartitionSpec
+                shard = self._shard_map(
+                    lambda flat, p: tuple(r[None]
+                                          for r in shard_body(flat, p)),
+                    mesh=self._plan_mesh(),
+                    in_specs=(PS("plan"), PS()),
+                    out_specs=(PS("plan"), PS("plan")))
 
-            PS = jax.sharding.PartitionSpec
-            shard = self._shard_map(
-                lambda flat, p: tuple(r[None] for r in shard_body(flat, p)),
-                mesh=self._plan_mesh(),
-                in_specs=(PS("plan"), PS()),
-                out_specs=(PS("plan"), PS("plan")))
-
-            def scan_span(lo, p):
-                # shards hold ascending contiguous flat ranges, so the
-                # first minimum over the (D,) per-shard bests (lowest
-                # device = lowest rows) is the globally first strict
-                # minimum of the span
-                cs, fs = shard(lo + jnp.arange(span), p)
-                return _first_min(cs, fs, 0)
-            return jax.jit(_named(scan_span, "plan_scan"))
+                def scan_span(lo, p):
+                    # shards hold ascending contiguous flat ranges, so the
+                    # first minimum over the (D,) per-shard bests (lowest
+                    # device = lowest rows) is the globally first strict
+                    # minimum of the span
+                    cs, fs = shard(lo + jnp.arange(span), p)
+                    return _first_min(cs, fs, 0)
+            return jax.jit(_named(_over_spans(scan_span, n_spans, span),
+                                  "plan_scan"))
 
         with self._scope():
             prog = self._program("scan", batch_cost_fn, cluster,
                                  (chunk, has_params, D), build)
             p = self._params(params)
-            span_costs, span_flats = [], []
             with _obs.span("backend.launch", cat="dispatch") as sp:
-                for lo in range(0, total, span):
-                    c, f = prog(lo, p)      # async dispatch: no host sync
-                    span_costs.append(c)
-                    span_flats.append(f)
-                    stats.configs_explored += min(span, total - lo)
+                c, f = prog(0, p)           # async dispatch: no host sync
                 if sp:
-                    sp.set(kind="scan", Qpad=1, chunk=chunk,
-                           launches=len(span_costs))
-            _metrics.counter("backend.launches").inc(len(span_costs))
-            # the first (lowest-lo) span wins ties — the same strict-<
-            # update order as the old sequential per-chunk fold
-            c, f = self._fold(span_costs, span_flats)
+                    sp.set(kind="scan", Qpad=1, chunk=chunk, launches=1,
+                           spans=n_spans)
+            _metrics.counter("backend.launches").inc()
+            _metrics.counter("backend.scan_spans").inc(n_spans)
+            stats.configs_explored += total
             cost = np.asarray(c)                            # one sync
             flat = np.asarray(f)
         best_cost = float(cost)
@@ -756,30 +776,32 @@ class JaxPlanBackend:
         idx = np.unravel_index(int(flat), shape)
         return tuple(int(g[i]) for g, i in zip(grids_np, idx)), best_cost
 
-    @hot_path("dispatches one compiled program per grid span per flush",
-              folds=3)  # params-normalizing asarray + the 2-site fold
+    @hot_path("launches one compiled program per stacked grid scan per "
+              "flush", folds=3)  # params-normalizing asarray + 2 outputs
     def argmin_grid_many_async(self, batch_cost_fn: BatchCostFn,
                                cluster: ClusterConditions,
                                params_many, *,
                                stats: Optional[PlanningStats] = None,
                                chunk_size: int = DEFAULT_CHUNK
                                ) -> Callable[[], List[Result]]:
-        """Dispatch the stacked scan for Q requests and return a zero-arg
+        """Launch the stacked scan for Q requests and return a zero-arg
         ``finalize`` closure that performs the single host sync + decode.
 
-        One vmapped jitted program per span shape: config enumeration is
-        hoisted out of the ``jax.vmap`` (every lane scans the same grid
+        One vmapped jitted program per stacked scan: config enumeration
+        is hoisted out of the ``jax.vmap`` (every lane scans the same grid
         rows), only the cost evaluation is mapped over the ``(Q, P)``
         params axis.  With D devices each span is ``D * chunk`` rows,
         ``shard_map``-partitioned so every device reduces its shard to a
         per-request ``(best_cost, best_flat)`` row and the cross-shard
         fold (first minimum = lowest device = lowest rows) runs inside
-        the program.  Chunk sizing is ``_many_chunk`` (floored shards +
-        explicit live-memory cap — the old ``chunk_size // Q`` floored to
-        tiny chunks for large Q); Q is padded to even so the compiled
-        shape set is halved at <= one wasted lane.  Nothing syncs until
-        ``finalize()``, so the broker can dispatch wave N and keep
-        enumerating wave N+1 while it runs."""
+        the program; a grid of more than one span loops over its spans
+        on the device (``_over_spans``).  Chunk sizing is ``_many_chunk``
+        (floored shards + explicit live-memory cap — the old
+        ``chunk_size // Q`` floored to tiny chunks for large Q); Q is
+        padded to even so the compiled shape set is halved at <= one
+        wasted lane.  Nothing syncs until ``finalize()``, so the broker
+        can dispatch wave N and keep enumerating wave N+1 while it
+        runs."""
         jax, jnp = self._jax, self.xp
         stats = stats if stats is not None else PlanningStats()
         pm = np.asarray(params_many, dtype=np.float64)
@@ -791,6 +813,7 @@ class JaxPlanBackend:
         Qpad = _pad_even(Q)
         chunk = _many_chunk(total, Qpad, D, chunk_size)
         span = chunk * D
+        n_spans = -(-total // span)
         grids_np = grid_arrays(cluster)
         shape = tuple(len(g) for g in grids_np)
 
@@ -803,50 +826,43 @@ class JaxPlanBackend:
                 costs = jnp.where(ok[None, :], costs, jnp.inf)  # (Q, rows)
                 return _first_min(costs, flat[None, :], 1)
 
-            name = f"plan_scan_many_w{Qpad}"
             if D == 1:
-                def scan_chunk(lo, p):
+                def scan_span(lo, p):
                     return shard_body(lo + jnp.arange(chunk), p)
-                return jax.jit(_named(scan_chunk, name))
+            else:
+                PS = jax.sharding.PartitionSpec
+                shard = self._shard_map(
+                    lambda flat, p: tuple(r[None]
+                                          for r in shard_body(flat, p)),
+                    mesh=self._plan_mesh(),
+                    in_specs=(PS("plan"), PS()),
+                    out_specs=(PS("plan"), PS("plan")))
 
-            PS = jax.sharding.PartitionSpec
-            shard = self._shard_map(
-                lambda flat, p: tuple(r[None] for r in shard_body(flat, p)),
-                mesh=self._plan_mesh(),
-                in_specs=(PS("plan"), PS()),
-                out_specs=(PS("plan"), PS("plan")))
-
-            def scan_span(lo, p):
-                cs, fs = shard(lo + jnp.arange(span), p)    # (D, Qpad)
-                # first minimum over the device axis = lowest device =
-                # lowest flat rows: the strict-< tie-break per request
-                return _first_min(cs, fs, 0)
-            return jax.jit(_named(scan_span, name))
+                def scan_span(lo, p):
+                    cs, fs = shard(lo + jnp.arange(span), p)  # (D, Qpad)
+                    # first minimum over the device axis = lowest device =
+                    # lowest flat rows: the strict-< tie-break per request
+                    return _first_min(cs, fs, 0)
+            return jax.jit(_named(_over_spans(scan_span, n_spans, span),
+                                  f"plan_scan_many_w{Qpad}"))
 
         with self._scope():
             prog = self._program("scan_many", batch_cost_fn, cluster,
                                  (chunk, Qpad, P, D), build)
             p = self._params(np.pad(pm, ((0, Qpad - Q), (0, 0)),
                                     mode="edge"))
-            span_costs, span_flats = [], []
             with _obs.span("backend.launch", cat="dispatch") as sp:
-                for lo in range(0, total, span):
-                    c, f = prog(lo, p)      # async dispatch: no host sync
-                    span_costs.append(c)
-                    span_flats.append(f)
-                    stats.configs_explored += Q * min(span, total - lo)
+                c, f = prog(0, p)           # async dispatch: no host sync
                 if sp:
                     sp.set(kind="scan_many", Qpad=Qpad, chunk=chunk,
-                           launches=len(span_costs))
-            _metrics.counter("backend.launches").inc(len(span_costs))
+                           launches=1, spans=n_spans)
+            _metrics.counter("backend.launches").inc()
+            _metrics.counter("backend.scan_spans").inc(n_spans)
+            stats.configs_explored += Q * total
 
         def finalize() -> List[Result]:
-            with self._scope():
-                # the first (lowest-lo) span wins ties — the same strict-<
-                # update order as the sequential per-chunk loop
-                c, f = self._fold(span_costs, span_flats)
-                costs = np.asarray(c)[:Q]                   # one sync
-                flats = np.asarray(f)[:Q]
+            costs = np.asarray(c)[:Q]                       # one sync
+            flats = np.asarray(f)[:Q]
             out: List[Result] = []
             for q in range(Q):
                 cq = float(costs[q])

@@ -27,6 +27,7 @@ __all__ = [
 ALWAYS_ON = (
     "backend.compiles",            # XLA compiles and compile-cache loads
     "backend.launches",            # scan program launches
+    "backend.scan_spans",          # spans those programs sweep
     "backend.programs_built",      # program-memo misses
     "backend.decode_table_dims",   # value-table dims decoded, per build
     "broker.sweep_rows.sync",      # grid rows swept by synchronous flushes

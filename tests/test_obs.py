@@ -103,7 +103,8 @@ def test_disabled_path_is_allocation_free():
                     if sp:
                         sp.set(wave=i)
             tr.complete("backend.compile", 0, cat="compile", end_ns=1)
-            reg.counter("backend.launches").inc(3)
+            reg.counter("backend.launches").inc()
+            reg.counter("backend.scan_spans").inc(3)
             reg.counter("broker.sweep_rows.async").inc(1000)
 
     loop(1000)                        # warm caches / lazy init
@@ -464,10 +465,11 @@ def _xp_fn(cfgs, p):
 
 @needs_jax
 def test_launches_count_every_span_of_every_scan(monkeypatch):
-    """``backend.launches`` over a small lockstep run equals the sum,
-    over the stacked scans it dispatched, of ceil(grid / span), the span
-    computed by hand from ``_many_chunk`` (the live-element cap lowered
-    so that each scan takes several launches)."""
+    """Over a small lockstep run ``backend.launches`` equals the number
+    of stacked scans dispatched, one program each, and
+    ``backend.scan_spans`` the sum over them of ceil(grid / span), the
+    span computed by hand from ``_many_chunk`` (the live-element cap
+    lowered so that each scan loops over several spans)."""
     from repro.core import planning_backend as pb
     monkeypatch.setattr(pb, "MAX_LIVE_ELEMENTS", 256)
     be = pb.JaxPlanBackend()
@@ -483,6 +485,7 @@ def test_launches_count_every_span_of_every_scan(monkeypatch):
     try:
         _run_lockstep(n_queries=4, backend=be)
         launches = mx.counter("backend.launches").value
+        spans = mx.counter("backend.scan_spans").value
     finally:
         mx.reset()
     D = be.device_count()
@@ -490,14 +493,15 @@ def test_launches_count_every_span_of_every_scan(monkeypatch):
                                                 pb.DEFAULT_CHUNK)))
                for q, total in scans)
     assert scans and want > len(scans)
-    assert launches == want
+    assert launches == len(scans)
+    assert spans == want
 
 
 @needs_jax
 def test_fresh_width_compiles_once_and_a_repeat_does_not():
     """With tracing off, the first stacked scan of a width builds one
-    program and compiles it and its fold; the same width again neither
-    builds nor compiles."""
+    program and compiles it alone (no separate fold program); the same
+    width again neither builds nor compiles."""
     from repro.core.planning_backend import JaxPlanBackend
     assert not get_tracer().enabled
     be = JaxPlanBackend()
@@ -512,7 +516,7 @@ def test_fresh_width_compiles_once_and_a_repeat_does_not():
     b0, c0 = counts()
     first = be.argmin_grid_many(_xp_fn, cluster, pm)
     b1, c1 = counts()
-    assert (b1 - b0, c1 - c0) == (1, 2)         # plan_scan_many_w4, fold
+    assert (b1 - b0, c1 - c0) == (1, 1)         # plan_scan_many_w4
     assert be.argmin_grid_many(_xp_fn, cluster, pm) == first
     assert counts() == (b1, c1)
 
@@ -540,6 +544,7 @@ def test_traced_compile_and_launch_spans_nest_under_the_launch(traced):
     (launch,) = tr.spans("backend.launch")
     assert launch["args"]["kind"] == "scan_many"
     assert launch["args"]["Qpad"] == 6 and launch["args"]["launches"] == 1
+    assert launch["args"]["spans"] == 1
     inner = [e for e in tr.spans("backend.compile")
              if e["args"]["parent"] == launch["args"]["id"]]
     assert [e["args"]["fun_name"] for e in inner] == \

@@ -213,6 +213,92 @@ def test_jax_scan_decodes_without_gather(method):
     assert not re.search(r"\b(gather|concatenate)\(", text)
 
 
+# ------------------- scans that loop over their spans ---------------------- #
+# The 1,240-row "range" grid scanned in spans of SPAN_ROWS rows: 7 spans,
+# the last holding 40 rows.  argmin_grid's span is its chunk_size;
+# argmin_grid_many's is _many_chunk's, set by the live-element cap.
+
+SPAN_ROWS = 200
+
+
+def _span_case(case, shape):
+    """A cost table over ``shape`` whose winner sits where the looped
+    scan must merge spans right."""
+    rng = np.random.default_rng(17)
+    table = rng.integers(8, 64, size=shape).astype(np.float64)
+    flat = table.reshape(-1)                  # a view: writes land in table
+    if case == "tie_across_spans":
+        flat[[1210, 260, 700]] = 2.0          # spans 6, 1, 3: 260 wins
+    elif case == "partial_last_span":
+        flat[1230] = 1.0
+    elif case == "all_inf":
+        flat[:] = np.inf
+    elif case == "nan":
+        flat[5] = 1.0                         # span 0's finite minimum
+        flat[910] = np.nan                    # a NaN in span 4 wins
+    return table
+
+
+def _oracle(cluster, costs):
+    """The first minimum of ``costs`` in ``all_configs`` order, a NaN
+    first (``np.argmin``); (None, inf) where every cost is inf."""
+    k = int(np.argmin(costs.reshape(-1)))
+    c = float(costs.reshape(-1)[k])
+    if math.isinf(c):
+        return None, math.inf
+    return tuple(int(v) for v in enumerate_configs(cluster, k, k + 1)[0]), c
+
+
+def _same(got, ref):
+    (rg, cg), (rr, cr) = got, ref
+    return rg == rr and (cg == cr or (math.isnan(cg) and math.isnan(cr)))
+
+
+@needs_jax
+@pytest.mark.parametrize("case", ["tie_across_spans", "partial_last_span",
+                                  "all_inf", "nan"])
+@pytest.mark.parametrize("method", ["argmin_grid", "argmin_grid_many"])
+@pytest.mark.parametrize("backend", ["jax", "jax_x64"])
+def test_looped_scan_matches_numpy_oracle_across_spans(backend, method,
+                                                       case, monkeypatch):
+    """A scan of many spans, looped on the device, returns the numpy
+    oracle's config and cost bit for bit: ties between spans go to the
+    lower row, a partial last span counts, an all-inf surface has no
+    plan, a NaN wins.  It is one launch that sweeps every span."""
+    import jax.numpy as jnp
+    from repro.core import planning_backend as pb
+    from repro.obs import get_metrics
+    cluster = DECODE_GRIDS["range"]
+    shape = tuple(len(d.grid()) for d in cluster.dims)
+    table = _span_case(case, shape)
+    fn = _table_param_fn(cluster, table, jnp)
+    be = get_backend(backend)
+    D = be.device_count()
+    monkeypatch.setattr(pb, "MAX_LIVE_ELEMENTS", 2 * SPAN_ROWS // D)
+    n_spans = -(-cluster.grid_size() // SPAN_ROWS)
+    mx = get_metrics()
+
+    def counts():
+        snap = mx.snapshot()
+        return (snap.get("backend.launches", 0),
+                snap.get("backend.scan_spans", 0))
+    before = counts()
+    if method == "argmin_grid":
+        got = [be.argmin_grid(fn, cluster, chunk_size=SPAN_ROWS // D)]
+        ref = [_oracle(cluster, table)]
+    else:
+        pm = np.array([[0.0], [3.0]])
+        got = be.argmin_grid_many(fn, cluster, pm)
+        ref = [_oracle(cluster, table + p) for p in pm[:, 0]]
+    after = counts()
+    assert n_spans == 7
+    assert (after[0] - before[0], after[1] - before[1]) == (1, n_spans)
+    assert all(_same(g, r) for g, r in zip(got, ref)), (got, ref)
+    if case == "tie_across_spans":
+        assert got[0][0] == tuple(int(v) for v in
+                                  enumerate_configs(cluster, 260, 261)[0])
+
+
 @needs_jax
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 10_000), na=st.integers(3, 12),

@@ -172,6 +172,77 @@ def test_sharded_backend_matches_numpy_oracle(backend, devices, variant):
     assert out["ok"], out["bad"]
 
 
+# The span-boundary tie on the mesh: a 1,240-row grid in spans of 200 rows
+# (4 shards of 50), so the scan loops over 7 spans on the device, the last
+# partial.  Equal minima at rows 1210 (span 6, shard 0), 260 (span 1,
+# shard 1) and 700 (span 3, shard 2): row 260 must win, not the lowest
+# shard of a later span.
+_SPAN_TIE_CHILD = """
+import json, sys
+import numpy as np
+import jax
+import jax.numpy as jnp
+from repro.core import planning_backend as pb
+from repro.core.cluster import ClusterConditions, ResourceDim
+from repro.obs import get_metrics
+
+name, want = sys.argv[1], int(sys.argv[2])
+be = pb.get_backend(name)
+assert be.device_count() == want, (be.device_count(), want)
+pb.MAX_LIVE_ELEMENTS = 2 * 50               # (Qpad 2) x 50 rows a shard
+cluster = ClusterConditions(dims=(ResourceDim("a", 3, 159, step=4),
+                                  ResourceDim("b", 2, 92, step=3)))
+shape = tuple(len(d.grid()) for d in cluster.dims)
+table = np.random.default_rng(17).integers(8, 64, size=shape).astype(float)
+table.reshape(-1)[[1210, 260, 700]] = 2.0
+
+
+def fn_of(xp):
+    ga, gb = (xp.asarray(np.asarray(d.grid())) for d in cluster.dims)
+    t = xp.asarray(table)
+
+    def fn(cfgs, params=None):
+        a = xp.asarray(cfgs)
+        c = t[xp.searchsorted(ga, a[:, 0]), xp.searchsorted(gb, a[:, 1])]
+        return c if params is None else c + params[0]
+    return fn
+
+
+k = int(np.argmin(table.reshape(-1)))
+cfg = [int(v) for v in pb.enumerate_configs(cluster, k, k + 1)[0]]
+pm = np.array([[0.0], [3.0]])
+mx = get_metrics()
+got = [be.argmin_grid(fn_of(jnp), cluster, chunk_size=50)]
+got += be.argmin_grid_many(fn_of(jnp), cluster, pm)
+snap = mx.snapshot()
+print(json.dumps({"winner": k, "cfg": cfg,
+                  "got": [[list(r), c] for r, c in got],
+                  "launches": snap.get("backend.launches", 0),
+                  "spans": snap.get("backend.scan_spans", 0)}))
+"""
+
+
+@needs_jax
+@pytest.mark.parametrize("backend", ["jax", "jax_x64"])
+def test_sharded_looped_scan_ties_across_spans_take_the_lower_row(backend):
+    """On a 4-device mesh a scan that loops over 7 spans returns the
+    first of minima tied across spans, through ``argmin_grid`` and
+    ``argmin_grid_many``, in one launch per scan."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("REPRO_PLAN_DEVICES", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _SPAN_TIE_CHILD, backend, "4"],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.splitlines()[-1])
+    assert out["winner"] == 260
+    assert out["got"] == [[out["cfg"], 2.0], [out["cfg"], 2.0],
+                          [out["cfg"], 5.0]]
+    assert (out["launches"], out["spans"]) == (2, 14)
+
+
 # -------------------- single-device (rollback) path ------------------------- #
 
 @needs_jax
