@@ -4,24 +4,23 @@
     python chip_smoke.py --chips 4    # the sharded scan on a 4-chip host
 
 Drives ``StreamingPlannerService`` -> ``LockstepDriver`` -> ``PlanBroker``
--> plan backend, with the ``jax`` and ``pallas`` backends compiled for the
-chip, in one process (a process that has touched JAX holds the chip).
+-> plan backend, with the ``jax`` backend compiled for the chip, in one
+process (a process that has touched JAX holds the chip).
 
-Phase A serves ``streaming_bench.FULL`` (256 concurrent tenants, 512
-queries of 2-6 tables from ``random_schema(16, seed=0)``) on the paper's
-§VII grid ``paper_cluster(100, 10)`` and compares every ticket's plan
-with the same stream planned on the float64 ``numpy`` backend.  Phase B
-serves the same stream on §VII-C's ``scaled_cluster(100_000, 100)``
-(10M configurations), requires the ``jax`` and ``pallas`` plans to agree,
-and re-solves a sample of the flushed requests on ``numpy``.  ``--chips
-4`` instead runs Phase B's single-request scan and stacked flush on a
-4-device plan mesh and compares them with the same backends on one
-device and with the ``numpy`` sample.
+Phase A serves ``FULL`` (256 concurrent tenants, 512 queries of 2-6
+tables from ``random_schema(16, seed=0)``) on the paper's §VII grid
+``paper_cluster(100, 10)`` and compares every ticket's plan with the
+same stream planned on the float64 ``numpy`` backend.  Phase B serves
+the same stream on §VII-C's ``scaled_cluster(100_000, 100)`` (10M
+configurations) and re-solves a sample of the flushed requests on
+``numpy``.  ``--chips 4`` instead runs Phase B's single-request scan and
+stacked flush on a 4-device plan mesh and compares them with the same
+backend on one device and with the ``numpy`` sample.
 
 "Agree" means the same plan, or, where the f32 device broke a near-tie
 differently, a plan whose float64 cost is within ``REL_TOL`` of the
-oracle's.  Any other difference, an interpreted kernel, a platform other
-than ``tpu`` or any exception exits nonzero without the final line.  The
+oracle's.  Any other difference, a platform other than ``tpu`` or any
+exception exits nonzero without the final line.  The
 timings printed on the way are set-up observations, not benchmark
 numbers.  The last line of stdout is the JSON verdict.
 """
@@ -39,9 +38,11 @@ import numpy as np
 ROOT = Path(__file__).resolve().parent
 REL_TOL = 1e-6            # float64 cost tolerance for a differing plan
 N_RESOLVE = 16            # flushed requests re-solved on numpy
-WARMUP_QUERIES = 64       # streaming_bench's warm-up: FULL n_queries // 8
+FULL = {"concurrency": 256, "n_queries": 512}   # the 256-tenant stream
+SCHEMA_TABLES = 16        # random_schema(SCHEMA_TABLES, seed=0)
+WARMUP_QUERIES = 64       # warm-up prefix: FULL n_queries // 8
 FLUSH_Q = 64              # stacked-flush width of the four-chip phase
-WORKLOAD_SEED = 43        # streaming_bench's FULL closed-loop seed
+WORKLOAD_SEED = 43        # the stream's arrival seed
 
 
 class Recorder:
@@ -84,16 +85,29 @@ def _device(jax) -> dict:
             "count": len(jax.devices())}
 
 
-def _backend(name: str, devices: int):
+def _workload(schema, n: int, seed: int) -> list:
+    """``n`` queries of 64 tenants as ``(tenant, tables)``, in the order
+    of a seeded Poisson arrival trace."""
+    from repro.service import poisson_trace
+    trace = poisson_trace(schema, n, rate=1000.0, seed=seed, tenants=64)
+    return [(a.tenant, a.tables) for a in trace]
+
+
+def _tree_sig(n):
+    """A plan tree's tables, join impls, resources and float64 costs."""
+    if n is None:
+        return None
+    if n.is_leaf:
+        return (tuple(sorted(n.tables)),)
+    return (tuple(sorted(n.tables)), n.impl, tuple(n.resources),
+            n.total_cost, _tree_sig(n.left), _tree_sig(n.right))
+
+
+def _backend(devices: int):
     from repro.core.planning_backend import JaxPlanBackend
-    from repro.kernels.plan_scan import PallasPlanBackend
-    be = JaxPlanBackend(devices=devices) if name == "jax" else \
-        PallasPlanBackend(devices=devices)
-    if getattr(be, "interpret", False):
-        raise SystemExit(f"chip_smoke: backend {name} would interpret "
-                         "its kernels")
+    be = JaxPlanBackend(devices=devices)
     _require(be.device_count() == devices,
-             f"{name} plans on {be.device_count()} device(s), not {devices}")
+             f"jax plans on {be.device_count()} device(s), not {devices}")
     return be
 
 
@@ -171,7 +185,6 @@ class Tally:
 
 def _compare_tickets(got, ref) -> Tally:
     """Every ticket's plan against ``ref``'s, by their float64 costs."""
-    from benchmarks.streaming_bench import _tree_sig
     _require(len(got) == len(ref), f"{len(got)} tickets, {len(ref)} in ref")
     tally = Tally()
     for g, r in zip(got, ref):
@@ -241,37 +254,31 @@ def phase_a(dev, schema, work, concurrency) -> None:
     cluster = paper_cluster(100, 10)
     oracle, _ = _serve(_raqo(schema, cluster, get_backend("numpy")), work,
                        concurrency, warm=False)
-    for name in ("jax", "pallas"):
-        raqo = _raqo(schema, cluster, _backend(name, 1))
-        tickets, rep = _serve(raqo, work, concurrency, warm=True)
-        _report_run("A", name, rep, dev)
-        _say(f"phaseA.{name}.vs_numpy", tickets=len(tickets),
-             numpy_researches=rep["researches"],
-             **_compare_tickets(tickets, oracle).fields())
+    raqo = _raqo(schema, cluster, _backend(1))
+    tickets, rep = _serve(raqo, work, concurrency, warm=True)
+    _report_run("A", "jax", rep, dev)
+    _say("phaseA.jax.vs_numpy", tickets=len(tickets),
+         numpy_researches=rep["researches"],
+         **_compare_tickets(tickets, oracle).fields())
 
 
 def phase_b(dev, schema, work, concurrency) -> None:
     from repro.core.cluster import scaled_cluster
     cluster = scaled_cluster(100_000, 100)
-    served, answers, refs = {}, {}, {}
-    for name in ("jax", "pallas"):
-        rec = Recorder(_backend(name, 1))
-        raqo = _raqo(schema, cluster, rec)
-        served[name], rep = _serve(raqo, work, concurrency, warm=True)
-        _report_run("B", name, rep, dev)
-        refs.update(_reference_fns(raqo))
-        answers[name] = {(refs[id(fn)][0], p): r for fn, p, r in rec.flushed}
-        if name == "jax":
-            picks = _sample(rec.flushed, refs, N_RESOLVE)
-    _say("phaseB.pallas_vs_jax", tickets=len(served["pallas"]),
-         **_compare_tickets(served["pallas"], served["jax"]).fields())
+    rec = Recorder(_backend(1))
+    raqo = _raqo(schema, cluster, rec)
+    _, rep = _serve(raqo, work, concurrency, warm=True)
+    _report_run("B", "jax", rep, dev)
+    refs = _reference_fns(raqo)
+    answers = {"jax": {(refs[id(fn)][0], p): r for fn, p, r in rec.flushed}}
+    picks = _sample(rec.flushed, refs, N_RESOLVE)
     _say("phaseB.numpy_resolve", requests=len(picks),
          **_resolve_sample(picks, cluster, answers))
 
 
 def phase_sharded(dev, schema, work, concurrency, chips) -> None:
     """Phase B's scan and stacked flush on a ``chips``-device plan mesh,
-    against the same backends on one device and the numpy sample."""
+    against the same backend on one device and the numpy sample."""
     from repro.core.cluster import paper_cluster, scaled_cluster
     from repro.core.planning_backend import get_backend
     # real request params: the flushed (ss, ls) of the served stream,
@@ -291,35 +298,34 @@ def phase_sharded(dev, schema, work, concurrency, chips) -> None:
     ref_fns = dict(refs.values())
     cluster = scaled_cluster(100_000, 100)
     answers = {}
-    for name in ("jax", "pallas"):
-        for n_dev in (chips, 1):
-            be = _backend(name, n_dev)
-            costing = _raqo(schema, cluster, be)._costing()
-            flush, scan = {}, {}
-            t0 = time.perf_counter()
-            for impl, ps in sorted(flushes.items()):
-                fn = costing._grid_fn(impl, be)
-                res = be.argmin_grid_many(fn, cluster, np.asarray(ps))
-                flush.update({(impl, p): r for p, r in zip(ps, res)})
-                for _, p, _ in (x for x in picks if x[0] == impl):
-                    scan[(impl, p)] = be.argmin_grid(fn, cluster,
-                                                     params=np.asarray(p))
-            answers[f"{name}@{n_dev}.flush"] = flush
-            answers[f"{name}@{n_dev}.scan"] = scan
-            wall_s = time.perf_counter() - t0
-            out_devs = _output_devices(be)
-            _say(f"sharded.{name}@{n_dev}", **dev, plan_devices=n_dev,
-                 output_devices=out_devs, wall_s=wall_s)
-            _require(out_devs == n_dev, f"{name}@{n_dev}: outputs land on "
-                     f"{out_devs} device(s)")
-        for op in ("flush", "scan"):
-            a, b = answers[f"{name}@{chips}.{op}"], answers[f"{name}@1.{op}"]
-            tally = Tally()
-            for k in sorted(b):
-                _agree(tally, f"{name} {op} {chips} vs 1 device", a[k], b[k],
-                       ref_fns[k[0]], k[1])
-            _say(f"sharded.{name}.{op}.{chips}_vs_1", requests=len(b),
-                 **tally.fields())
+    for n_dev in (chips, 1):
+        be = _backend(n_dev)
+        costing = _raqo(schema, cluster, be)._costing()
+        flush, scan = {}, {}
+        t0 = time.perf_counter()
+        for impl, ps in sorted(flushes.items()):
+            fn = costing._grid_fn(impl, be)
+            res = be.argmin_grid_many(fn, cluster, np.asarray(ps))
+            flush.update({(impl, p): r for p, r in zip(ps, res)})
+            for _, p, _ in (x for x in picks if x[0] == impl):
+                scan[(impl, p)] = be.argmin_grid(fn, cluster,
+                                                 params=np.asarray(p))
+        answers[f"jax@{n_dev}.flush"] = flush
+        answers[f"jax@{n_dev}.scan"] = scan
+        wall_s = time.perf_counter() - t0
+        out_devs = _output_devices(be)
+        _say(f"sharded.jax@{n_dev}", **dev, plan_devices=n_dev,
+             output_devices=out_devs, wall_s=wall_s)
+        _require(out_devs == n_dev, f"jax@{n_dev}: outputs land on "
+                 f"{out_devs} device(s)")
+    for op in ("flush", "scan"):
+        a, b = answers[f"jax@{chips}.{op}"], answers[f"jax@1.{op}"]
+        tally = Tally()
+        for k in sorted(b):
+            _agree(tally, f"jax {op} {chips} vs 1 device", a[k], b[k],
+                   ref_fns[k[0]], k[1])
+        _say(f"sharded.jax.{op}.{chips}_vs_1", requests=len(b),
+             **tally.fields())
     _say("sharded.numpy_resolve", requests=len(picks),
          **_resolve_sample(picks, cluster, answers))
 
@@ -331,14 +337,10 @@ def _output_devices(be) -> int:
     most = 0
     for key, (_, prog) in be._programs.items():
         kind, extra = key[0], key[3]
-        if kind == "scan_many":       # jax: (chunk, Qpad, P, D)
+        if kind == "scan_many":       # (chunk, Qpad, P, D)
             out = prog(0, np.zeros(extra[1:3], np.float32))
-        elif kind == "scan":          # jax: (chunk, has_params, D)
+        elif kind == "scan":          # (chunk, has_params, D)
             out = prog(0, np.zeros(2, np.float32))
-        elif kind == "pscan_sh":      # pallas: (block, nbs, D, nq, ...)
-            out = prog(np.zeros(max(1, extra[3]) * extra[5], np.float32))
-        elif kind in ("pscan", "pscan_many"):
-            out = prog(np.zeros(max(1, extra[2]) * extra[5], np.float32))
         else:
             continue
         most = max(most, len(out[0].sharding.device_set))
@@ -359,7 +361,6 @@ def main() -> None:
         sys.exit(f"chip_smoke: --chips {args.chips} but JAX sees "
                  f"{dev['count']} device(s)")
     sys.path.insert(0, str(ROOT / "src"))
-    from benchmarks.streaming_bench import FULL, SCHEMA_TABLES, _workload
     from repro.core.schema import random_schema
     from repro.launch.compile_cache import enable_compile_cache
     _say("setup", compile_cache=enable_compile_cache(), **dev)

@@ -10,25 +10,18 @@ Runs the three passes —
 applies inline pragmas, prints the human report, optionally writes the
 structured JSON, and exits non-zero when any *unallowed* finding reaches
 the ``--fail-on`` threshold.
-
-``--history`` appends a flat numeric snapshot (severity counts + the
-per-backend compile-count table) to ``BENCH_plan_lint.json`` so the
-bench trend report can chart lint drift alongside perf drift.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import sys
-import time
 from pathlib import Path
 from typing import Dict, List
 
 from repro.analysis.report import (Finding, apply_pragmas, render_report,
-                                   severity_at_least, summarize, write_json)
+                                   severity_at_least, write_json)
 
 _REPO_ROOT = Path(__file__).resolve().parents[3]
-HISTORY_PATH = _REPO_ROOT / "BENCH_plan_lint.json"
 
 
 def collect(backends=None, skip_audit: bool = False):
@@ -61,28 +54,6 @@ def collect(backends=None, skip_audit: bool = False):
     return findings, table, thash
 
 
-def append_history(findings: List[Finding], table, thash) -> None:
-    s = summarize(findings)
-    snap = {"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-            "allowed": float(s["allowed"])}
-    for sev, n in s["by_severity"].items():
-        snap[sev] = float(n)
-    for backend, probes in table.items():
-        for probe, n in probes.items():
-            snap[f"compile.{backend}.{probe}"] = float(n)
-    doc = {"bench": "plan_lint", "history": []}
-    if HISTORY_PATH.exists():
-        try:
-            doc = json.loads(HISTORY_PATH.read_text())
-        except (json.JSONDecodeError, OSError):
-            pass
-    doc.setdefault("history", []).append(snap)
-    doc["history"] = doc["history"][-200:]
-    doc["compile_counts"] = table
-    doc["table_hash"] = thash
-    HISTORY_PATH.write_text(json.dumps(doc, indent=1) + "\n")
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis",
@@ -98,8 +69,6 @@ def main(argv=None) -> int:
                          "recompile audit (default: all available)")
     ap.add_argument("--skip-audit", action="store_true",
                     help="skip the dynamic recompile probe sweep")
-    ap.add_argument("--history", action="store_true",
-                    help="append a snapshot to BENCH_plan_lint.json")
     args = ap.parse_args(argv)
 
     backends = args.backends.split(",") if args.backends else None
@@ -108,8 +77,6 @@ def main(argv=None) -> int:
     print(render_report(findings, table or None, thash))
     if args.json is not None:
         write_json(args.json, findings, table or None, thash)
-    if args.history:
-        append_history(findings, table, thash)
 
     if args.fail_on == "never":
         return 0

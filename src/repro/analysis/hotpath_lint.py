@@ -1,10 +1,10 @@
 """Pass 3 — AST host-sync lint over designated hot paths.
 
 Hot paths are functions decorated ``@hot_path("reason")``
-(``repro.analysis.registry``): the broker flush machinery, the jitted
-backends' per-request scan/climb drivers, and the Pallas kernel
-builders.  Inside them — including nested ``def``s — the following calls
-force a device->host synchronization and are flagged:
+(``repro.analysis.registry``): the broker flush machinery and the
+jitted backend's per-request scan/climb drivers.  Inside them —
+including nested ``def``s — the following calls force a device->host
+synchronization and are flagged:
 
     float(x)            .item()             np.asarray(x)
     jax.device_get(x)   x.block_until_ready()

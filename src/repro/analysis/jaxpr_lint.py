@@ -1,8 +1,8 @@
 """Pass 1 — jaxpr contract lint for registered cost surfaces.
 
 Traces every registered batch cost surface (``repro.analysis.registry``)
-to a jaxpr exactly the way ``JaxPlanBackend`` / ``_split_cost_fn`` would,
-and checks the machine-checkable invariants the backends depend on:
+to a jaxpr exactly the way ``JaxPlanBackend`` would, and checks the
+machine-checkable invariants the backends depend on:
 
 rule ``tracer-bool`` (error)
     Tracing raised a concretization error: the surface branches on (or
@@ -23,25 +23,22 @@ rule ``weak-type`` (warn)
     cache-identical — the program-memo churn class.  Anchor the dtype
     (e.g. multiply by ``xp.asarray(1.0)`` or cast explicitly).
 
-rule ``closure-capture`` (warn / error)
+rule ``closure-capture`` (warn)
     A 0-d array captured from the enclosing scope became a jaxpr const:
     that is a per-request scalar baked into the compiled program (a new
-    value means a full retrace), and the Pallas builders must reshape it
-    to hoist it to a VMEM input.  Per-request scalars belong in
-    ``params``.  Escalates to error when a captured const exceeds the
-    VMEM hoist budget (it cannot live as a whole-array kernel input).
+    value means a full retrace).  Per-request scalars belong in
+    ``params``.
 
 rule ``cross-config-reduce`` (error)
     A reduction runs across the config axis.  Costs must be elementwise
-    per configuration: the chunked scans and Pallas grid blocks evaluate
-    the surface on *slices* of the grid, so any cross-config coupling
-    makes the result depend on chunk geometry and breaks the
-    strict-``<`` first-minimum contract between backends.
+    per configuration: the chunked scans evaluate the surface on
+    *slices* of the grid, so any cross-config coupling makes the result
+    depend on chunk geometry and breaks the strict-``<`` first-minimum
+    contract between backends.
 
 Python/numpy scalar captures fold into jaxpr *literals* (not consts) and
 are indistinguishable from legitimate model coefficients, so only array
-captures are detectable — which is exactly the set ``_split_cost_fn``
-must hoist.
+captures are detectable.
 """
 from __future__ import annotations
 
@@ -58,9 +55,6 @@ from repro.analysis.report import Finding
 # axis of this size are reductions over configs (no shipped surface has
 # another axis of 7)
 TRACE_ROWS = 7
-# whole-array VMEM inputs share ~16 MB with the cost temporaries; a
-# hoisted const beyond this cannot ride along as a kernel input
-VMEM_CONST_BUDGET = 4 << 20
 
 LOW_PRECISION = ("float16", "bfloat16")
 REDUCE_PRIMS = {"reduce_min", "reduce_max", "reduce_sum", "reduce_prod",
@@ -126,7 +120,7 @@ def lint_cost_fn(fn: Callable, n_dims: int, p_width: int, *,
             "tracer-bool", "error",
             "data-dependent Python control flow or host conversion while "
             f"tracing ({type(e).__name__}: {first}) — use xp.where masks; "
-            "the surface cannot run inside the jitted/Pallas scans")]
+            "the surface cannot run inside the jitted scans")]
     except Exception as e:  # noqa: BLE001 — any trace failure is a finding
         first = str(e).strip().splitlines()[0]
         return [finding(
@@ -193,29 +187,13 @@ def lint_cost_fn(fn: Callable, n_dims: int, p_width: int, *,
 
     # ---- closure consts --------------------------------------------------- #
     for const in closed.consts:
-        try:
-            arr = np.asarray(const)
-        except Exception:  # noqa: BLE001 — unhoistable capture
-            out.append(finding(
-                "closure-capture", "error",
-                f"captured constant of type {type(const).__name__} cannot "
-                "be materialized as an array — _split_cost_fn cannot hoist "
-                "it to a Pallas kernel input"))
-            continue
-        if arr.ndim == 0:
+        if np.ndim(const) == 0:
             out.append(finding(
                 "closure-capture", "warn",
-                f"0-d {arr.dtype} array captured from the enclosing scope "
-                "is baked into the traced program (a new value means a "
-                "full retrace, and the Pallas builders must reshape it to "
-                "hoist it) — per-request scalars belong in params"))
-        elif arr.nbytes > VMEM_CONST_BUDGET:
-            out.append(finding(
-                "closure-capture", "error",
-                f"captured {arr.dtype}{arr.shape} const is "
-                f"{arr.nbytes / 1e6:.1f} MB — beyond the "
-                f"{VMEM_CONST_BUDGET >> 20} MB VMEM hoist budget for "
-                "whole-array kernel inputs"))
+                f"0-d {np.result_type(const)} array captured from the "
+                "enclosing scope is baked into the traced program (a new "
+                "value means a full retrace) — per-request scalars belong "
+                "in params"))
     return out
 
 

@@ -27,9 +27,8 @@ rule ``stale-program`` (error)
     request it was not built for (jit may mask this by shape-retracing
     under the memo's back, or worse, bake a stale static value).
 
-The expected table itself is emitted (JSON + report) so the bench can
-hash and trend it: a PR that changes compile-count behaviour moves the
-hash, which shows in ``artifacts/bench_report.md``.
+The expected table itself is emitted (JSON + report) with its hash: a
+PR that changes compile-count behaviour moves the hash.
 
 **Static key-coverage check** (``audit_source``).  An AST pass over the
 backend sources finds every ``self._program(kind, fn, cluster, extra,
@@ -61,7 +60,6 @@ from repro.core.cluster import ClusterConditions, ResourceDim
 _REPO_ROOT = Path(__file__).resolve().parents[3]
 _BACKEND_SOURCES = (
     _REPO_ROOT / "src" / "repro" / "core" / "planning_backend.py",
-    _REPO_ROOT / "src" / "repro" / "kernels" / "plan_scan.py",
 )
 
 PROBES = ("scan_params_reuse", "scan_chunk_churn", "scan_many_qpad",
@@ -70,15 +68,12 @@ PROBES = ("scan_params_reuse", "scan_chunk_churn", "scan_many_qpad",
 
 # The per-backend compile-count contract for the probe battery below,
 # at ONE plan-mesh device.  numpy compiles nothing; jax keys chunk
-# geometry (so the chunk churn probe legitimately builds twice); pallas
-# derives its block size from the backend (chunk_size is not a trace
-# input, so one build); the pallas climb reuses ONE neighbor-step
-# program across a stacked batch (its many-path loops per request),
-# where jax builds per padded Q.  With a multi-device plan mesh the jax
-# contract SHRINKS (device-even padding collapses chunk/Qpad classes) —
-# use ``expected_compile_counts(name, n_devices)``, which recomputes the
-# device-dependent probes from the same geometry helpers the backends
-# key their program memos on.
+# geometry (so the chunk churn probe legitimately builds twice) and
+# builds a stacked climb per padded Q.  With a multi-device plan mesh
+# the jax contract SHRINKS (device-even padding collapses chunk/Qpad
+# classes) — use ``expected_compile_counts(name, n_devices)``, which
+# recomputes the device-dependent probes from the same geometry helpers
+# the backends key their program memos on.
 EXPECTED_COMPILE_COUNTS: Dict[str, Dict[str, int]] = {
     "numpy": {p: 0 for p in PROBES},
     "jax": {"scan_params_reuse": 1, "scan_chunk_churn": 2,
@@ -89,10 +84,6 @@ EXPECTED_COMPILE_COUNTS: Dict[str, Dict[str, int]] = {
                 "scan_many_qpad": 3, "climb_params_reuse": 1,
                 "climb_many_qpad": 2, "grid_rekey": 2,
                 "lockstep_wave_qpad": 3},
-    "pallas": {"scan_params_reuse": 1, "scan_chunk_churn": 1,
-               "scan_many_qpad": 3, "climb_params_reuse": 1,
-               "climb_many_qpad": 1, "grid_rekey": 2,
-               "lockstep_wave_qpad": 3},
 }
 
 
@@ -132,10 +123,7 @@ def expected_compile_counts(backend_name: str,
     than hard-coded: D == 1 reproduces the legacy literal table, while
     e.g. D == 8 collapses the churn probe's {8, 4} chunk sweep into one
     class (both clip to the 2-row device share of the 12-row grid) and
-    the climb Q sweep {1..4} into one padded class of 8.  The pallas
-    table is device-independent: its round-robin dispatch re-places the
-    same per-chunk executables across devices without touching the memo
-    keys, and the audit battery runs the interpreted (round-robin) path.
+    the climb Q sweep {1..4} into one padded class of 8.
     """
     base = dict(EXPECTED_COMPILE_COUNTS[backend_name])
     D = max(1, int(n_devices))
@@ -190,9 +178,6 @@ def fresh_backend(name: str):
         return JaxPlanBackend()
     if name == "jax_x64":
         return JaxPlanBackend(precision="x64")
-    if name == "pallas":
-        from repro.kernels.plan_scan import PallasPlanBackend
-        return PallasPlanBackend()
     raise ValueError(f"unknown backend {name!r}")
 
 
@@ -261,8 +246,7 @@ def compare_counts(backend_name: str, actual: Dict[str, int],
                    ) -> List[Finding]:
     expected = expected if expected is not None \
         else expected_compile_counts(backend_name, plan_devices())
-    src = "src/repro/core/planning_backend.py" \
-        if backend_name != "pallas" else "src/repro/kernels/plan_scan.py"
+    src = "src/repro/core/planning_backend.py"
     out: List[Finding] = []
     for probe in PROBES:
         got, want = actual.get(probe, 0), expected.get(probe, 0)
@@ -287,7 +271,7 @@ def compare_counts(backend_name: str, actual: Dict[str, int],
 
 def available_backends() -> List[str]:
     from repro.core.planning_backend import have_backend
-    return [n for n in ("numpy", "jax", "jax_x64", "pallas")
+    return [n for n in ("numpy", "jax", "jax_x64")
             if have_backend(n)]
 
 

@@ -63,10 +63,9 @@ class ClusterConditions:
 class ConfigColumns:
     """A batch of configurations held as one array per resource dimension.
 
-    The grid scans (the jax programs and the fused Pallas kernels) decode
-    configurations straight into one array per dimension; stacking them
-    into an ``(N, n_dims)`` array would force a relayout the TPU compiler
-    handles badly, or in a Pallas kernel refuses.
+    The jax grid scans decode configurations straight into one array per
+    dimension; stacking them into an ``(N, n_dims)`` array would force a
+    relayout the TPU compiler handles badly.
     This view answers the two things batch cost fns ask of their configs,
     ``configs[:, d]`` (the tile of dimension d) and ``shape``; cost fns
     read it through ``as_configs``.  ``jnp.asarray`` still stacks it into

@@ -30,10 +30,7 @@ machinery:
    every start of every request climbing in one vmapped jitted
    ``while_loop``).  On the numpy backend the stacked arithmetic is
    bit-identical with Q independent per-operator searches (argmin ties
-   included); on jax the whole group is one fused program dispatch; on
-   ``"pallas"`` the group runs on the fused scan+argmin kernel
-   (repro.kernels.plan_scan) as a 2-D grid over (query, block) — zero
-   materialized ``(Q, chunk)`` cost matrix.
+   included); on jax the whole group is one fused program dispatch.
 
 3. **Commit / fan-out.**  Each winner is re-evaluated through the
    caller's scalar float64 cost fn before being fanned back to the
@@ -87,12 +84,11 @@ device.  Commit order is preserved exactly — wave N's stage-3 commits
 order) always complete before wave N+1's stage-1 cache lookups, so
 plans, cache contents, and hit/miss counters are bit-identical to
 calling ``flush()`` at the same points; ``PlanFuture.result()`` on an
-in-flight request commits just that wave.  ``double_buffer=False`` (or
-a backend without the async split) degrades ``flush_async`` to
-``flush``.  Within a *synchronous* flush the same split still pays:
-every (fn, grid) group's program is dispatched before any group's
-results are read back, so e.g. a flush mixing SMJ and BHJ operators
-overlaps the two scans.
+in-flight request commits just that wave.  ``double_buffer=False``
+degrades ``flush_async`` to ``flush``.  Within a *synchronous* flush
+the same split still pays: every (fn, grid) group's program is
+dispatched before any group's results are read back, so e.g. a flush
+mixing SMJ and BHJ operators overlaps the two scans.
 
 Instrumentation (``repro.obs``): a wave is the spans ``broker.stage1``
 (dedup, memo and cache fronting), ``broker.dispatch`` (around each
@@ -686,23 +682,12 @@ class PlanBroker:
                 _metrics.counter(rows).inc(req0.cluster.grid_size())
             with _obs.span("broker.dispatch.group", cat="broker") as sp:
                 if mode == "grid":
-                    if hasattr(be, "argmin_grid_many_async"):
-                        fin = be.argmin_grid_many_async(
-                            req0.fn, req0.cluster, pm, stats=gstats)
-                    else:           # backend without the async split
-                        results = be.argmin_grid_many(
-                            req0.fn, req0.cluster, pm, stats=gstats)
-                        fin = (lambda r=results: r)
+                    fin = be.argmin_grid_many_async(
+                        req0.fn, req0.cluster, pm, stats=gstats)
                 else:
-                    if hasattr(be, "hill_climb_ensemble_many_async"):
-                        fin = be.hill_climb_ensemble_many_async(
-                            req0.fn, req0.cluster, pm, stats=gstats,
-                            n_random=req0.n_random, seed=req0.seed)
-                    else:
-                        results = be.hill_climb_ensemble_many(
-                            req0.fn, req0.cluster, pm, stats=gstats,
-                            n_random=req0.n_random, seed=req0.seed)
-                        fin = (lambda r=results: r)
+                    fin = be.hill_climb_ensemble_many_async(
+                        req0.fn, req0.cluster, pm, stats=gstats,
+                        n_random=req0.n_random, seed=req0.seed)
                 if sp:
                     sp.set(mode=mode, q=len(entries), wave=wave_no,
                            backend=getattr(be, "name", "?"))

@@ -65,18 +65,6 @@ even so the compiled shape set stays small).
 This is the engine under ``repro.core.plan_broker``: one fused program
 call plans every operator of every concurrent query.
 
-Pallas backend
---------------
-``get_backend("pallas")`` (``repro.kernels.plan_scan.PallasPlanBackend``,
-a ``JaxPlanBackend`` subclass) runs the grid scan as a *fused*
-decode+cost+argmin Pallas kernel: configurations are decoded from flat
-row ids in-kernel and the running ``(best_cost, best_idx)`` pair is
-carried across grid blocks, so neither the config array nor any cost
-vector — in particular no ``(Q, chunk)`` cost matrix on the stacked
-many-request path — is ever materialized in main memory.  Off-TPU the
-kernels run in interpret mode (correctness everywhere; the CI backend
-matrix runs the parity suites on it).
-
 Precision
 ---------
 ``JaxPlanBackend(precision="x64")`` (``get_backend("jax_x64")``) scopes
@@ -278,10 +266,10 @@ def enumerate_configs(cluster: ClusterConditions, lo: int = 0,
 
 
 # ------------------------------- grid decode -------------------------------- #
-# One decode serves every scan program, jax and Pallas alike: flat row ids
-# become configuration values by arithmetic, so no program gathers from a
-# value table or stacks an (N, n_dims) array (a TPU has no vector gather,
-# and a minor dimension of n_dims is a relayout its compiler handles badly).
+# One decode serves every scan program: flat row ids become configuration
+# values by arithmetic, so no program gathers from a value table or stacks
+# an (N, n_dims) array (a TPU has no vector gather, and a minor dimension
+# of n_dims is a relayout its compiler handles badly).
 
 def _dim_meta(cluster: ClusterConditions) -> Tuple[Tuple, ...]:
     """Static per-dimension decode recipe: ("affine", lo, step) for range
@@ -1076,9 +1064,8 @@ def have_backend(spec: str) -> bool:
 
 def get_backend(spec: Union[str, PlanBackend, None] = None) -> PlanBackend:
     """Resolve a backend selection: None/"numpy", "jax", "jax_x64" (exact
-    x64-scoped jit), "pallas" (fused scan+argmin kernels,
-    repro.kernels.plan_scan; interpret mode off-TPU), "auto" (jax if
-    importable, else numpy), or an already-constructed backend instance.
+    x64-scoped jit), "auto" (jax if importable, else numpy), or an
+    already-constructed backend instance.
     String selections return process-wide singletons so compiled-program
     caches are shared."""
     if spec is None:
@@ -1097,13 +1084,7 @@ def get_backend(spec: Union[str, PlanBackend, None] = None) -> PlanBackend:
             _SINGLETONS[spec] = JaxPlanBackend()
         elif spec == "jax_x64":
             _SINGLETONS[spec] = JaxPlanBackend(precision="x64")
-        elif spec == "pallas":
-            # deferred import: plan_scan pulls in jax + pallas and imports
-            # this module for the shared grid helpers
-            from repro.kernels.plan_scan import PallasPlanBackend
-            _SINGLETONS[spec] = PallasPlanBackend()
         else:
             raise ValueError(f"unknown plan backend {spec!r} (expected "
-                             "'numpy', 'jax', 'jax_x64', 'pallas', or "
-                             "'auto')")
+                             "'numpy', 'jax', 'jax_x64' or 'auto')")
     return _SINGLETONS[spec]

@@ -19,10 +19,7 @@ resource-plan cache.
 Backend selection (repro.core.planning_backend): ``backend="numpy"``
 (default — float64, bit-identical with the scalar loops),
 ``backend="jax"`` / ``"jax_x64"`` runs the same searches through
-jit-compiled programs, and ``backend="pallas"`` through the fused
-scan+argmin kernels of repro.kernels.plan_scan (config decode, cost
-evaluation, and the argmin reduction in one program per grid block —
-no materialized cost vector).
+jit-compiled programs.
 On the jax backend the per-operator data characteristics (ss, ls) are
 *traced arguments*, so one compiled program per (impl, objective) serves
 every operator of every query — the cost model fuses with the search.

@@ -78,7 +78,7 @@ class RAQO:
     cache: Optional[ResourcePlanCache] = None
     seed: int = 0
     # array-search backend (planning_backend):
-    # None/"numpy" | "jax" | "jax_x64" | "pallas" | "auto"
+    # None/"numpy" | "jax" | "jax_x64" | "auto"
     backend: Union[str, PlanBackend, None] = None
     # session planning broker shared by every costing this RAQO creates;
     # plan_queries constructs one on demand when unset

@@ -134,7 +134,7 @@ class ShardingPlanner:
     resource_planning: str = "hillclimb"
     cache: Optional[ResourcePlanCache] = None
     objective: str = "time"                    # time | chip_seconds
-    # numpy | jax | jax_x64 | pallas | auto
+    # numpy | jax | jax_x64 | auto
     backend: Union[str, PlanBackend, None] = "numpy"
     ensemble_starts: int = 24                  # random starts for "ensemble"
     seed: int = 0

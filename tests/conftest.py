@@ -7,7 +7,7 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # CI backend matrix: REPRO_PLAN_BACKEND selects the PlanBackend lane this
-# suite run exercises (numpy | jax | jax_x64 | pallas).  The parity suites
+# suite run exercises (numpy | jax | jax_x64).  The parity suites
 # (test_plan_scan.py and friends) pick it up through the fixture below and
 # compare the lane's backend against the numpy oracle, so every backend
 # stays bit-honest under the same property tests.

@@ -264,7 +264,7 @@ def test_lockstep_recompile_contract_frozen():
     D=8 so drift fails loudly."""
     legacy = {"scan_params_reuse", "scan_chunk_churn", "scan_many_qpad",
               "climb_params_reuse", "climb_many_qpad", "grid_rekey"}
-    for be in ("numpy", "jax", "jax_x64", "pallas"):
+    for be in ("numpy", "jax", "jax_x64"):
         d1 = expected_compile_counts(be, 1)
         assert set(d1) == legacy | {"lockstep_wave_qpad"}
         assert d1["lockstep_wave_qpad"] == (0 if be == "numpy" else 3)
@@ -273,7 +273,7 @@ def test_lockstep_recompile_contract_frozen():
                  "climb_many_qpad": 1, "grid_rekey": 2,
                  "lockstep_wave_qpad": 3}
     assert expected_compile_counts("jax", 8) == frozen_d8
-    assert expected_compile_counts("pallas", 8) == frozen_d8
+    assert expected_compile_counts("jax_x64", 8) == frozen_d8
     assert all(v == 0 for v in expected_compile_counts("numpy", 8).values())
 
 

@@ -1,15 +1,15 @@
-"""Fused pallas scan+argmin kernel parity (repro.kernels.plan_scan).
+"""The jax plan-scan programs against the float64 numpy oracle.
 
-The pallas backend computes in float32, so these property tests use
-integer-valued cost tables (exact in f32): pallas(interpret) must then
-agree with the float64 numpy oracle *bit-for-bit* — argmin config, cost,
-and tie-breaking — on random, ragged, OOM-masked, and all-infeasible
-grids; the (Q, P)-stacked kernel (both the 2-D (query, block) grid and
-the query-unrolled interpret variant) must equal Q sequential scans; and
-a broker flush on ``backend="pallas"`` must be identical with sequential
-per-operator planning.  The env-lane tests at the bottom run the same
-parity properties against whichever backend the CI matrix selected via
-``REPRO_PLAN_BACKEND`` (see tests/conftest.py).
+``get_backend("jax")`` searches in float32, so these tests use
+integer-valued cost tables (exact in f32): its scans (``plan_scan``,
+``plan_scan_many_w{Qpad}``) and climbs (``plan_climb``,
+``plan_climb_many``) must then agree with numpy *bit-for-bit* — argmin
+config, cost, and tie-breaking — on all-infeasible grids, on ties within
+and across spans, for stacked scans of any request count, and for a
+broker flush against sequential per-operator planning.  The env-lane
+tests at the bottom run the same parity properties against whichever
+backend the CI matrix selected via ``REPRO_PLAN_BACKEND`` (see
+tests/conftest.py).
 """
 import math
 import os
@@ -38,8 +38,8 @@ needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 
 def _random_cluster(rng, na: int, nb: int, ragged: bool):
     """Two-dim cluster; optionally a ragged-stepped dim plus an
-    explicit-values dim, exercising both in-kernel decode paths (affine
-    arithmetic and compare-select over the value table)."""
+    explicit-values dim, exercising both decode paths (affine arithmetic
+    and compare-select over the value table)."""
     if ragged:
         step = int(rng.integers(2, 4))
         hi = 1 + step * (na - 1) + int(rng.integers(1, step))
@@ -56,9 +56,7 @@ def _random_cluster(rng, na: int, nb: int, ragged: bool):
 def _table_fn(cluster, table, xp):
     """Batch cost fn looking up an (na, nb) table by config value.
     Integer-valued costs are exact in float32, so f32 backends must agree
-    with numpy exactly, ties included.  The xp tables are captured by
-    closure: on the pallas backend they are hoisted out of the traced
-    cost fn and streamed into the kernel as constant inputs."""
+    with numpy exactly, ties included."""
     ga, gb = (np.asarray(d.grid(), dtype=np.int64) for d in cluster.dims)
     t = xp.asarray(table)
     ga_x, gb_x = xp.asarray(ga), xp.asarray(gb)
@@ -83,61 +81,6 @@ def _assert_same(a, b):
     assert (ca == cb) or (math.isinf(ca) and math.isinf(cb))
 
 
-# ------------------------- argmin parity vs numpy --------------------------- #
-
-@needs_jax
-@settings(max_examples=10, deadline=None)
-@given(seed=st.integers(0, 10_000), na=st.integers(2, 12),
-       nb=st.integers(2, 9), ragged=st.booleans())
-def test_hypothesis_pallas_numpy_argmin_identical(seed, na, nb, ragged):
-    rng = np.random.default_rng(seed)
-    cluster = _random_cluster(rng, na, nb, ragged)
-    table = _random_table(rng, na, nb)
-    _assert_same(
-        get_backend("pallas").argmin_grid(_table_fn(cluster, table, jnp),
-                                          cluster),
-        get_backend("numpy").argmin_grid(_table_fn(cluster, table, np),
-                                         cluster))
-
-
-@needs_jax
-def test_all_infeasible_grid_returns_none():
-    cluster = paper_cluster(7, 5)
-    table = np.full((7, 5), np.inf)
-    res, cost = get_backend("pallas").argmin_grid(
-        _table_fn(cluster, table, jnp), cluster)
-    assert res is None and math.isinf(cost)
-
-
-@needs_jax
-def test_tie_break_index_identity():
-    """Duplicated minima must resolve to the FIRST config in
-    ``enumerate_configs`` order, exactly like the numpy backend — within
-    one block and across the chunk fold alike (a tiny block forces the
-    minimum into a later chunk and ties across chunk boundaries)."""
-    from repro.kernels.plan_scan import PallasPlanBackend
-    cluster = ClusterConditions(dims=(ResourceDim("a", 0, 11),
-                                      ResourceDim("b", 0, 4)))
-    table = np.full((12, 5), 9.0)
-    table[3, 2] = table[7, 1] = table[7, 3] = 1.0   # three tied minima
-    fn_np = _table_fn(cluster, table, np)
-    r_np = get_backend("numpy").argmin_grid(fn_np, cluster)
-    assert r_np[0] == (3, 2)                        # first in scan order
-    for block in (60, 7):                           # 1 chunk / 9 chunks
-        be = PallasPlanBackend(block=block)
-        _assert_same(be.argmin_grid(_table_fn(cluster, table, jnp),
-                                    cluster), r_np)
-    # constant surface: every config ties -> the very first config wins
-    flat = np.zeros((12, 5))
-    r_c = get_backend("numpy").argmin_grid(_table_fn(cluster, flat, np),
-                                           cluster)
-    assert r_c[0] == (0, 0)
-    _assert_same(PallasPlanBackend(block=7).argmin_grid(
-        _table_fn(cluster, flat, jnp), cluster), r_c)
-
-
-# --------------------------- stacked (Q, P) scan ---------------------------- #
-
 def _param_fn(xp):
     """Cost surface that depends on per-request params (integer-exact):
     cost = table-free arithmetic of config and a per-request offset."""
@@ -148,96 +91,131 @@ def _param_fn(xp):
     return fn
 
 
+# ------------------------------ the jax scans ------------------------------ #
+
+@needs_jax
+@pytest.mark.parametrize("method", ["argmin_grid", "argmin_grid_many"])
+def test_all_infeasible_grid_returns_none(method):
+    """A grid on which every configuration costs inf has no plan: one
+    span (the plain body) answers ``(None, inf)``, per request."""
+    cluster = paper_cluster(7, 5)
+    table = np.full((7, 5), np.inf)
+    be = get_backend("jax")
+    fn = _table_fn(cluster, table, jnp)
+    if method == "argmin_grid":
+        got = [be.argmin_grid(fn, cluster)]
+    else:
+        got = be.argmin_grid_many(fn, cluster, np.zeros((3, 1)))
+    assert got == [(None, math.inf)] * len(got)
+
+
+@needs_jax
+@pytest.mark.parametrize("chunk_size", [60, 13, 7, 1])
+def test_tie_break_index_identity(chunk_size):
+    """Duplicated minima resolve to the FIRST config in
+    ``enumerate_configs`` order, exactly like the numpy backend, whether
+    they share a span (one span of 60 rows) or lie in different spans
+    that the device loop merges (spans of 13, 7 and 1 rows)."""
+    cluster = ClusterConditions(dims=(ResourceDim("a", 0, 11),
+                                      ResourceDim("b", 0, 4)))
+    table = np.full((12, 5), 9.0)
+    table[3, 2] = table[7, 1] = table[7, 3] = 1.0   # three tied minima
+    be = get_backend("jax")
+    r_np = get_backend("numpy").argmin_grid(_table_fn(cluster, table, np),
+                                            cluster)
+    assert r_np[0] == (3, 2)                        # first in scan order
+    _assert_same(be.argmin_grid(_table_fn(cluster, table, jnp), cluster,
+                                chunk_size=chunk_size), r_np)
+    # constant surface: every config ties -> the very first config wins
+    flat = np.zeros((12, 5))
+    r_c = get_backend("numpy").argmin_grid(_table_fn(cluster, flat, np),
+                                           cluster)
+    assert r_c[0] == (0, 0)
+    _assert_same(be.argmin_grid(_table_fn(cluster, flat, jnp), cluster,
+                                chunk_size=chunk_size), r_c)
+
+
 @needs_jax
 @settings(max_examples=8, deadline=None)
 @given(seed=st.integers(0, 10_000), q=st.integers(1, 6),
        ragged=st.booleans())
 def test_hypothesis_stacked_scan_equals_sequential(seed, q, ragged):
-    """(Q, P)-stacked pallas scan == Q sequential pallas scans == Q numpy
-    scans, for both kernel variants (2-D (query, block) grid and the
-    query-unrolled interpret body)."""
-    from repro.kernels.plan_scan import PallasPlanBackend
+    """A (Q, P)-stacked jax scan == Q sequential jax scans == Q numpy
+    scans, for odd Q (padded to even with an edge copy) and even Q."""
     rng = np.random.default_rng(seed)
     cluster = _random_cluster(rng, int(rng.integers(3, 10)),
                               int(rng.integers(3, 8)), ragged)
     pm = rng.integers(0, 1000, size=(q, 1)).astype(np.float64)
+    be = get_backend("jax")
     ref = [get_backend("numpy").argmin_grid(_param_fn(np), cluster,
                                             params=pm[i])
            for i in range(q)]
-    for variant in ("unrolled", "grid2d"):
-        be = PallasPlanBackend(block=16, many_variant=variant)
-        got = be.argmin_grid_many(_param_fn(jnp), cluster, pm)
-        seq = [be.argmin_grid(_param_fn(jnp), cluster, params=pm[i])
-               for i in range(q)]
-        for g, s, r in zip(got, seq, ref):
-            _assert_same(g, s)
-            _assert_same(g, r)
+    got = be.argmin_grid_many(_param_fn(jnp), cluster, pm)
+    seq = [be.argmin_grid(_param_fn(jnp), cluster, params=pm[i])
+           for i in range(q)]
+    assert len(got) == q
+    for g, s, r in zip(got, seq, ref):
+        _assert_same(g, s)
+        _assert_same(g, r)
 
 
 @needs_jax
 def test_stacked_scan_chunks_large_q(monkeypatch):
-    """Q beyond the unroll bound splits into UNROLL_Q-sized kernel
-    batches with unchanged results."""
-    from repro.kernels import plan_scan
-    monkeypatch.setattr(plan_scan, "UNROLL_Q", 2)
+    """A wide stack (41 requests, padded to 42) shrinks the span to keep
+    its live cost block under ``MAX_LIVE_ELEMENTS``, so the scan loops
+    over several spans in one launch, with unchanged results."""
+    from repro.core import planning_backend as pb
+    from repro.obs import get_metrics
+    q = 41
+    monkeypatch.setattr(pb, "MAX_LIVE_ELEMENTS", 42 * 8)    # 8-row spans
     cluster = paper_cluster(9, 4)
-    pm = np.arange(5, dtype=np.float64).reshape(5, 1) * 3.0
-    be = plan_scan.PallasPlanBackend(many_variant="unrolled")
-    got = be.argmin_grid_many(_param_fn(jnp), cluster, pm)
+    pm = np.arange(q, dtype=np.float64).reshape(q, 1) * 3.0
+    mx = get_metrics()
+
+    def counts():
+        snap = mx.snapshot()
+        return (snap.get("backend.launches", 0),
+                snap.get("backend.scan_spans", 0))
+    before = counts()
+    got = get_backend("jax").argmin_grid_many(_param_fn(jnp), cluster, pm)
+    after = counts()
     ref = get_backend("numpy").argmin_grid_many(_param_fn(np), cluster, pm)
+    # one launch sweeping 36 rows in spans of 8
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 5)
+    assert len(got) == q
     for g, r in zip(got, ref):
         _assert_same(g, r)
 
 
-# ------------------------------ ensemble climb ------------------------------ #
-
-@needs_jax
-@settings(max_examples=8, deadline=None)
-@given(seed=st.integers(0, 10_000), na=st.integers(3, 12),
-       nb=st.integers(3, 9), ragged=st.booleans(),
-       n_random=st.integers(0, 8))
-def test_hypothesis_pallas_ensemble_identical(seed, na, nb, ragged,
-                                              n_random):
-    """Same seed -> same starts -> identical steepest-descent
-    trajectories on the fused neighbor-costing kernel and the numpy
-    backend (first-min tie-breaking on neighbors included)."""
-    rng = np.random.default_rng(seed)
-    cluster = _random_cluster(rng, na, nb, ragged)
-    table = _random_table(rng, na, nb)
-    _assert_same(
-        get_backend("pallas").hill_climb_ensemble(
-            _table_fn(cluster, table, jnp), cluster, n_random=n_random,
-            seed=seed),
-        get_backend("numpy").hill_climb_ensemble(
-            _table_fn(cluster, table, np), cluster, n_random=n_random,
-            seed=seed))
-
-
 @needs_jax
 def test_ensemble_many_equals_per_request():
+    """The stacked climb (``plan_climb_many``, one vmapped program) gives
+    each request the climb it gets alone, and numpy's."""
     cluster = paper_cluster(12, 6)
     pm = np.asarray([[5.0], [250.0], [777.0]])
-    be = get_backend("pallas")
+    be = get_backend("jax")
     many = be.hill_climb_ensemble_many(_param_fn(jnp), cluster, pm,
                                        n_random=4, seed=1)
     seq = [be.hill_climb_ensemble(_param_fn(jnp), cluster, params=pm[i],
                                   n_random=4, seed=1) for i in range(3)]
-    assert many == seq
+    ref = get_backend("numpy").hill_climb_ensemble_many(
+        _param_fn(np), cluster, pm, n_random=4, seed=1)
+    assert many == seq == ref
 
 
-# ------------------------- broker flush on pallas --------------------------- #
+# --------------------------- broker flush on jax ---------------------------- #
 
 @needs_jax
 @pytest.mark.parametrize("mode", ["batched", "ensemble"])
-def test_broker_flush_pallas_identical_with_sequential(mode):
-    """A PlanBroker("pallas") flush (stacked kernel programs) must return
-    exactly the plans and costs of sequential per-operator planning on
-    the same backend (winners re-committed through scalar float64 on
-    both ends)."""
+def test_broker_flush_jax_identical_with_sequential(mode):
+    """A PlanBroker("jax") flush (stacked programs) must return exactly
+    the plans and costs of sequential per-operator planning on the same
+    backend (winners re-committed through scalar float64 on both
+    ends)."""
     kw = dict(models=simulator_cost_models(), cluster=paper_cluster(40, 10),
               resource_planning=mode)
-    seq = OperatorCosting(backend="pallas", **kw)
-    brk = OperatorCosting(broker=PlanBroker("pallas"), **kw)
+    seq = OperatorCosting(backend="jax", **kw)
+    brk = OperatorCosting(broker=PlanBroker("jax"), **kw)
     ops = [("SMJ", 2.0, 74.0), ("BHJ", 1.0, 74.0), ("SMJ", 3.0, 50.0),
            ("BHJ", 0.5, 20.0), ("SMJ", 2.0, 74.0)]    # recurring op
     for op in ops:
@@ -247,13 +225,35 @@ def test_broker_flush_pallas_identical_with_sequential(mode):
 
 
 @needs_jax
-def test_pallas_backend_protocol_surface():
-    be = get_backend("pallas")
-    assert be is get_backend("pallas")          # process-wide singleton
-    assert be.name == "pallas" and be.exact is False
+def test_jax_backend_protocol_surface():
+    """``get_backend("jax")``: a process-wide float32 singleton that the
+    broker drives through both async splits, each a dispatch that returns
+    the finalize closure giving the synchronous answer."""
+    be = get_backend("jax")
+    assert be is get_backend("jax") is get_backend("auto")
+    assert be.name == "jax" and be.exact is False
     assert be.precision == "float32"
-    import jax.numpy as jnp_mod
-    assert be.xp is jnp_mod
+    assert be.xp is jnp
+    cluster = paper_cluster(6, 4)
+    pm = np.asarray([[2.0], [9.0], [4.0]])
+    fin = be.argmin_grid_many_async(_param_fn(jnp), cluster, pm)
+    assert callable(fin)
+    assert fin() == be.argmin_grid_many(_param_fn(jnp), cluster, pm)
+    fin = be.hill_climb_ensemble_many_async(_param_fn(jnp), cluster, pm,
+                                            n_random=3, seed=2)
+    assert callable(fin)
+    assert fin() == be.hill_climb_ensemble_many(_param_fn(jnp), cluster, pm,
+                                                n_random=3, seed=2)
+
+
+def test_unknown_backend_names_the_ones_that_exist():
+    """A name outside numpy, jax, jax_x64 and auto is refused, and the
+    refusal lists exactly those four."""
+    with pytest.raises(ValueError) as err:
+        get_backend("fused")
+    msg = str(err.value)
+    assert "'fused'" in msg
+    assert msg.endswith("(expected 'numpy', 'jax', 'jax_x64' or 'auto')")
 
 
 # ------------------- env-selected backend lane (CI matrix) ------------------ #

@@ -77,7 +77,7 @@ def _random_table(rng, na, nb, oom_frac=0.15):
 # ------------------------- argmin-grid parity ------------------------------ #
 
 @needs_jax
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=25, deadline=None)
 @given(seed=st.integers(0, 10_000), na=st.integers(2, 12),
        nb=st.integers(2, 9), ragged=st.booleans())
 def test_hypothesis_jax_numpy_argmin_identical(seed, na, nb, ragged):
@@ -300,7 +300,7 @@ def test_looped_scan_matches_numpy_oracle_across_spans(backend, method,
 
 
 @needs_jax
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=18, deadline=None)
 @given(seed=st.integers(0, 10_000), na=st.integers(3, 12),
        nb=st.integers(3, 9), ragged=st.booleans(),
        n_random=st.integers(0, 8))

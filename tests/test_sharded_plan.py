@@ -61,13 +61,9 @@ import jax.numpy as jnp
 from repro.core.cluster import ClusterConditions, ResourceDim
 from repro.core.planning_backend import get_backend
 
-name, want, variant = sys.argv[1], int(sys.argv[2]), sys.argv[3]
+name, want = sys.argv[1], int(sys.argv[2])
 assert jax.device_count() == want, (jax.device_count(), want)
-if name == "pallas" and variant != "default":
-    from repro.kernels.plan_scan import PallasPlanBackend
-    be = PallasPlanBackend(block=7, shard_variant=variant)
-else:
-    be = get_backend(name)
+be = get_backend(name)
 np_be = get_backend("numpy")
 assert be.device_count() == want, (be.device_count(), want)
 
@@ -142,32 +138,30 @@ print(json.dumps({"devices": jax.device_count(), "ok": not bad,
 """
 
 
-def _run_sharded_lane(backend: str, devices: int,
-                      variant: str = "default") -> dict:
+def _run_sharded_lane(backend: str, devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("REPRO_PLAN_DEVICES", None)
     proc = subprocess.run(
-        [sys.executable, "-c", _DRIVER, backend, str(devices), variant],
+        [sys.executable, "-c", _DRIVER, backend, str(devices)],
         capture_output=True, text=True, env=env, timeout=600)
     assert proc.returncode == 0, proc.stderr[-4000:]
     return json.loads(proc.stdout.splitlines()[-1])
 
 
 @needs_jax
-@pytest.mark.parametrize("backend,devices,variant", [
-    ("jax", 2, "default"),
-    ("jax", 8, "default"),
-    ("jax_x64", 8, "default"),
-    ("pallas", 8, "default"),       # auto -> round-robin dispatch (interpret)
-    ("pallas", 8, "shardmap"),      # one mesh-wide program per chunk class
+@pytest.mark.parametrize("backend,devices", [
+    pytest.param("jax", 2, id="jax-2-default"),
+    pytest.param("jax", 4, id="jax-4-default"),     # a v5e 2x2 host
+    pytest.param("jax", 8, id="jax-8-default"),
+    pytest.param("jax_x64", 8, id="jax_x64-8-default"),
 ])
-def test_sharded_backend_matches_numpy_oracle(backend, devices, variant):
+def test_sharded_backend_matches_numpy_oracle(backend, devices):
     """Every sharded lane is bit-identical with the numpy oracle —
     argmin config, cost, and first-minimum tie-breaking — on random,
     ragged, tie-heavy, and all-infeasible grids."""
-    out = _run_sharded_lane(backend, devices, variant)
+    out = _run_sharded_lane(backend, devices)
     assert out["devices"] == devices
     assert out["ok"], out["bad"]
 
@@ -258,13 +252,19 @@ def test_plan_devices_env_is_the_rollback_switch(monkeypatch):
 
 @needs_jax
 def test_devices_ctor_cap_and_shard_mode_off():
+    """``devices=1`` caps the plan mesh at one device: the scans build
+    their single-device programs, with no ``shard_map``."""
+    import jax.numpy as jnp
     from repro.core.planning_backend import JaxPlanBackend
-    from repro.kernels.plan_scan import PallasPlanBackend
-    assert JaxPlanBackend(devices=1).device_count() == 1
-    be = PallasPlanBackend(devices=1)
-    assert be._shard_mode() == "off"
-    with pytest.raises(ValueError):
-        PallasPlanBackend(shard_variant="bogus")
+    be = JaxPlanBackend(devices=1)
+    assert be.device_count() == 1
+    def fn(cfgs, p):
+        return jnp.asarray(cfgs)[:, 0] * 1.0 + p[0]
+    be.argmin_grid_many(fn, paper_cluster(9, 4), np.zeros((2, 1)))
+    ((key, (_, prog)),) = be._programs.items()
+    assert key[0] == "scan_many" and key[3][-1] == 1      # D in the key
+    text = prog.lower(0, be._params(np.zeros((2, 1)))).as_text()
+    assert "sdy.manual" not in text and "SPMDFullToShardShape" not in text
 
 
 # ------------------------ _many_chunk geometry ------------------------------ #
