@@ -26,6 +26,19 @@ class ResourceDim:
             return self.values
         return tuple(range(self.lo, self.hi + 1, self.step))
 
+    @property
+    def size(self) -> int:
+        """``len(self.grid())``, by arithmetic: the grid is not built."""
+        return len(self._seq())
+
+    def value(self, i: int) -> int:
+        """``self.grid()[i]``, by arithmetic: the grid is not built."""
+        return self._seq()[i]
+
+    def _seq(self) -> Sequence[int]:
+        # a range's len and indexing are O(1); only grid() materialises it
+        return self.values or range(self.lo, self.hi + 1, self.step)
+
     def clamp_ok(self, v: int) -> bool:
         if self.values:
             return v in self.values
@@ -50,7 +63,7 @@ class ClusterConditions:
     def grid_size(self) -> int:
         n = 1
         for d in self.dims:
-            n *= len(d.grid())
+            n *= d.size
         return n
 
     def all_configs(self):

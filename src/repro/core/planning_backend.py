@@ -248,7 +248,9 @@ def _listen_for_compiles(jax) -> None:
 
 def grid_arrays(cluster: ClusterConditions) -> List[np.ndarray]:
     """Per-dimension value grids as int64 arrays."""
-    return [np.asarray(d.grid(), dtype=np.int64) for d in cluster.dims]
+    return [np.asarray(d.values, dtype=np.int64) if d.values
+            else np.arange(d.lo, d.hi + 1, d.step, dtype=np.int64)
+            for d in cluster.dims]
 
 
 def enumerate_configs(cluster: ClusterConditions, lo: int = 0,
@@ -286,7 +288,7 @@ def _dim_meta(cluster: ClusterConditions) -> Tuple[Tuple, ...]:
 
 
 def _dim_sizes(cluster: ClusterConditions) -> Tuple[int, ...]:
-    return tuple(len(d.grid()) for d in cluster.dims)
+    return tuple(d.size for d in cluster.dims)
 
 
 def _value_of_index(idx, meta):
@@ -358,10 +360,18 @@ def _snap_to_indices(cfg: Sequence[int], cluster: ClusterConditions,
     return [int(np.argmin(np.abs(g - v))) for g, v in zip(grids, snapped)]
 
 
-def _decode_flat(grids: List[np.ndarray], shape: Tuple[int, ...],
-                 flat: int) -> Tuple[int, ...]:
-    idx = np.unravel_index(int(flat), shape)
-    return tuple(int(g[i]) for g, i in zip(grids, idx))
+def _decode_flat(cluster: ClusterConditions, flat: int) -> Tuple[int, ...]:
+    """A flat row id -> its configuration, in ``enumerate_configs`` order:
+    the host twin of ``_decode_columns``, by arithmetic (no grid is
+    built, so a scan's winners decode in O(n_dims))."""
+    dims = cluster.dims
+    cfg = [0] * len(dims)
+    rem = int(flat)
+    for d in range(len(dims) - 1, 0, -1):
+        rem, i = divmod(rem, dims[d].size)
+        cfg[d] = int(dims[d].value(i))
+    cfg[0] = int(dims[0].value(rem))
+    return tuple(cfg)
 
 
 def _pad_even(n: int) -> int:
@@ -531,10 +541,8 @@ class NumpyPlanBackend:
             upd = c < best_cost
             best_cost[upd] = c[upd]
             best_flat[upd] = lo + j[upd]
-        grids = grid_arrays(cluster)
-        shape = tuple(len(g) for g in grids)
         return [(None, math.inf) if best_flat[q] < 0 else
-                (_decode_flat(grids, shape, best_flat[q]),
+                (_decode_flat(cluster, best_flat[q]),
                  float(best_cost[q])) for q in range(Q)]
 
     def hill_climb_ensemble_many(self, batch_cost_fn: BatchCostFn,
@@ -709,8 +717,6 @@ class JaxPlanBackend:
         chunk = int(min(chunk_size, _pad_multiple(total, D) // D))
         span = chunk * D
         n_spans = -(-total // span)
-        grids_np = grid_arrays(cluster)
-        shape = tuple(len(g) for g in grids_np)
         has_params = params is not None
 
         def build():
@@ -761,8 +767,7 @@ class JaxPlanBackend:
         best_cost = float(cost)
         if math.isinf(best_cost):
             return None, math.inf
-        idx = np.unravel_index(int(flat), shape)
-        return tuple(int(g[i]) for g, i in zip(grids_np, idx)), best_cost
+        return _decode_flat(cluster, flat), best_cost
 
     @hot_path("launches one compiled program per stacked grid scan per "
               "flush", folds=3)  # params-normalizing asarray + 2 outputs
@@ -802,8 +807,6 @@ class JaxPlanBackend:
         chunk = _many_chunk(total, Qpad, D, chunk_size)
         span = chunk * D
         n_spans = -(-total // span)
-        grids_np = grid_arrays(cluster)
-        shape = tuple(len(g) for g in grids_np)
 
         def build():
             rows = self._decoder(cluster)
@@ -857,8 +860,7 @@ class JaxPlanBackend:
                 if math.isinf(cq):
                     out.append((None, math.inf))
                 else:
-                    out.append((_decode_flat(grids_np, shape, flats[q]),
-                                cq))
+                    out.append((_decode_flat(cluster, flats[q]), cq))
             return out
 
         return finalize

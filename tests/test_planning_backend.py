@@ -299,6 +299,82 @@ def test_looped_scan_matches_numpy_oracle_across_spans(backend, method,
                                   enumerate_configs(cluster, 260, 261)[0])
 
 
+# ------------------- grid size and decode by arithmetic -------------------- #
+
+SIZE_DIMS = {
+    "ragged": ResourceDim("a", 3, 20, step=4),        # (hi - lo) % step == 1
+    "single": ResourceDim("a", 7, 7),                 # lo == hi
+    "values": ResourceDim("a", 1, 64, values=(1, 2, 4, 8, 16, 32, 64)),
+}
+
+
+@pytest.mark.parametrize("case", [*sorted(SIZE_DIMS), "scaled_100k"])
+def test_dim_size_and_value_match_the_built_grid(case):
+    """``ResourceDim.size`` and ``value(i)``, ``grid_size()``,
+    ``grid_arrays`` and the host decode ``_decode_flat`` agree with the
+    grid that ``grid()`` and ``all_configs()`` build."""
+    from repro.core.planning_backend import _decode_flat, grid_arrays
+    if case == "scaled_100k":                         # sizes only
+        cluster = scaled_cluster(100_000, 100)
+        assert [d.size for d in cluster.dims] == \
+            [len(d.grid()) for d in cluster.dims] == [100_000, 100]
+        assert cluster.grid_size() == 10_000_000
+        return
+    dim = SIZE_DIMS[case]
+    assert dim.size == len(dim.grid())
+    assert [dim.value(i) for i in range(dim.size)] == list(dim.grid())
+    cluster = ClusterConditions(dims=(dim, ResourceDim("b", 2, 9, step=3)))
+    configs = list(cluster.all_configs())
+    assert cluster.grid_size() == len(configs)
+    for g, d in zip(grid_arrays(cluster), cluster.dims):
+        assert g.dtype == np.int64 and g.tolist() == list(d.grid())
+    assert [_decode_flat(cluster, k) for k in range(len(configs))] == configs
+
+
+@needs_jax
+def test_jax_scan_path_builds_no_grid(monkeypatch):
+    """The jax grid scans (``argmin_grid``, ``argmin_grid_many_async`` and
+    its finalize) and a broker grid flush size the grid and decode their
+    winners by arithmetic: with ``ResourceDim.grid`` and ``grid_arrays``
+    made to raise they still return the numpy oracle's configs and
+    costs, and the flush counts the same sweep rows."""
+    import jax.numpy as jnp
+    from repro.core import planning_backend as pb
+    from repro.core.plan_broker import PlanBroker, PlanRequest
+    from repro.obs import get_metrics
+    cluster = DECODE_GRIDS["mixed"]                   # stepped x values
+    configs = list(cluster.all_configs())
+    shape = tuple(len(d.grid()) for d in cluster.dims)
+    rng = np.random.default_rng(19)
+    table = rng.integers(0, 64, size=shape).astype(np.float64)
+    table[rng.random(shape) < 0.15] = np.inf
+    fn_np, fn_jx = (_table_param_fn(cluster, table, xp) for xp in (np, jnp))
+    cost_of = dict(zip(configs, table.reshape(-1).tolist()))
+    pm = np.array([[0.0], [3.0], [-2.0]])
+    np_be = get_backend("numpy")
+    ref_one = np_be.argmin_grid(fn_np, cluster, params=pm[0])
+    ref_many = np_be.argmin_grid_many(fn_np, cluster, pm)
+
+    def no_grid(*args, **kwargs):
+        raise AssertionError("the grid was built on the scan path")
+    monkeypatch.setattr(ResourceDim, "grid", no_grid)
+    monkeypatch.setattr(pb, "grid_arrays", no_grid)
+    be = get_backend("jax")
+    assert be.argmin_grid(fn_jx, cluster, params=pm[0]) == ref_one
+    assert be.argmin_grid_many_async(fn_jx, cluster, pm)() == ref_many
+
+    broker = PlanBroker(be)
+    futs = [broker.submit(PlanRequest(
+        fn=fn_jx, cluster=cluster, params=p,
+        commit_fn=lambda cfg, p=p: cost_of[cfg] + p[0], mode="grid"))
+        for p in pm]
+    rows = get_metrics().counter("broker.sweep_rows.sync")
+    before = rows.value
+    broker.flush()
+    assert rows.value - before == len(configs)
+    assert [f.result() for f in futs] == ref_many
+
+
 @needs_jax
 @settings(max_examples=18, deadline=None)
 @given(seed=st.integers(0, 10_000), na=st.integers(3, 12),
